@@ -239,9 +239,10 @@ def main(argv=None) -> int:
                         "lowered template programs keyed by (template "
                         "digest, engine, jax/jaxlib version, "
                         "flatten-schema version) with a vocab snapshot "
-                        "replay, plus JAX's persistent XLA compilation "
-                        "cache under <dir>/xla — a warm restart or "
-                        "--once run skips lowering entirely")
+                        "replay — a warm restart or --once run skips "
+                        "lowering entirely.  (JAX's persistent XLA cache "
+                        "is placed by JAX_COMPILATION_CACHE_DIR, else "
+                        "<checkout>/.jax_cache — never by this flag)")
     p.add_argument("--collect", default="reduced",
                    choices=["reduced", "masks", "differential"],
                    help="sweep collect lane: 'reduced' folds verdicts ON "
@@ -748,26 +749,15 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             return 1
     else:
+        from gatekeeper_tpu.utils.xla_cache import configure_xla_cache
+
+        configure_xla_cache()
         compile_cache = None
         if args.compile_cache:
             from gatekeeper_tpu.drivers.generation import CompileCache
 
             compile_cache = CompileCache(args.compile_cache,
                                          metrics=metrics)
-            try:
-                # XLA executables persist beside the lowering entries;
-                # min thresholds dropped so small admission kernels cache
-                import jax as _jax
-
-                _jax.config.update("jax_compilation_cache_dir",
-                                   compile_cache.xla_cache_dir())
-                _jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", -1)
-                _jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0)
-            except Exception as e:
-                print(f"xla compile cache unavailable: {e}",
-                      file=sys.stderr)
             print(f"compile cache: {args.compile_cache}", file=sys.stderr)
         tpu = TpuDriver(cel_driver=cel, metrics=metrics,
                         generation_swap=args.generation_swap == "on",
@@ -1045,9 +1035,8 @@ def main(argv=None) -> int:
             # warm-state replay (drivers/generation.WarmStateCache):
             # re-land the fused sweep traces + the admission warm-ref
             # kernels recorded by the previous process, so the first
-            # tick/burst after this restart retraces nothing — the
-            # persistent XLA cache under the same dir answers the
-            # compiles
+            # tick/burst after this restart retraces nothing — JAX's
+            # persistent XLA cache answers the compiles
             from gatekeeper_tpu.drivers.generation import WarmStateCache
 
             warm_cache = WarmStateCache(args.compile_cache,
@@ -1090,7 +1079,10 @@ def main(argv=None) -> int:
                       f"{v.namespace + '/' if v.namespace else ''}{v.name}: "
                       f"{v.message}")
         export_trace()
-        return 0
+        # a pass that dropped chunks has no verdicts for them: a one-shot
+        # run must not report that as success (served mode keeps going
+        # with the partial results and the incomplete marker)
+        return 1 if run.incomplete else 0
 
     # namespace lookup for the webhook hot path: with a live apiserver,
     # serve from a watch-fed cache (the reference's cached client with

@@ -29,8 +29,8 @@ is not reachable from the current vocab state (different template order, a
 process that already interned conflicting strings) is a miss — baked sids
 can never silently point at the wrong strings.  Corrupted or
 version-drifted entries are rejected (and deleted) on load, never served.
-``--compile-cache DIR`` also points JAX's persistent compilation cache at
-``DIR/xla`` so XLA executable builds survive restarts too.
+XLA executables persist separately, in JAX's own compilation cache
+(utils/xla_cache.py places it; ``--compile-cache`` does not move it).
 """
 
 from __future__ import annotations
@@ -141,12 +141,6 @@ class CompileCache:
         blob = "|".join([tdigest, engine, jv, jlv,
                          str(FLATTEN_SCHEMA_VERSION), str(CACHE_FORMAT)])
         return hashlib.sha256(blob.encode()).hexdigest()[:40]
-
-    def xla_cache_dir(self) -> str:
-        """Subdirectory for JAX's persistent compilation cache (XLA
-        executables) — enabled by ``__main__`` next to the lowering
-        entries so one ``--compile-cache DIR`` covers both."""
-        return os.path.join(self.root, "xla")
 
     def _paths(self, key: str) -> tuple:
         return (os.path.join(self.root, key + ".json"),

@@ -3,9 +3,9 @@ control plane's process shape — N clusters' audit planes multiplexed
 behind shared per-library runtimes (see :mod:`fleet.evaluator`).
 
 Shares the single-cluster entry's flags where they apply: one
-``--compile-cache`` serves every library's lowerings (+ the persistent
-XLA cache), one ``--snapshot-spill`` root holds per-cluster spill
-subdirs, ``--audit-interval``/``--audit-chunk-size``/
+``--compile-cache`` serves every library's lowerings, one
+``--snapshot-spill`` root holds per-cluster spill subdirs,
+``--audit-interval``/``--audit-chunk-size``/
 ``--constraint-violations-limit`` size the sweeps, ``--once`` runs one
 packed fleet pass and exits (spilling each cluster on the way out).
 """
@@ -71,22 +71,14 @@ def run_fleet(args) -> int:
         print(f"fleet config: {e}", file=sys.stderr)
         return 2
     metrics = MetricsRegistry()
+    from gatekeeper_tpu.utils.xla_cache import configure_xla_cache
+
+    configure_xla_cache()
     compile_cache = None
     if args.compile_cache:
         from gatekeeper_tpu.drivers.generation import CompileCache
 
         compile_cache = CompileCache(args.compile_cache, metrics=metrics)
-        try:
-            import jax as _jax
-
-            _jax.config.update("jax_compilation_cache_dir",
-                               compile_cache.xla_cache_dir())
-            _jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            _jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0)
-        except Exception as e:
-            print(f"xla compile cache unavailable: {e}", file=sys.stderr)
     fleet = FleetEvaluator(
         metrics=metrics,
         chunk_size=args.audit_chunk_size,

@@ -42,8 +42,7 @@ used to live in ``tests/fuzz_differential.py`` (``rand_obj`` /
 ``rand_value`` / ``IMAGES`` / ``VALUES``) so the manual fuzzer, the CI
 entry (``tests/test_fuzz.py``) and the soak harness share ONE
 generator.  This module stays import-light (no jax, no driver imports):
-``fuzz_differential`` must be able to pin ``JAX_PLATFORMS`` before any
-jax import, and the corpus is usable from tools without a device.
+the corpus is usable from tools without a device.
 """
 
 from __future__ import annotations

@@ -445,6 +445,10 @@ def run_cli(argv: list[str]) -> int:
 
     engines = ([args.engine] if args.engine != "all"
                else ["rego", "cel", "all"])
+    if args.engine in ("tpu", "sweep"):  # the engines that compile
+        from gatekeeper_tpu.utils.xla_cache import configure_xla_cache
+
+        configure_xla_cache()
     # span-trace every engine run: an already-active tracer (gator
     # --chaos runs under an outer harness, tests) is reused; otherwise a
     # seeded full-sampling tracer is installed for the bench duration so

@@ -162,7 +162,6 @@ COMMANDS = {
 
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    # JAX_PLATFORMS honored at package import (gatekeeper_tpu/__init__.py)
     if not argv or argv[0] in ("-h", "--help"):
         print("usage: gator [--chaos spec.json] "
               "{test|verify|expand|bench|sync|policy|decisions|"

@@ -880,51 +880,42 @@ def _flatten_worker_main(conn):
 class FlattenWorkerPool:
     """N long-lived flatten worker processes behind pipes.
 
-    Forked (cheap; workers inherit the already-built native module and
-    never touch jax), created lazily on first use and reused across
-    chunks/sweeps.  ``run`` is serialized by a lock — concurrent
-    pipeline flatten-stage threads take turns rather than interleaving
-    pipe messages."""
+    Spawned, not forked: the pool is created lazily on first use, by
+    which time the parent has initialised the accelerator runtime and
+    runs its threads — a forked copy of that process is unsafe, and a
+    chip belongs to one process.  A spawned worker starts from a fresh
+    interpreter, imports only this module and the C columnizer, and
+    never imports jax.  Workers are reused across chunks/sweeps.
+    ``run`` is serialized by a lock — concurrent pipeline flatten-stage
+    threads take turns rather than interleaving pipe messages."""
 
     def __init__(self, workers: int):
         import multiprocessing as mp
 
-        # build + load the native module in the PARENT first so forked
-        # children inherit it loaded (two children racing the on-disk
+        # build the native module in the PARENT first: the workers then
+        # find the binary on disk (two children racing the on-disk
         # build would collide)
         from gatekeeper_tpu.ops import native
 
         native.load_json()
-        try:
-            ctx = mp.get_context("fork")
-        except ValueError:  # platforms without fork
-            ctx = mp.get_context("spawn")
+        ctx = mp.get_context("spawn")
         self.workers = workers
         self.dead = False
         self._lock = threading.Lock()
         self._procs: list = []
         self._conns: list = []
-        import warnings
-
         for _ in range(workers):
             parent_c, child_c = ctx.Pipe()
             p = ctx.Process(target=_flatten_worker_main, args=(child_c,),
                             daemon=True, name="flatten-worker")
-            with warnings.catch_warnings():
-                # jax registers an at-fork RuntimeWarning (XLA threads +
-                # fork CAN deadlock in general); these children run only
-                # Python + the C columnizer and never touch jax, and the
-                # repo promotes RuntimeWarning to error
-                warnings.simplefilter("ignore", RuntimeWarning)
-                p.start()
+            p.start()
             child_c.close()
             self._procs.append(p)
             self._conns.append(parent_c)
 
     # per-span reply deadline: a columnize is seconds at worst, so a
-    # worker silent this long is wedged (e.g. a bad fork interaction) —
-    # the pool dies and the batch falls back in-process rather than
-    # hanging the sweep
+    # worker silent this long is wedged — the pool dies and the batch
+    # falls back in-process rather than hanging the sweep
     REPLY_TIMEOUT_S = 120.0
 
     def run(self, jobs: list) -> list:

@@ -26,10 +26,10 @@ def _load_named(name: str, src_file: str) -> Optional[object]:
     _tried.add(name)
     if not os.path.exists(os.path.join(_NATIVE_DIR, src_file)):
         # no source (installed wheel): the prebuilt module is the only
-        # option.  When the source IS present, go through _build so its
-        # mtime staleness check runs even if a sibling module already put
-        # native/build on sys.path (an edited .c must not silently run as
-        # the previous binary)
+        # option.  When the source IS present, go through _build so the
+        # binary is the one keyed on this source's content even if a
+        # sibling module already put a build dir on sys.path (an edited
+        # .c must not silently run as the previous binary)
         try:
             import importlib
 
@@ -77,10 +77,19 @@ def _build_flags() -> list:
     )
 
 
-def _flag_digest(flags: list) -> str:
+def _build_digest(flags: list, src: str) -> str:
+    """Build-directory key: the compiler invocation AND the source
+    bytes.  A flag change (edited CFLAGS, GTPU_NATIVE_CFLAGS, another
+    compiler) or an edited ``.c`` lands in a fresh directory, so a
+    binary left on disk by another revision of the tree (``native/build``
+    is git-ignored but travels with a copied checkout) is never picked
+    up — mtimes say nothing about which source a binary came from."""
     import hashlib
 
-    return hashlib.sha256(" ".join(flags).encode()).hexdigest()[:12]
+    h = hashlib.sha256(" ".join(flags).encode())
+    with open(src, "rb") as f:
+        h.update(b"\0" + f.read())
+    return h.hexdigest()[:12]
 
 
 def _build(name: str, src_file: str):
@@ -88,21 +97,24 @@ def _build(name: str, src_file: str):
 
     src = os.path.abspath(os.path.join(_NATIVE_DIR, src_file))
     flags = _build_flags()
-    # the flag set is hashed into the output directory: a compile-flag
-    # change (edited CFLAGS, GTPU_NATIVE_CFLAGS, a different compiler)
-    # lands in a fresh dir and rebuilds — the mtime check alone silently
-    # reused the old binary under flag drift
-    out_dir = os.path.abspath(os.path.join(_BUILD_DIR, _flag_digest(flags)))
+    out_dir = os.path.abspath(
+        os.path.join(_BUILD_DIR, _build_digest(flags, src)))
     os.makedirs(out_dir, exist_ok=True)
     ext = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
     out = os.path.join(out_dir, name + ext)
-    if not os.path.exists(out) or (
-        os.path.getmtime(out) < os.path.getmtime(src)
-    ):
+    if not os.path.exists(out):
         include = sysconfig.get_path("include")
         np_include = np.get_include()
-        cmd = flags + [src, "-o", out, f"-I{include}", f"-I{np_include}"]
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        # compile to a temp name and rename: a crashed or concurrent
+        # build never leaves a half-written module under the keyed name
+        tmp = f"{out}.{os.getpid()}.tmp"
+        cmd = flags + [src, "-o", tmp, f"-I{include}", f"-I{np_include}"]
+        try:
+            subprocess.run(cmd, check=True, capture_output=True, text=True)
+            os.replace(tmp, out)
+        finally:
+            if os.path.exists(tmp):
+                os.remove(tmp)
     if out_dir not in sys.path:
         sys.path.insert(0, out_dir)
     import importlib

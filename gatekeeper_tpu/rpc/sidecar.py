@@ -325,6 +325,9 @@ def main(argv: Optional[list] = None) -> int:
     p.add_argument("--port", type=int, default=9090)
     p.add_argument("--violations-limit", type=int, default=20)
     args = p.parse_args(argv)
+    from gatekeeper_tpu.utils.xla_cache import configure_xla_cache
+
+    configure_xla_cache()
     server, bound, servicer = serve(args.port, args.violations_limit)
     import jax
 

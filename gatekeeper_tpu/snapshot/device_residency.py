@@ -4,8 +4,8 @@ The snapshot store (snapshot/store.py) already keeps per-group tall
 ColumnBatches resident HOST-side and ticks O(churn) — but every sweep
 chunk still pays slice_rows (host gather) + pack_transfer_cols (host
 pack) + device_put (H2D wire) for rows that have not changed since the
-last tick (SWEEP1M: 119MB H2D per 1M-object sweep, all of it re-upload
-of clean rows).  This module promotes residency one level:
+last tick (119MB H2D per 1M-object sweep at the full library, all of
+it re-upload of clean rows).  This module promotes residency one level:
 
 - each routed :class:`GroupStore`'s tall batch lives ON DEVICE as the
   same dtype-packed transfer buffers a sweep dispatch would build
@@ -36,9 +36,9 @@ Degradation: the built-in ``device_residency_evict`` action
 (resilience/overload.py) demotes every resident group back to host
 columns on an SLO breach — ``prepare`` polls it, frees the device
 buffers, and falls back until the action releases (re-upload is lazy).
-Hosts without an accelerator degrade the same way automatically
-(mode "auto"), with the reason logged once — tier-1 stays green on the
-1-core CPU host while mode "on" keeps the lane testable everywhere.
+An evaluator whose mesh is on the CPU declines the same way (mode
+"auto"), with the reason logged once; mode "on" keeps the lane testable
+there.
 """
 
 from __future__ import annotations
@@ -165,17 +165,11 @@ class DeviceResidency:
             self._log_fallback("multi-chip mesh (resident gather is "
                               "single-chip; see ROADMAP NEXT)")
             return False
-        if self.mode == "auto":
-            import jax
-
-            try:
-                backend = jax.default_backend()
-            except Exception:
-                backend = "cpu"
-            if backend == "cpu":
-                self._log_fallback("no accelerator (mode=auto on a CPU "
-                                  "host)")
-                return False
+        if self.mode == "auto" \
+                and ev.mesh.devices.flat[0].platform == "cpu":
+            self._log_fallback("no accelerator (mode=auto on a CPU "
+                              "mesh)")
+            return False
         from gatekeeper_tpu.resilience.overload import (
             DEVICE_RESIDENCY_EVICT, degradation_active)
 

@@ -771,6 +771,9 @@ class Batcher:
                 sp.add_event("deadline_exceeded", component="batcher")
                 raise DeadlineExceeded("batched review outlived the "
                                        "request deadline budget")
+            # which lane answered THIS request (the flush span carries
+            # it too, but parents into the first entry's trace only)
+            sp.set_attribute("lane", slot.get("lane", ""))
         if "error" in slot:
             raise slot["error"]
         return slot["responses"]
@@ -821,6 +824,8 @@ class Batcher:
 
             lane = ("interp" if len(batch) <= self.small_batch
                     else "grid")
+            for entry in batch:
+                entry[2]["lane"] = lane
             try:
                 # the flush span lives on the batch thread, parented into
                 # the FIRST entry's trace (its request waited longest);

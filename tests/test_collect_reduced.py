@@ -232,3 +232,34 @@ def test_complete_overflow_falls_back_bit_identically(world):
     _assert_runs_identical(run_m, run_r)
     assert ev_r.perf.get("collect_fallbacks", 0) > 0
     assert state["pinned"] or state["cap"] > 8
+
+
+# --- budgeted buffer sizing across passes ----------------------------------
+
+def test_budget_sizing_forgets_the_previous_pass():
+    """The hit buffer is sized from the previous chunk's selection — but
+    a NEW pass starts with its kept budgets reset, and the previous
+    pass's drained tail must not size its head chunks (every pass after
+    the first re-dispatched them through the masks lane: an overflow, a
+    second dispatch and, the first time, a compile inside the pass)."""
+    from types import SimpleNamespace
+
+    from gatekeeper_tpu.parallel.sharded import make_mesh
+
+    ev = ShardedEvaluator(None, make_mesh(1), violations_limit=20)
+    cons = [object() for _ in range(4)]
+    left = {"v": 20}
+    flat = SimpleNamespace(kinds=("K",), by_kind={"K": cons}, pad_n=64,
+                           budget=lambda _con: left["v"])
+    full = 4 * 20
+    _b, cap = ev._budget_hit_cap(flat, 4, 20)
+    assert cap == full                       # first chunk: no history
+    ev._hit_state_for(("K",), 64)["blast"] = 3   # its collect reports 3
+    left["v"] = 1
+    _b, cap = ev._budget_hit_cap(flat, 4, 20)
+    assert cap == 16                         # drained: smallest step
+    ev._hit_state_for(("K",), 64)["blast"] = 0   # the pass's tail
+    left["v"] = 20                           # next pass: budgets reset
+    for _ in range(2):   # pipelined: two dispatches before any collect
+        budget, cap = ev._budget_hit_cap(flat, 4, 20)
+        assert cap == full and budget.tolist() == [20] * 4

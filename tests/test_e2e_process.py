@@ -87,6 +87,23 @@ def test_audit_once_end_to_end(manifest_dir):
     assert "good-pod" not in proc.stdout
 
 
+def test_audit_once_incomplete_exits_nonzero(manifest_dir, tmp_path):
+    """A one-shot audit whose device dispatches all fail drops its
+    chunks: the run is marked incomplete AND the process says so with
+    its exit code (a pass without verdicts is not a success)."""
+    spec = tmp_path / "chaos.json"
+    spec.write_text(json.dumps({"seed": 0, "faults": [
+        {"site": "device.dispatch", "mode": "error"}]}))
+    proc = subprocess.run(
+        [sys.executable, "-m", "gatekeeper_tpu", "--manifests", manifest_dir,
+         "--once", "--chaos", str(spec)],
+        capture_output=True, text=True, timeout=180, cwd=REPO, env=_env(),
+    )
+    assert proc.returncode == 1, proc.stderr[-2000:]
+    assert "[INCOMPLETE" in proc.stderr, proc.stderr[-2000:]
+    assert "bad-pod" not in proc.stdout
+
+
 def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))

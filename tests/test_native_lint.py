@@ -1,5 +1,5 @@
 """Native-kernel gates: warning-clean strict compiles in tier-1, the
-ASan/UBSan corpus run slow-marked, and the ops/native.py flag-digest
+ASan/UBSan corpus run slow-marked, and the ops/native.py build-key
 rebuild semantics (a compile-flag change must never silently reuse the
 previous binary)."""
 
@@ -30,7 +30,7 @@ def test_native_asan_corpus():
     assert ok, f"sanitizer corpus run failed:\n{out}"
 
 
-# --- flag-digest rebuild semantics (ops/native._build) -----------------
+# --- build-key rebuild semantics (ops/native._build) -------------------
 
 _TRIVIAL_MOD = textwrap.dedent("""\
     #define PY_SSIZE_T_CLEAN
@@ -51,9 +51,10 @@ def _expected_out(name):
     import sysconfig
 
     ext = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
+    src = os.path.join(native._NATIVE_DIR, name + ".c")
     return os.path.join(
         os.path.abspath(native._BUILD_DIR),
-        native._flag_digest(native._build_flags()), name + ext)
+        native._build_digest(native._build_flags(), src), name + ext)
 
 
 @pytest.fixture
@@ -82,9 +83,8 @@ def test_build_reuses_fresh_binary(build_env):
 
 
 def test_build_flag_drift_lands_in_new_dir(build_env, monkeypatch):
-    """The regression this guards: _build used to compare source mtime
-    only, so an edited flag set silently reused the stale binary.  The
-    flag digest is part of the output path — drift compiles fresh."""
+    """An edited flag set must not reuse the binary built under the old
+    one: the flags are part of the build key — drift compiles fresh."""
     src = build_env("gtpu_lint_t2")
     native._build("gtpu_lint_t2", src)
     plain_out = _expected_out("gtpu_lint_t2")
@@ -98,8 +98,34 @@ def test_build_flag_drift_lands_in_new_dir(build_env, monkeypatch):
     assert os.path.exists(plain_out), "drift build clobbered the original"
 
 
-def test_flag_digest_depends_on_flags():
-    a = native._flag_digest(["cc", "-O3"])
-    b = native._flag_digest(["cc", "-O3", "-DX"])
-    assert a != b
-    assert native._flag_digest(["cc", "-O3"]) == a
+def test_edited_source_lands_in_new_dir(build_env):
+    """The build key hashes the source CONTENT: an edited .c compiles
+    into a fresh directory, and a binary that is merely newer on disk
+    (a git-ignored build dir copied along with another revision of the
+    tree) can never stand in for it."""
+    src = build_env("gtpu_lint_t3")
+    native._build("gtpu_lint_t3", src)
+    old_out = _expected_out("gtpu_lint_t3")
+    assert os.path.exists(old_out)
+    path = os.path.join(native._NATIVE_DIR, src)
+    with open(path, "a") as f:
+        f.write("/* edited */\n")
+    # make the OLD binary look newer than the edited source: an mtime
+    # comparison would have kept it
+    st = os.stat(path)
+    os.utime(old_out, (st.st_atime + 3600, st.st_mtime + 3600))
+    new_out = _expected_out("gtpu_lint_t3")
+    assert os.path.dirname(new_out) != os.path.dirname(old_out)
+    assert not os.path.exists(new_out)
+    native._build("gtpu_lint_t3", src)
+    assert os.path.exists(new_out), "edited source did not rebuild"
+
+
+def test_build_digest_depends_on_flags_and_source(tmp_path):
+    a_src, b_src = tmp_path / "a.c", tmp_path / "b.c"
+    a_src.write_text("int a;\n")
+    b_src.write_text("int b;\n")
+    a = native._build_digest(["cc", "-O3"], str(a_src))
+    assert native._build_digest(["cc", "-O3", "-DX"], str(a_src)) != a
+    assert native._build_digest(["cc", "-O3"], str(b_src)) != a
+    assert native._build_digest(["cc", "-O3"], str(a_src)) == a

@@ -1,10 +1,13 @@
 """The Pallas verdict-epilogue kernel must agree with the XLA twin
 (parallel.sharded.topk_violations) under the valid-mask, for every grid
-shape class the sweep produces.  Off-TPU the kernel runs in interpret
-mode — same kernel logic, plain-JAX execution."""
+shape class the sweep produces.  These tests run the kernel through the
+Pallas interpreter (``interpret=True`` — an argument only tests pass;
+production call sites always compile it with Mosaic, and chip_smoke.py
+repeats the comparison on the chip)."""
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from gatekeeper_tpu.ops.pallas_topk import (fused_fold_pallas,
                                             topk_violations_counts_pallas,
@@ -15,7 +18,7 @@ from gatekeeper_tpu.parallel.sharded import topk_violations
 def _agree(verdicts: np.ndarray, k: int):
     g = jnp.asarray(verdicts)
     xi, xv = topk_violations(g, k)
-    pi, pv, pc = topk_violations_counts_pallas(g, k)
+    pi, pv, pc = topk_violations_counts_pallas(g, k, interpret=True)
     xi, xv = np.asarray(xi), np.asarray(xv)
     pi, pv = np.asarray(pi), np.asarray(pv)
     assert np.array_equal(xv, pv), "valid masks differ"
@@ -48,6 +51,26 @@ def test_k_beyond_lane_tile_falls_back():
     _agree(v, 200)
 
 
+def test_lane_tiling_carries_selection_across_tiles():
+    """N beyond one lane tile: the first-k selection and the counts
+    accumulate across tiles (hits before, inside and after tile
+    boundaries, rows that fill up early, rows whose hits start late)."""
+    from gatekeeper_tpu.ops.pallas_topk import _TN
+
+    rng = np.random.default_rng(7)
+    n = 3 * _TN + 640                      # ragged last tile (padded)
+    v = rng.random((9, n)) < 0.002
+    v[0] = False
+    v[0, [_TN - 1, _TN, 2 * _TN + 5]] = True   # straddles boundaries
+    v[1] = False
+    v[1, 3 * _TN + 600:] = True                # hits only in the tail
+    v[2, :_TN] = True                          # full before tile 1
+    v[3] = False
+    _agree(v, 20)
+    mask = rng.random((9, n)) < 0.6
+    _fold_agree(v, mask, 20)
+
+
 def test_row_padding_to_sublane_tile():
     rng = np.random.default_rng(2)
     for c in (1, 7, 8, 9, 46):
@@ -62,7 +85,7 @@ def _fold_agree(grid_raw: np.ndarray, mask: np.ndarray, k: int):
     g, m = jnp.asarray(grid_raw), jnp.asarray(mask)
     masked = grid_raw & mask
     xi, xv = topk_violations(jnp.asarray(masked), min(k, masked.shape[1]))
-    pi, pv, pc, po = fused_fold_pallas(g, m, k)
+    pi, pv, pc, po = fused_fold_pallas(g, m, k, interpret=True)
     xi, xv = np.asarray(xi), np.asarray(xv)
     pi, pv = np.asarray(pi), np.asarray(pv)
     assert np.array_equal(xv, pv), "valid masks differ"
@@ -107,7 +130,18 @@ def test_first_k_are_lowest_indices():
     v = np.zeros((2, 256), bool)
     hits = [5, 17, 99, 100, 255]
     v[0, hits] = True
-    idx, valid = topk_violations_pallas(jnp.asarray(v), 3)
+    idx, valid = topk_violations_pallas(jnp.asarray(v), 3,
+                                        interpret=True)
     assert np.asarray(idx)[0, :3].tolist() == hits[:3]
     assert np.asarray(valid)[0].tolist() == [True, True, True]
     assert not np.asarray(valid)[1].any()
+
+
+def test_production_call_sites_never_interpret():
+    """Without the test-only argument the kernel goes to the compiler:
+    off the TPU that is an error, never a silent interpreter run."""
+    v = jnp.zeros((8, 256), bool)
+    with pytest.raises(ValueError, match="interpret mode"):
+        topk_violations_counts_pallas(v, 3)
+    with pytest.raises(ValueError, match="interpret mode"):
+        fused_fold_pallas(v, v, 3)

@@ -37,7 +37,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def build_setup(n_objects: int):
@@ -186,13 +185,14 @@ def run(n_objects: int = 200, passes: int = 5,
             return 0.0
         return statistics.median(abs(x - m) for x in xs) / m
 
+    import jax
+
     entry = {
         "kind": "obs_overhead",
         "note": "instrumented (metrics+attribution+flightrec+tracer) "
                 "vs bare, serial schedule",
         "date": time.strftime("%Y-%m-%d"),
-        "platform": "cpu" if os.environ.get(
-            "JAX_PLATFORMS", "") == "cpu" else "tpu",
+        "platform": jax.devices()[0].platform,
         "host_cpus": os.cpu_count(),
         "objects": n_objects,
         "admissions": len(bodies),

@@ -71,7 +71,7 @@ def asan_corpus_run(timeout_s: float = 600.0) -> tuple:
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": "cpu",
-        # the flag-digest build dir (ops/native._build) keys on this:
+        # the build-dir key (ops/native._build) includes this:
         # the sanitized build lands beside, never instead of, the
         # production binary
         "GTPU_NATIVE_CFLAGS":

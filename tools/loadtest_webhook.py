@@ -1,12 +1,14 @@
-"""Webhook serving-layer load test (VERDICT r1 #10).
+"""Webhook serving-layer load test.
 
 Drives the real WebhookServer (TLS off) with concurrent AdmissionReview
 POSTs over persistent connections, through the full stack: HTTP parse →
 ValidationHandler → Batcher microbatch lane → device verdict grids →
 deny/warn partition.  Reports throughput + a latency histogram and writes
-WEBHOOK_LOAD.json at the repo root.
+WEBHOOK_LOAD.json at the repo root.  Server and load generator share ONE
+process (a chip belongs to one process), on whatever platform JAX
+selects; the record carries it.
 
-    JAX_PLATFORMS=cpu python tools/loadtest_webhook.py [n_requests] [conc]
+    python tools/loadtest_webhook.py [n_requests] [conc]
 
 The reference's concurrency model is goroutine-per-request capped by
 --max-serving-threads (pkg/webhook/policy.go:116-120); here the cap is the
@@ -24,7 +26,6 @@ import threading
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def build_server():
@@ -159,93 +160,7 @@ def warmup(port: int, bodies: list, k: int = 8) -> None:
     conn.close()
 
 
-def serve_worker(port: int) -> None:
-    """--worker mode: a full serving replica bound with SO_REUSEPORT;
-    prints its served-request count on SIGTERM (the parent asserts the
-    kernel spread load across replicas)."""
-    import signal
-
-    from gatekeeper_tpu.apis.constraints import AUDIT_EP, WEBHOOK_EP
-    from gatekeeper_tpu.client.client import Client
-    from gatekeeper_tpu.drivers.cel_driver import CELDriver
-    from gatekeeper_tpu.drivers.tpu_driver import TpuDriver
-    from gatekeeper_tpu.metrics.registry import MetricsRegistry
-    from gatekeeper_tpu.target.target import K8sValidationTarget
-    from gatekeeper_tpu.utils.synthetic import load_library
-    from gatekeeper_tpu.webhook.policy import Batcher, ValidationHandler
-    from gatekeeper_tpu.webhook.server import WebhookServer
-
-    cel = CELDriver()
-    tpu = TpuDriver(cel_driver=cel)
-    client = Client(target=K8sValidationTarget(), drivers=[tpu, cel],
-                    enforcement_points=[WEBHOOK_EP, AUDIT_EP])
-    load_library(client)
-    metrics = MetricsRegistry()
-    batcher = Batcher(client, window_s=0.002, max_batch=64).start()
-    handler = ValidationHandler(client, batcher=batcher, metrics=metrics)
-    srv = WebhookServer(validation_handler=handler, port=port,
-                        readiness_check=lambda: True,
-                        reuse_port=True).start()
-    print(f"worker {os.getpid()} on :{srv.port}", file=sys.stderr,
-          flush=True)
-    stop = threading.Event()
-
-    def _term(signum, frame):
-        stop.set()
-
-    signal.signal(signal.SIGTERM, _term)
-    stop.wait()
-    served = metrics.counter_total("validation_request_count")
-    print(json.dumps({"pid": os.getpid(), "served": served}), flush=True)
-    srv.stop()
-
-
-def multi_worker_lane(bodies: list, n: int, conc: int,
-                      n_workers: int = 2) -> dict:
-    """SO_REUSEPORT lane: W independent serving processes share one port;
-    the kernel balances connections.  Verifies every worker served
-    traffic and reports aggregate throughput."""
-    import socket
-    import subprocess
-
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-    procs = [subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__), "--worker", str(port)],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    ) for _ in range(n_workers)]
-    # wait for all workers to bind + warm
-    deadline = time.time() + 300
-    while time.time() < deadline:
-        try:
-            warmup(port, bodies, k=2)
-            break
-        except OSError:
-            time.sleep(1.0)
-    time.sleep(n_workers * 2)  # let every replica finish loading
-    warmup(port, bodies, k=16)
-    stats = run_load(port, bodies, n, conc)
-    served = []
-    for p in procs:
-        p.terminate()
-        out, _ = p.communicate(timeout=30)
-        for line in out.splitlines():
-            try:
-                served.append(json.loads(line))
-            except ValueError:
-                pass
-    stats["workers"] = served
-    stats["all_workers_served"] = (
-        len(served) == n_workers and all(w["served"] > 0 for w in served))
-    return stats
-
-
 def main():
-    if len(sys.argv) > 2 and sys.argv[1] == "--worker":
-        serve_worker(int(sys.argv[2]))
-        return
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 2000
     conc = int(sys.argv[2]) if len(sys.argv) > 2 else 64
     srv, batcher, nt, nc = build_server()
@@ -267,27 +182,20 @@ def main():
     lane_sat = run_load(srv.port, bodies, n, conc)
     batcher.stop()
     srv.stop()
-    # lane 4: SO_REUSEPORT multi-process serving
-    print("lane multi-worker (SO_REUSEPORT x2)...", file=sys.stderr)
-    lane_mw = multi_worker_lane(bodies, n, conc, n_workers=2)
+    import jax
 
     out = {
         "metric": "webhook serving load",
+        "platform": jax.devices()[0].platform,
         "host_cpus": os.cpu_count(),
         "batch_window_ms": 2.0,
         "n1": lane_n1,
         "conc8": lane_c8,
         f"conc{conc}": lane_sat,
-        "multiworker2": lane_mw,
         "server": "stdlib ThreadingHTTPServer (thread-per-connection; the "
                   "Batcher coalesces concurrent reviews so handler threads "
                   "block on the shared device pass, not on per-request "
-                  "evaluation); SO_REUSEPORT worker processes for "
-                  "multi-core hosts (--webhook-workers)",
-        "note": "this bench host has ONE core: saturation latency is "
-                "queueing delay (Little's law), and worker processes "
-                "cannot add throughput here — the n1/conc8 lanes plus "
-                "all_workers_served are the meaningful signals",
+                  "evaluation)",
     }
     print(json.dumps(out, indent=1))
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
