@@ -263,9 +263,13 @@ class AuditManager:
         # (the evaluator tracks its own flatten/masks/wire/dispatch/collect)
         self.perf: dict = {}
         # per-stage breakdown of the last pipelined sweep (JSON-ready dict
-        # from pipeline.executor.PipelineRun.summary + device-idle proxy);
-        # None when the last sweep ran the serial schedule
+        # from pipeline.executor.PipelineRun.summary + the collect stage's
+        # head-of-line wait); None when the last sweep ran the serial
+        # schedule
         self.pipe_stats: Optional[dict] = None
+
+    def _perf_add(self, key: str, value: float) -> None:
+        self.perf[key] = self.perf.get(key, 0.0) + value
 
     # --- spill persistence (snapshot/persist.py) -------------------------
     def attach_spiller(self, spiller) -> None:
@@ -350,8 +354,7 @@ class AuditManager:
                     "stage_busy_sum_s",
                     self.pipe_stats.get("stage_busy_sum_s"))
                 sp.set_attribute(
-                    "device_idle_fraction",
-                    self.pipe_stats.get("device_idle_fraction"))
+                    "device_wait_s", self.pipe_stats.get("device_wait_s"))
                 sp.set_attribute(
                     "overlap_ratio", self.pipe_stats.get("overlap_ratio"))
             return run
@@ -478,10 +481,20 @@ class AuditManager:
         run.total_violations = totals
         run.kept = kept
         run.duration_s = time.time() - t0
-        self._write_statuses(run, constraints)
-        self._publish_metrics(run)
-        self._finish(run)
+        self._report(run, constraints)
         return run
+
+    def _report(self, run: AuditRun, constraints) -> None:
+        """The pass's epilogue, after its last fold: constraint statuses,
+        metrics, the export / log / event sinks."""
+        from gatekeeper_tpu.observability import tracing
+
+        t0 = time.perf_counter()
+        with tracing.span("audit.report"):
+            self._write_statuses(run, constraints)
+            self._publish_metrics(run)
+            self._finish(run)
+        self._perf_add("report", time.perf_counter() - t0)
 
     # --- snapshot lane (gatekeeper_tpu/snapshot/) -------------------------
     def _snapshot_mode(self) -> bool:
@@ -592,9 +605,7 @@ class AuditManager:
                 self.metrics.set_gauge(M.TICK_H2D_BYTES,
                                        float(tick_h2d), labels)
         snap.publish_metrics()
-        self._write_statuses(run, constraints)
-        self._publish_metrics(run)
-        self._finish(run)
+        self._report(run, constraints)
         return run
 
     def _snapshot_eval(self, rows_by_store, run) -> None:
@@ -684,9 +695,7 @@ class AuditManager:
                         self._fold_snapshot_chunk(swept, cons_g, gids,
                                                   objects)
                         snap.mark_clean(gids)
-                        self.perf["fold_render"] = (
-                            self.perf.get("fold_render", 0.0)
-                            + time.perf_counter() - t0)
+                        self._perf_add("fold_render", time.perf_counter() - t0)
                     except Exception as e:
                         chunk_failed(e)
 
@@ -771,13 +780,14 @@ class AuditManager:
 
         return render
 
-    @staticmethod
-    def _attr_render(con, dt: float) -> None:
-        """Exact per-template attribution of one exact-engine render
-        (the host-side cost of a device hit) — no apportioning needed,
-        the call IS template-scoped."""
+    def _attr_render(self, con, dt: float) -> None:
+        """One exact-engine render's seconds (the host-side cost of a
+        device hit): into ``perf["render"]`` beside ``n_renders``, and
+        to its template exactly — no apportioning needed, the call IS
+        template-scoped."""
         from gatekeeper_tpu.observability import costattr
 
+        self._perf_add("render", dt)
         attr = costattr.active()
         if attr is not None:
             attr.record(con.kind, costattr.EP_AUDIT,
@@ -1159,10 +1169,7 @@ class AuditManager:
         holds ``audit_yield_release`` (yield_device_lane checks it)."""
         from gatekeeper_tpu.resilience import overload
 
-        waited = overload.yield_device_lane(cluster=self.cluster)
-        if waited:
-            self.perf["brownout_yield_s"] = (
-                self.perf.get("brownout_yield_s", 0.0) + waited)
+        overload.yield_device_lane(cluster=self.cluster)
 
     def _resync_deferred(self) -> bool:
         """``resync_defer`` degradation action: a breaching
@@ -1578,9 +1585,7 @@ class AuditManager:
                     t0 = time.perf_counter()
                     self._process_swept(swept, objs, cons, kept, totals,
                                         limit)
-                    self.perf["fold_render"] = (
-                        self.perf.get("fold_render", 0.0)
-                        + time.perf_counter() - t0)
+                    self._perf_add("fold_render", time.perf_counter() - t0)
                 except Exception as e:
                     chunk_failed(e, "fold")
 
@@ -1610,8 +1615,6 @@ class AuditManager:
                         waitq.put(pending)
                 while window and (len(window) > max_inflight
                                   or _sweep_ready(window[0][0])):
-                    self.perf["n_eager_collects"] = (
-                        self.perf.get("n_eager_collects", 0) + 1)
                     fold_oldest()
             else:
                 # interpreter lane: evaluate into CHUNK-LOCAL dicts and
@@ -1644,11 +1647,11 @@ class AuditManager:
                                           use_router, counter))
             chunk_i = -1
             while True:
+                t0 = time.perf_counter()
                 try:
-                    objs, cons = next(src)
-                    chunk_i += 1
-                except StopIteration:
-                    break
+                    with tracing.span("audit.chunk.list",
+                                      chunk=chunk_i + 1):
+                        item = next(src, None)
                 except Exception as e:
                     # the lister died mid-iteration — a generator cannot
                     # resume, so finish with what was listed and mark the
@@ -1662,8 +1665,12 @@ class AuditManager:
                               "with partial results",
                               event_type="audit_lister_failed",
                               error=str(e))
+                    item = None
+                self._perf_add("list", time.perf_counter() - t0)
+                if item is None:
                     break
-                submit(objs, cons, chunk_i)
+                chunk_i += 1
+                submit(*item, chunk_i)
             while window:  # drain: blocking collect of the tail chunks
                 fold_oldest()
         finally:
@@ -1727,9 +1734,7 @@ class AuditManager:
             swept, objs, cons = item
             t0 = time.perf_counter()
             self._process_swept(swept, objs, cons, kept, totals, limit)
-            self.perf["fold_render"] = (
-                self.perf.get("fold_render", 0.0)
-                + time.perf_counter() - t0)
+            self._perf_add("fold_render", time.perf_counter() - t0)
             return None
 
         from gatekeeper_tpu.pipeline import effective_cpu_count
@@ -1765,25 +1770,29 @@ class AuditManager:
                     {"dependency": "audit_pipeline"},
                     value=float(n_retries))
         stats = pr.summary()
-        # device-idle proxy: the collect stage blocks exactly while the
-        # device (or wire) is still producing the head-of-line result;
-        # the rest of the wall the chip had nothing in flight to finish.
-        # An upper bound on device busy (it includes wire drain), hence a
-        # LOWER bound on idle-fraction improvements it reports.
-        coll_s = pr.stage("collect")
-        device_wait = coll_s.busy_s if coll_s is not None else 0.0
+        # the collect stage is busy exactly while it waits for the
+        # head-of-line chunk's result and fetches it.  HOST time: what the
+        # device itself did in that wait only a device trace can say.
+        device_wait = pr.stage("collect").busy_s
         stats["device_wait_s"] = round(device_wait, 3)
-        stats["device_idle_fraction"] = (
-            round(max(0.0, 1.0 - device_wait / pr.wall_s), 3)
-            if pr.wall_s > 0 else 0.0)
         self.pipe_stats = stats
-        self.perf["pipe_wall"] = (
-            self.perf.get("pipe_wall", 0.0) + pr.wall_s)
-        self.perf["pipe_stage_busy_sum"] = (
-            self.perf.get("pipe_stage_busy_sum", 0.0)
-            + pr.stage_busy_sum())
-        self.perf["pipe_device_wait"] = (
-            self.perf.get("pipe_device_wait", 0.0) + device_wait)
+        # the pass's account, summed over passes.  The calling thread's:
+        # list + pipe_source_stall + pipe_drain == pipe_wall.  Each
+        # stage's: busy - cpu is time its thread held a chunk and did not
+        # run (GIL wait, or a call that released it), wait and stall are
+        # time it had no chunk or could not hand one on — together they
+        # tell a GIL-bound pipeline from a starved one.
+        self._perf_add("pipe_wall", pr.wall_s)
+        self._perf_add("pipe_device_wait", device_wait)
+        self._perf_add("list", pr.source_busy_s)
+        self._perf_add("list_cpu", pr.source_cpu_s)
+        self._perf_add("pipe_source_stall", pr.source_stall_s)
+        self._perf_add("pipe_drain", pr.drain_s)
+        for st in pr.stages:
+            for what in ("busy", "wait", "stall", "cpu"):
+                self._perf_add(f"pipe_{st.name}_{what}",
+                               getattr(st, what + "_s"))
+            self.perf[f"pipe_{st.name}_workers"] = float(st.workers)
 
     @staticmethod
     def _schedules_differ(kept_a, totals_a, kept_b, totals_b):
@@ -1837,9 +1846,8 @@ class AuditManager:
                                    s["occupancy"], lab)
             self.metrics.set_gauge(M.PIPELINE_QUEUE_HIGHWATER,
                                    s["queue_highwater"], lab)
-        self.metrics.set_gauge(
-            M.PIPELINE_DEVICE_IDLE,
-            self.pipe_stats.get("device_idle_fraction", 0.0))
+        self.metrics.set_gauge(M.PIPELINE_DEVICE_WAIT,
+                               self.pipe_stats.get("device_wait_s", 0.0))
         # sweep-level aggregates (previously only in the bench JSON):
         # wall vs summed stage busy is the overlap proof, scrapeable now
         self.metrics.set_gauge(M.PIPELINE_WALL,

@@ -363,11 +363,12 @@ WATCH_GVKS = "watch_manager_watched_gvk"
 # staged host-pipeline instrumentation (pipeline/executor.py via the
 # audit manager): per-stage busy seconds / occupancy (busy over pipeline
 # wall) / input-queue depth high-water, all labelled {stage=...}, plus
-# the device-idle proxy (1 - head-of-line device wait / wall)
+# the collect stage's head-of-line wait for the device (HOST seconds of
+# the last pipelined sweep; the device's own idle share needs a trace)
 PIPELINE_STAGE_SECONDS = "audit_pipeline_stage_seconds"
 PIPELINE_STAGE_OCCUPANCY = "audit_pipeline_stage_occupancy"
 PIPELINE_QUEUE_HIGHWATER = "audit_pipeline_queue_depth_highwater"
-PIPELINE_DEVICE_IDLE = "audit_pipeline_device_idle_fraction"
+PIPELINE_DEVICE_WAIT = "audit_pipeline_device_wait_seconds"
 # TPU lowering coverage: templates whose compile lowered onto the device
 # verdict path vs templates that fell back to the exact interpreter
 # (labelled {kind=..., engine=rego|cel}); a user template silently losing
@@ -388,10 +389,9 @@ RESILIENCE_STALE_SERVED = "resilience_stale_served_count"  # {dependency}
 RESILIENCE_DEGRADED = "resilience_degraded_count"  # {component, to}
 RESILIENCE_CHUNKS_FAILED = "resilience_audit_chunks_failed_count"
 # sweep-level pipeline aggregates (the ROADMAP's "read stage_busy_sum_s
-# vs wall_s + device_idle_fraction" numbers, scraped instead of dug out
-# of the bench JSON): wall seconds of the last pipelined sweep, the sum
-# of stage busy seconds across stages (> wall == measured overlap), and
-# the device-idle proxy already exported above
+# vs wall_s" numbers, scraped instead of dug out of the bench JSON): wall
+# seconds of the last pipelined sweep and the sum of stage busy seconds
+# across stages (> wall == measured overlap)
 PIPELINE_WALL = "audit_pipeline_wall_seconds"
 PIPELINE_STAGE_BUSY_SUM = "audit_pipeline_stage_busy_sum_seconds"
 # span tracer (observability/tracing.py): tail-sampler outcomes — how

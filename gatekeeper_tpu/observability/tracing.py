@@ -26,11 +26,16 @@ the resilience layer's :class:`Deadline` budget already uses:
 
 With no tracer installed every entry point (:func:`span`,
 :func:`add_event`, :func:`current_span`) is one contextvar read plus one
-global read — nanoseconds, no locks, no behavior change.  Cross-thread
-propagation (batcher lane, pipeline stage workers, the webhook deadline
-helper thread) is explicit: capture :func:`current_span` on the
-submitting thread, re-enter it with :func:`use_span` (or pass it as
-``parent=``) on the worker.
+global read — nanoseconds, no locks, no behavior change — and
+``gc.callbacks`` holds no hook of this module.  While a tracer is active
+one hook turns every FULL collection of CPython's collector into a
+``runtime.gc.full`` span under whatever span was open on the collecting
+thread, so a stall on the timeline can be put down to the collector.
+
+Cross-thread propagation (batcher lane, pipeline stage workers, the
+webhook deadline helper thread) is explicit: capture :func:`current_span`
+on the submitting thread, re-enter it with :func:`use_span` (or pass it
+as ``parent=``) on the worker.
 
 W3C trace-context interop: :func:`parse_traceparent` ingests an incoming
 ``traceparent`` header as a remote parent (the webhook HTTP path), and
@@ -41,6 +46,7 @@ calls (external-data provider sends, apiserver requests).
 from __future__ import annotations
 
 import contextvars
+import gc
 import random
 import threading
 import time
@@ -79,8 +85,8 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.is_root = is_root
-        self.start_ts = tracer._wall()
         self._t0 = tracer._clock()
+        self.start_ts = tracer._wall_at(self._t0)
         self.duration_s = 0.0
         self.attributes: dict = {}
         self.events: list = []
@@ -95,8 +101,8 @@ class Span:
         self.attributes[key] = value
 
     def add_event(self, name: str, **attrs: Any) -> None:
-        self.events.append({"ts": self._tracer._wall(), "name": name,
-                            "attrs": attrs})
+        self.events.append({"ts": self._tracer._wall_at(
+            self._tracer._clock()), "name": name, "attrs": attrs})
 
     def set_status(self, status: str, error: str = "") -> None:
         self.status = status
@@ -159,7 +165,12 @@ class Tracer:
                  metrics=None):
         self._rng = random.Random(seed)
         self._clock = clock
-        self._wall = wall
+        # one clock per span: the wall clock is read ONCE, here, and every
+        # timestamp after is this anchor plus elapsed monotonic time — a
+        # span's start and its duration cannot disagree when the wall
+        # clock steps, and a child never ends past its parent
+        self._mono0 = clock()
+        self._wall0 = wall()
         self.slow_threshold_s = slow_threshold_s
         self.sample_rate = float(sample_rate)
         self.max_spans_per_trace = max_spans_per_trace
@@ -171,6 +182,23 @@ class Tracer:
         self.kept = 0
         self.sampled_out = 0
         self.span_count = 0  # spans STARTED (includes sampled-out traces)
+        # spans recorded after the fact (_finished_span): the full
+        # collections of CPython's collector, from the gc.callbacks hook
+        # below.  A collection can start on ANY allocation — inside
+        # start_span / end_span with _lock held too — so the hook only
+        # appends here (lock-free) and end_span files the span dicts
+        # under its lock.  IDs come from an RNG of their own: a
+        # collection must not shift a seeded run's sequence.
+        self._finished_rng = random.Random(
+            None if seed is None else f"gc:{seed}")
+        self._gc_t0: Optional[float] = None
+        self._finished: deque = deque()
+        self.gc_full_unparented = 0  # collections with no ambient span
+        self.gc_full_unparented_s = 0.0
+
+    def _wall_at(self, mono: float) -> float:
+        """Wall-clock seconds of a reading of the monotonic clock."""
+        return self._wall0 + (mono - self._mono0)
 
     # --- IDs --------------------------------------------------------------
     def _gen_trace_id(self) -> str:
@@ -204,6 +232,11 @@ class Tracer:
     def end_span(self, s: Span) -> None:
         s.duration_s = self._clock() - s._t0
         with self._lock:
+            while self._finished:
+                # a collection ends inside its parent span, so it is filed
+                # before that span (or any later one) ends
+                d = self._finished.popleft()
+                self._pending.setdefault(d["trace_id"], []).append(d)
             buf = self._pending.setdefault(s.trace_id, [])
             if len(buf) < self.max_spans_per_trace:
                 buf.append(s.to_dict())
@@ -244,6 +277,49 @@ class Tracer:
             "spans": spans,
         })
 
+    def _on_full_gc(self, phase: str, info: dict) -> None:
+        """One full collection -> a ``runtime.gc.full`` span under the
+        span ambient on the thread that triggered it.  With no ambient
+        span it is only counted: a root per collection would push real
+        traces out of the ring.  Collections never nest, so one start
+        slot is enough."""
+        now = self._clock()
+        if phase == "start":
+            self._gc_t0 = now
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None:
+            return  # hooked in the middle of this collection
+        parent = _ctx_span.get()
+        if getattr(parent, "_tracer", None) is not self:
+            self.gc_full_unparented += 1
+            self.gc_full_unparented_s += now - t0
+            return
+        self._finished_span(
+            "runtime.gc.full", parent, t0, now,
+            collected=info.get("collected", 0),
+            uncollectable=info.get("uncollectable", 0))
+
+    def _finished_span(self, name: str, parent: Span, t0: float,
+                       t1: float, **attrs: Any) -> None:
+        """Record a span that is already over, [t0, t1) on the tracer's
+        clock, as a child of ``parent`` on the calling thread.  Takes no
+        lock (see ``__init__``): ``end_span`` files it."""
+        t = threading.current_thread()
+        self._finished.append({
+            "name": name,
+            "trace_id": parent.trace_id,
+            "span_id": f"{self._finished_rng.getrandbits(64):016x}",
+            "parent_id": parent.span_id,
+            "start_ts": self._wall_at(t0),
+            "duration_s": t1 - t0,
+            "thread_id": t.ident or 0,
+            "thread_name": t.name,
+            "attributes": attrs,
+            "events": [],
+            "status": "ok",
+        })
+
     def _count(self, name: str) -> None:
         if self.metrics is not None:
             try:
@@ -267,6 +343,8 @@ class Tracer:
                 "ring_capacity": self._ring.maxlen,
                 "slow_threshold_s": self.slow_threshold_s,
                 "sample_rate": self.sample_rate,
+                "gc_full_unparented": self.gc_full_unparented,
+                "gc_full_unparented_s": self.gc_full_unparented_s,
                 "traces": list(self._ring),
             }
 
@@ -278,16 +356,39 @@ _ctx_tracer: contextvars.ContextVar = contextvars.ContextVar(
 _global_tracer: list = [None]  # process-scoped (--trace; worker threads)
 _ctx_span: contextvars.ContextVar = contextvars.ContextVar(
     "gatekeeper_span", default=None)
+_scopes: list = [0]  # live activate() scopes
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """The one ``gc.callbacks`` hook: full collections go to the tracer
+    active on the collecting thread; young ones return at once."""
+    if info["generation"] != 2:
+        return
+    tracer = active_tracer()
+    if tracer is not None:
+        tracer._on_full_gc(phase, info)
+
+
+def _sync_gc_hook() -> None:
+    """The hook is in ``gc.callbacks`` exactly while a tracer is
+    installed or an ``activate()`` scope is live."""
+    want = _global_tracer[0] is not None or _scopes[0] > 0
+    have = _gc_hook in gc.callbacks
+    if want and not have:
+        gc.callbacks.append(_gc_hook)
+    elif have and not want:
+        gc.callbacks.remove(_gc_hook)
 
 
 def install(tracer: Optional[Tracer]) -> None:
     """Process-global activation (the ``--trace`` flag): every thread
     sees the tracer, including workers spawned before the call."""
     _global_tracer[0] = tracer
+    _sync_gc_hook()
 
 
 def uninstall() -> None:
-    _global_tracer[0] = None
+    install(None)
 
 
 def active_tracer() -> Optional[Tracer]:
@@ -306,12 +407,16 @@ def activate(tracer: Tracer, process: bool = True):
     prev = _global_tracer[0]
     if process:
         _global_tracer[0] = tracer
+    _scopes[0] += 1
+    _sync_gc_hook()
     try:
         yield tracer
     finally:
         _ctx_tracer.reset(token)
         if process:
             _global_tracer[0] = prev
+        _scopes[0] -= 1
+        _sync_gc_hook()
 
 
 # --- the hot-path entry points -------------------------------------------

@@ -114,6 +114,7 @@ def _fold(grid: jnp.ndarray, mask, k: int, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="verdict_fold",  # the kernel's name in a device trace
     )(*operands)[:c]
 
 

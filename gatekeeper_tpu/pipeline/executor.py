@@ -139,6 +139,11 @@ class StageStats:
     workers: int = 1
     items: int = 0
     busy_s: float = 0.0   # inside fn (summed across workers)
+    # CPU seconds of the worker threads inside fn (time.thread_time()).
+    # busy - cpu is time a thread held an item and did not run: waiting
+    # for the GIL, or blocked in a call that released it (device_put, a
+    # device wait, the C columnizer's own threads)
+    cpu_s: float = 0.0
     wait_s: float = 0.0   # blocked on upstream (input get)
     stall_s: float = 0.0  # blocked on downstream (output put, backpressure)
     queue_highwater: int = 0  # input channel depth high-water
@@ -156,9 +161,14 @@ class StageStats:
 class PipelineRun:
     """Result of StagedPipeline.run: stats + wall clock."""
 
+    # the calling thread's account: source_busy_s + source_stall_s +
+    # drain_s == wall_s by construction
     wall_s: float = 0.0
     source_items: int = 0
+    source_busy_s: float = 0.0   # inside next(source): the lister
+    source_cpu_s: float = 0.0    # its CPU seconds (time.thread_time())
     source_stall_s: float = 0.0  # source blocked on stage-1 backpressure
+    drain_s: float = 0.0  # source exhausted -> last stage worker exited
     stages: list = field(default_factory=list)  # [StageStats]
 
     def stage(self, name: str) -> Optional[StageStats]:
@@ -181,11 +191,15 @@ class PipelineRun:
                 self.stage_busy_sum() / self.wall_s, 3
             ) if self.wall_s > 0 else 0.0,
             "source_items": self.source_items,
+            "source_busy_s": round(self.source_busy_s, 3),
+            "source_cpu_s": round(self.source_cpu_s, 3),
             "source_stall_s": round(self.source_stall_s, 3),
+            "drain_s": round(self.drain_s, 3),
             "stages": {
                 s.name: {
                     "items": s.items,
                     "busy_s": round(s.busy_s, 3),
+                    "cpu_s": round(s.cpu_s, 3),
                     "wait_s": round(s.wait_s, 3),
                     "stall_s": round(s.stall_s, 3),
                     "occupancy": round(s.occupancy(self.wall_s), 3),
@@ -319,6 +333,7 @@ class StagedPipeline:
                         in_ch.put(_DONE)  # release sibling workers
                         break
                     t0 = time.perf_counter()
+                    c0 = time.thread_time()
                     attempt = 0
                     with tracing.span(f"pipeline.stage.{stage.name}",
                                       parent=trace_parent, chunk=idx) as sp:
@@ -341,11 +356,13 @@ class StagedPipeline:
                                              attempt=attempt, error=str(e))
                                 _log_stage_restart(stage.name, attempt, e)
                     busy = time.perf_counter() - t0
+                    cpu = time.thread_time() - c0
                     stall = emits[si].emit(
                         idx, _SKIP if out is None else out)
                     with st_locks[si]:
                         st.items += 1
                         st.busy_s += busy
+                        st.cpu_s += cpu
                         st.wait_s += wait
                         st.stall_s += stall
             except _Aborted:
@@ -371,14 +388,33 @@ class StagedPipeline:
                 t.start()
                 threads.append(t)
 
-        t_start = time.perf_counter()
+        t_start = mark = time.perf_counter()
+
+        def lap() -> float:
+            """Seconds since the last lap: each clock read ends one term
+            of the calling thread's account and starts the next."""
+            nonlocal mark
+            prev, mark = mark, time.perf_counter()
+            return mark - prev
+
+        it = iter(source)
         try:
-            for item in source:
-                t0 = time.perf_counter()
+            while True:
+                # the lister runs HERE, on the calling thread: each
+                # next() is one chunk's listing, timed and spanned like a
+                # stage item (the StopIteration call counts too — a
+                # lister's tail work is still listing)
+                c0 = time.thread_time()
+                with tracing.span("pipeline.source",
+                                  chunk=run.source_items):
+                    item = next(it, _DONE)
+                run.source_cpu_s += time.thread_time() - c0
+                run.source_busy_s += lap()
                 chans[0].put(item)
-                run.source_stall_s += time.perf_counter() - t0
+                run.source_stall_s += lap()
+                if item is _DONE:
+                    break
                 run.source_items += 1
-            chans[0].put(_DONE)
         except _Aborted:
             pass
         except BaseException as e:  # noqa: BLE001 — source failed
@@ -391,7 +427,8 @@ class StagedPipeline:
                 if abort.is_set():
                     t.join(5.0)
                     break
-        run.wall_s = time.perf_counter() - t_start
+        run.drain_s = lap()  # since the source loop's last booking
+        run.wall_s = mark - t_start
         for si, ch in enumerate(chans[:-1]):
             stats[si].queue_highwater = ch.highwater
         if first_error:

@@ -1,0 +1,430 @@
+"""The audit pass accounts for itself from inside the program: the lister
+is a timed, spanned layer, every pipeline stage says what it waited for
+and what CPU it burnt, a dispatch has parts, full garbage collections are
+on the tracer's timeline, and a span lives on one clock."""
+
+import gc
+import threading
+import time
+
+import pytest
+
+from gatekeeper_tpu.apis.constraints import AUDIT_EP
+from gatekeeper_tpu.audit.manager import AuditConfig, AuditManager
+from gatekeeper_tpu.client.client import Client
+from gatekeeper_tpu.drivers.tpu_driver import TpuDriver
+from gatekeeper_tpu.metrics import registry as M
+from gatekeeper_tpu.observability import tracing
+from gatekeeper_tpu.parallel.sharded import ShardedEvaluator, make_mesh
+from gatekeeper_tpu.pipeline import PipelineError, Stage, StagedPipeline
+from gatekeeper_tpu.target.target import K8sValidationTarget
+from gatekeeper_tpu.utils.unstructured import load_yaml_file
+
+LIB = "/root/repo/library/general"
+
+
+def _sleepy(seconds):
+    return lambda x: (time.sleep(seconds), x)[1]
+
+
+def _spin(seconds):
+    def fn(x):
+        end = time.perf_counter() + seconds
+        while time.perf_counter() < end:
+            pass
+        return x
+    return fn
+
+
+def _slow_source(n, seconds):
+    for i in range(n):
+        time.sleep(seconds)
+        yield i
+
+
+# --- the executor's account ------------------------------------------------
+
+def test_slow_source_shows_in_source_busy():
+    run = StagedPipeline([Stage("sink", lambda x: None)]).run(
+        _slow_source(10, 0.01))
+    assert run.source_items == 10
+    assert run.source_busy_s >= 0.1
+    assert run.source_busy_s > 0.8 * run.wall_s
+    # asleep in the lister is busy, not CPU
+    assert run.source_cpu_s < 0.5 * run.source_busy_s
+
+
+def test_slow_last_stage_shows_in_drain():
+    # an unbounded-enough queue: the source is done at once, and the pass
+    # then waits for the sink to work through what is queued
+    run = StagedPipeline([
+        Stage("sink", lambda x: (time.sleep(0.01), None)[1], queue_cap=64),
+    ]).run(range(10))
+    assert run.drain_s >= 0.08
+    assert run.drain_s > 0.8 * run.wall_s
+    assert run.source_busy_s < 0.2 * run.wall_s
+
+
+@pytest.mark.parametrize("source,stages", [
+    (lambda: range(50), lambda: [Stage("sink", lambda x: None)]),
+    (lambda: _slow_source(8, 0.005), lambda: [Stage("sink", lambda x: None)]),
+    (lambda: range(8), lambda: [
+        Stage("slow", _sleepy(0.005), queue_cap=1),
+        Stage("sink", lambda x: None, queue_cap=1)]),
+    (lambda: range(0), lambda: [Stage("sink", lambda x: None)]),
+], ids=["fast", "slow-source", "backpressure", "empty"])
+def test_calling_threads_account_closes(source, stages):
+    run = StagedPipeline(stages(), source_cap=1).run(source())
+    assert run.source_busy_s + run.source_stall_s + run.drain_s == \
+        pytest.approx(run.wall_s, abs=1e-9)
+    assert min(run.source_busy_s, run.source_stall_s, run.drain_s) >= 0.0
+
+
+def test_account_closes_after_a_failed_source():
+    def src():
+        yield 1
+        time.sleep(0.01)
+        raise RuntimeError("lister died")
+
+    # run() raises, so the account is read off the spans instead: the
+    # failed next() is a pipeline.source span with the error on it
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer), tracing.span("root"):
+        with pytest.raises(PipelineError):
+            StagedPipeline([Stage("s", lambda x: None)]).run(src())
+    spans = [s for s in tracer.traces()[0]["spans"]
+             if s["name"] == "pipeline.source"]
+    assert [s["attributes"]["chunk"] for s in spans] == [0, 1]
+    assert spans[1]["status"] == "error" and spans[1]["duration_s"] >= 0.01
+
+
+def test_stage_cpu_is_at_most_busy_and_near_zero_asleep():
+    run = StagedPipeline([
+        Stage("spin", _spin(0.01), queue_cap=2),
+        Stage("sleep", _sleepy(0.01), workers=2, queue_cap=2),
+        Stage("sink", lambda x: None),
+    ]).run(range(12))
+    spin, sleep = run.stage("spin"), run.stage("sleep")
+    for st in run.stages:
+        # thread_time and perf_counter tick apart by microseconds an item
+        assert st.cpu_s <= st.busy_s + 1e-3 * st.items, st
+    assert spin.busy_s >= 0.12 and sleep.busy_s >= 0.12
+    assert sleep.cpu_s < 0.25 * sleep.busy_s  # asleep burns no CPU
+    # a spinning thread runs whenever it holds the GIL; what it lacks of
+    # its busy time it spent waiting for the interpreter
+    assert spin.cpu_s > sleep.cpu_s
+
+
+def test_summary_carries_the_account():
+    run = StagedPipeline([Stage("a", _sleepy(0.001)),
+                          Stage("sink", lambda x: None)]).run(range(5))
+    doc = run.summary()
+    for key in ("source_busy_s", "source_cpu_s", "source_stall_s",
+                "drain_s", "wall_s"):
+        assert key in doc, key
+    assert set(doc["stages"]) == {"a", "sink"}
+    for st in doc["stages"].values():
+        assert {"busy_s", "cpu_s", "wait_s", "stall_s"} <= set(st)
+
+
+def test_source_spans_sit_under_the_ambient_span():
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer), tracing.span("root") as root:
+        StagedPipeline([Stage("sink", lambda x: None)]).run(range(3))
+    spans = tracer.traces()[0]["spans"]
+    src = [s for s in spans if s["name"] == "pipeline.source"]
+    # one per next(): three items and the call that found the end
+    assert [s["attributes"]["chunk"] for s in src] == [0, 1, 2, 3]
+    assert {s["parent_id"] for s in src} == {root.span_id}
+    assert {s["thread_id"] for s in src} == {threading.get_ident()}
+
+
+# --- the audit pass's account ----------------------------------------------
+
+def _objects(n):
+    return [{"apiVersion": "v1", "kind": "Namespace",
+             "metadata": {"name": f"ns-{i}",
+                          "labels": {"gatekeeper": "x"} if i % 3 else {}}}
+            for i in range(n)]
+
+
+@pytest.fixture(scope="module")
+def toy():
+    tpu = TpuDriver()
+    client = Client(target=K8sValidationTarget(), drivers=[tpu],
+                    enforcement_points=[AUDIT_EP])
+    client.add_template(load_yaml_file(
+        f"{LIB}/requiredlabels/template.yaml")[0])
+    client.add_constraint({
+        "apiVersion": "constraints.gatekeeper.sh/v1beta1",
+        "kind": "K8sRequiredLabels",
+        "metadata": {"name": "ns-must-have-gk"},
+        "spec": {"match": {"kinds": [{"apiGroups": [""],
+                                      "kinds": ["Namespace"]}]},
+                 "parameters": {"labels": [{"key": "gatekeeper"}]}},
+    })
+    evaluator = ShardedEvaluator(tpu, make_mesh(), violations_limit=5)
+    return client, evaluator
+
+
+def _toy_mgr(toy, pipeline, metrics=None):
+    client, evaluator = toy
+    objects = _objects(40)
+    return AuditManager(
+        client, lister=lambda: iter(objects),
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline=pipeline),
+        evaluator=evaluator, metrics=metrics)
+
+
+def test_perf_keys_after_a_pipelined_pass(toy):
+    metrics = M.MetricsRegistry()
+    mgr = _toy_mgr(toy, "on", metrics)
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        run = mgr.audit()
+    perf = mgr.perf
+    assert perf["pipelined"] == 1.0 and run.total_objects == 40
+    for key in ("list", "list_cpu", "pipe_source_stall", "pipe_drain",
+                "pipe_wall", "pipe_device_wait", "report", "render",
+                "fold_render", "n_renders"):
+        assert key in perf, key
+    for stage in ("flatten", "dispatch", "collect", "fold_render"):
+        for what in ("busy", "wait", "stall", "cpu", "workers"):
+            assert f"pipe_{stage}_{what}" in perf, (stage, what)
+        assert perf[f"pipe_{stage}_workers"] >= 1.0
+    # the calling thread's account closes on the pipeline's wall
+    assert perf["list"] + perf["pipe_source_stall"] + perf["pipe_drain"] \
+        == pytest.approx(perf["pipe_wall"], abs=1e-6)
+    assert perf["pipe_device_wait"] == perf["pipe_collect_busy"]
+    assert 0.0 < perf["render"] <= perf["fold_render"]
+    assert perf["n_renders"] > 0
+    # what misled is gone; what it was computed from stands under its name
+    assert "device_idle_fraction" not in mgr.pipe_stats
+    assert mgr.pipe_stats["device_wait_s"] >= 0.0
+    assert metrics.get_gauge(M.PIPELINE_DEVICE_WAIT) is not None
+    assert not hasattr(M, "PIPELINE_DEVICE_IDLE")
+    # and the pass's parts are on the timeline
+    spans = tracer.traces()[0]["spans"]
+    root = next(s for s in spans if s["name"] == "audit.sweep")
+    assert "device_idle_fraction" not in root["attributes"]
+    by_name: dict = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert len(by_name["pipeline.source"]) == 4  # 3 chunks + the end
+    assert [s["parent_id"] for s in by_name["audit.report"]] == \
+        [root["span_id"]]
+    dispatches = {s["span_id"] for s in by_name["device.sweep_dispatch"]}
+    for part in ("masks", "pack", "launch"):
+        parts = by_name[f"device.sweep_dispatch.{part}"]
+        assert len(parts) == len(dispatches) == 3, part
+        assert {s["parent_id"] for s in parts} == dispatches
+    # a second pass adds to the account; workers is a reading, not a sum
+    wall1, workers = perf["pipe_wall"], perf["pipe_flatten_workers"]
+    mgr.audit()
+    assert mgr.perf["pipe_wall"] > wall1
+    assert mgr.perf["pipe_flatten_workers"] == workers
+
+
+def test_perf_keys_after_a_serial_pass(toy):
+    mgr = _toy_mgr(toy, "off")
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        run = mgr.audit()
+    assert mgr.perf["pipelined"] == 0.0 and run.total_objects == 40
+    assert mgr.pipe_stats is None
+    for key in ("list", "report", "render", "fold_render"):
+        assert mgr.perf[key] > 0.0, key
+    assert not any(k.startswith("pipe_") for k in mgr.perf)
+    spans = tracer.traces()[0]["spans"]
+    listed = [s for s in spans if s["name"] == "audit.chunk.list"]
+    assert [s["attributes"]["chunk"] for s in listed] == [0, 1, 2, 3]
+    assert sum(s["duration_s"] for s in listed) <= mgr.perf["list"]
+    assert sum(s["name"] == "audit.report" for s in spans) == 1
+
+
+def test_dispatch_parts_keep_their_perf_keys(toy):
+    _client, evaluator = toy
+    evaluator.perf_reset()
+    _toy_mgr(toy, "off").audit()
+    for key in ("flatten", "masks", "wire_pack", "wire_bytes", "dispatch",
+                "collect", "d2h_bytes"):
+        assert evaluator.perf.get(key, 0.0) > 0.0, key
+
+
+# --- full collections on the program's timeline -----------------------------
+
+def test_full_collection_is_a_span_under_the_ambient_span():
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        with tracing.span("root"):
+            with tracing.span("child") as child:
+                gc.collect()
+            gc.collect(0)  # a young collection: no span
+    spans = tracer.traces()[0]["spans"]
+    full = [s for s in spans if s["name"] == "runtime.gc.full"]
+    assert len(full) == 1
+    assert full[0]["parent_id"] == child.span_id
+    assert full[0]["trace_id"] == child.trace_id
+    assert full[0]["thread_id"] == threading.get_ident()
+    assert set(full[0]["attributes"]) == {"collected", "uncollectable"}
+    assert full[0]["duration_s"] > 0.0
+    assert tracer.snapshot()["gc_full_unparented"] == 0
+
+
+def test_full_collection_with_no_ambient_span_is_counted_not_traced():
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        gc.collect()
+        gc.collect()
+        with tracing.span("later"):
+            pass
+    snap = tracer.snapshot()
+    assert snap["gc_full_unparented"] == 2
+    assert snap["gc_full_unparented_s"] > 0.0
+    assert [tr["root"] for tr in snap["traces"]] == ["later"]
+    assert [s["name"] for s in snap["traces"][0]["spans"]] == ["later"]
+
+
+def test_gc_hook_lives_only_while_a_tracer_does():
+    def hooked():
+        return tracing._gc_hook in gc.callbacks
+
+    assert not hooked()
+    tracer = tracing.Tracer(seed=0)
+    tracing.install(tracer)
+    try:
+        assert hooked()
+        with tracing.activate(tracing.Tracer(seed=1), process=False):
+            assert hooked()
+        assert hooked()  # the installed tracer still wants it
+    finally:
+        tracing.uninstall()
+    assert not hooked()
+    with tracing.activate(tracer):
+        with tracing.activate(tracing.Tracer(seed=2)):
+            assert gc.callbacks.count(tracing._gc_hook) == 1
+        assert hooked()
+    assert not hooked()
+
+
+def test_collection_inside_the_tracers_lock_does_not_deadlock():
+    """A collection can start on an allocation inside ``start_span`` or
+    ``end_span``, where the thread holds ``Tracer._lock``.  The hook must
+    not want that lock."""
+    tracer = tracing.Tracer(seed=0)
+    done = []
+
+    def body():
+        with tracing.activate(tracer, process=False):
+            with tracing.span("root"):
+                with tracer._lock:
+                    gc.collect()
+            done.append(True)
+
+    t = threading.Thread(target=body, daemon=True)
+    t.start()
+    t.join(timeout=20)
+    assert done, "the gc hook took Tracer._lock while the thread held it"
+    names = [s["name"] for s in tracer.traces()[0]["spans"]]
+    assert names == ["runtime.gc.full", "root"]
+
+
+def test_collections_under_allocation_pressure_on_many_threads():
+    """The same with real collections: thresholds of 1 start collections
+    on allocations everywhere, end_span's included, on more threads than
+    cores."""
+    tracer = tracing.Tracer(seed=0, ring_capacity=4096)
+    old = gc.get_threshold()
+    errors = []
+
+    def body():
+        try:
+            for _ in range(200):
+                with tracing.span("root"):
+                    with tracing.span("child", junk=[[] for _ in range(8)]):
+                        pass
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=body, daemon=True)
+               for _ in range(8)]
+    with tracing.activate(tracer):
+        gc.set_threshold(1, 1, 1)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            gc.set_threshold(*old)
+    assert not any(t.is_alive() for t in threads), "deadlocked"
+    assert not errors
+    traces = tracer.traces()
+    assert len(traces) == 8 * 200
+    for tr in traces:
+        ids = {s["span_id"] for s in tr["spans"]}
+        for s in tr["spans"]:
+            assert s["name"] in ("root", "child", "runtime.gc.full")
+            # every collection was filed under a span of its own trace
+            assert s["parent_id"] is None or s["parent_id"] in ids
+
+
+def test_a_collection_does_not_shift_the_seeded_id_sequence():
+    def ids(collect):
+        tracer = tracing.Tracer(seed=3)
+        with tracing.activate(tracer):
+            with tracing.span("a"):
+                if collect:
+                    gc.collect()
+                with tracing.span("b"):
+                    pass
+        return [(s["name"], s["span_id"])
+                for s in tracer.traces()[0]["spans"]
+                if s["name"] != "runtime.gc.full"]
+
+    assert ids(True) == ids(False)
+
+
+# --- one clock per span ----------------------------------------------------
+
+def test_child_never_ends_past_its_parent():
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        for _ in range(200):
+            with tracing.span("root"):
+                with tracing.span("child"):
+                    with tracing.span("leaf"):
+                        pass
+        with tracing.span("root"):
+            gc.collect()
+    for tr in tracer.traces():
+        by_id = {s["span_id"]: s for s in tr["spans"]}
+        for s in tr["spans"]:
+            parent = by_id.get(s["parent_id"])
+            if parent is None:
+                continue
+            # float seconds near 1.8e9 resolve to ~2.4e-7
+            assert s["start_ts"] >= parent["start_ts"] - 1e-6
+            assert s["start_ts"] + s["duration_s"] <= \
+                parent["start_ts"] + parent["duration_s"] + 1e-6
+
+
+def test_timestamps_ignore_a_wall_clock_that_steps():
+    mono, wall = [100.0], [5000.0]
+    tracer = tracing.Tracer(seed=0, clock=lambda: mono[0],
+                            wall=lambda: wall[0])
+    with tracing.activate(tracer):
+        with tracing.span("root") as root:
+            mono[0] += 1.0
+            wall[0] -= 3600.0  # the wall clock is set back an hour
+            with tracing.span("child"):
+                mono[0] += 2.0
+                root.add_event("tick")
+            mono[0] += 0.5
+    spans = {s["name"]: s for s in tracer.traces()[0]["spans"]}
+    assert spans["root"]["start_ts"] == 5000.0
+    assert spans["root"]["duration_s"] == 3.5
+    assert spans["child"]["start_ts"] == 5001.0
+    assert spans["child"]["duration_s"] == 2.0
+    assert spans["root"]["events"][0]["ts"] == 5003.0

@@ -266,7 +266,7 @@ def test_pipeline_stats_flow_into_metrics_registry():
     for stage in ("flatten", "dispatch", "collect", "fold_render"):
         assert metrics.get_gauge(M.PIPELINE_STAGE_SECONDS,
                                  {"stage": stage}) is not None, stage
-    assert metrics.get_gauge(M.PIPELINE_DEVICE_IDLE) is not None
+    assert metrics.get_gauge(M.PIPELINE_DEVICE_WAIT) is not None
     assert M.PREFIX + M.PIPELINE_STAGE_OCCUPANCY in rendered
     assert metrics.get_counter(
         M.AUDIT_DURATION, None) == 0.0  # histogram, not counter

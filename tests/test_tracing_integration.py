@@ -11,6 +11,7 @@ zero-cost to verdicts — the chaos-differential discipline applied to
 observability)."""
 
 import json
+import time
 import urllib.request
 
 import pytest
@@ -89,6 +90,16 @@ def _post(port, path, body, headers=None):
         return json.loads(resp.read()), dict(resp.headers)
 
 
+def _wait_traces(tracer, n=1, timeout=5.0):
+    """The kept traces, once there are ``n``: the request thread ends its
+    root span AFTER the reply is on the wire, so the ring may still be
+    empty when ``_post`` returns."""
+    deadline = time.monotonic() + timeout
+    while len(tracer.traces()) < n and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return tracer.traces()
+
+
 def test_webhook_timeline_and_traceparent_roundtrip(traced_server):
     remote_trace = "a" * 32
     header = f"00-{remote_trace}-{'b' * 16}-01"
@@ -98,7 +109,7 @@ def test_webhook_timeline_and_traceparent_roundtrip(traced_server):
             traced_server.port, "/v1/admit", _review_body(),
             headers={"traceparent": header})
     assert out["response"]["allowed"] is False
-    traces = tracer.traces()
+    traces = _wait_traces(tracer)
     assert len(traces) == 1
     tr = traces[0]
     # ingest: the request span joined the caller's trace
@@ -131,7 +142,7 @@ def test_webhook_without_traceparent_starts_fresh_trace(traced_server):
     tracer = tracing.Tracer(seed=0)
     with tracing.activate(tracer):
         _post(traced_server.port, "/v1/admit", _review_body("u2"))
-    tr = tracer.traces()[0]
+    tr = _wait_traces(tracer)[0]
     root = next(s for s in tr["spans"] if s["name"] == "webhook.request")
     assert root["parent_id"] is None
     assert len(tr["trace_id"]) == 32
@@ -148,6 +159,7 @@ def test_debug_traces_endpoint(traced_server):
     tracer = tracing.Tracer(seed=0)
     with tracing.activate(tracer):
         _post(traced_server.port, "/v1/admit", _review_body("u3"))
+        _wait_traces(tracer)
         with urllib.request.urlopen(url) as resp:
             doc = json.loads(resp.read())
     assert doc["kept"] >= 1
@@ -201,8 +213,8 @@ def test_pipelined_sweep_emits_chunk_scoped_stage_spans(tmp_path):
         sum(run.total_violations.values()) > 0
     assert root["attributes"]["stage_busy_sum_s"] == \
         mgr.pipe_stats["stage_busy_sum_s"]
-    assert root["attributes"]["device_idle_fraction"] == \
-        mgr.pipe_stats["device_idle_fraction"]
+    assert root["attributes"]["device_wait_s"] == \
+        mgr.pipe_stats["device_wait_s"]
     # chunk-scoped stage spans, parented under the sweep root
     for stage in ("flatten", "dispatch", "collect", "fold_render"):
         st = [s for s in spans if s["name"] == f"pipeline.stage.{stage}"]

@@ -46,8 +46,11 @@ OVERLOAD_PY = PKG / "resilience" / "overload.py"
 
 _FAULT_CALL = re.compile(r'fault_point\(\s*(f?)"([^"]+)"')
 # tracer span call sites: tracing.span("..."), otel.span("..."),
-# tracer.start_span("...") — the \s* spans a line wrap after the paren
-_SPAN_CALL = re.compile(r'\b(?:span|start_span)\(\s*(f?)"([^"]+)"')
+# tracer.start_span("..."), and the tracer's own _finished_span("...")
+# (spans it records after the fact: full garbage collections) — the \s*
+# spans a line wrap after the paren
+_SPAN_CALL = re.compile(
+    r'\b(?:span|start_span|_finished_span)\(\s*(f?)"([^"]+)"')
 _DOC_ENTRY = re.compile(r"^\s*-\s+`([^`]+)`")
 _FSTRING_FIELD = re.compile(r"\{[^}]*\}")
 # route constants at the top of webhook/server.py; only the /debug/*
