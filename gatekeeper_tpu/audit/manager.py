@@ -1412,51 +1412,52 @@ class AuditManager:
             # one tee per sweep pass: the differential schedule runs
             # this generator twice — a stale buffer would double-expand
             self._gen_buf = []
-        if use_router:
-            from gatekeeper_tpu.parallel.sharded import make_kind_router
-            from gatekeeper_tpu.utils.rawjson import peek_kind
+        chunk_size = self.config.chunk_size
+        counts = [0, 0]  # listed by the native call / one at a time
+        try:
+            if use_router:
+                from gatekeeper_tpu.observability import tracing
+                from gatekeeper_tpu.ops.listroute import route_chunks
+                from gatekeeper_tpu.parallel.sharded import make_kind_router
 
-            router = make_kind_router(constraints)
-            cons_of_group: dict = {}
-            bufs: dict = {}  # group -> pending chunk
-            for obj in self.lister():
-                k = peek_kind(obj)
-                self._gen_tee(obj, k)
-                if kind_filter is not None and k not in kind_filter:
-                    continue
-                counter[0] += 1
-                g = router(k)
-                if not g:
-                    continue  # no template's match reaches this kind
-                buf = bufs.setdefault(g, [])
-                buf.append(obj)
-                if len(buf) >= self.config.chunk_size:
+                cons_of_group: dict = {}
+                # the armed expansion tee sees every listed object with
+                # its kind: such a pass stays on the per-object loop
+                tee = self._gen_tee if self._gen_buf is not None else None
+                for g, buf in route_chunks(
+                        self.lister(), make_kind_router(constraints),
+                        chunk_size, counter, counts, kind_filter, tee):
+                    tracing.set_attribute("list_fast", counts[0])
+                    tracing.set_attribute("list_slow", counts[1])
                     cg = cons_of_group.get(g)
                     if cg is None:
                         cg = [c for c in constraints if c.kind in g]
                         cons_of_group[g] = cg
-                    self._brownout_yield()
+                    if len(buf) >= chunk_size:  # not a tail
+                        self._brownout_yield()
                     yield buf, cg
-                    bufs[g] = []
-            for g, buf in bufs.items():
-                if buf:
-                    yield buf, [c for c in constraints if c.kind in g]
-        else:
-            chunk: list = []
-            for obj in self.lister():
-                if self._gen_buf is not None or kind_filter is not None:
-                    _, _, k = gvk_of(obj)
-                    self._gen_tee(obj, k)
-                    if kind_filter is not None and k not in kind_filter:
-                        continue
-                chunk.append(obj)
-                counter[0] += 1
-                if len(chunk) >= self.config.chunk_size:
-                    self._brownout_yield()
+            else:
+                chunk: list = []
+                for obj in self.lister():
+                    counts[1] += 1
+                    if self._gen_buf is not None or kind_filter is not None:
+                        _, _, k = gvk_of(obj)
+                        self._gen_tee(obj, k)
+                        if kind_filter is not None and k not in kind_filter:
+                            continue
+                    chunk.append(obj)
+                    counter[0] += 1
+                    if len(chunk) >= chunk_size:
+                        self._brownout_yield()
+                        yield chunk, constraints
+                        chunk = []
+                if chunk:
                     yield chunk, constraints
-                    chunk = []
-            if chunk:
-                yield chunk, constraints
+        finally:
+            # both on every pass, a 0 too: the share of the listing that
+            # ran as native calls (benchmark: list.fast_share)
+            self._perf_add("list_fast", counts[0])
+            self._perf_add("list_slow", counts[1])
 
     # --- serial schedule (eager-poll, the one-core-safe path) ------------
     def _sweep_serial(self, constraints, kind_filter, use_router, device,

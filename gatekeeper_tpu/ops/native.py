@@ -62,6 +62,23 @@ def load_json() -> Optional[object]:
     return _load_named("gtpu_flattenjson", "flattenjsonmod.c")
 
 
+def load_listroute() -> Optional[object]:
+    """The audit lister's per-object routing (native/listroutemod.c),
+    bound to the RawJSON class whose slots it reads."""
+    if "gtpu_listroute" not in _tried:
+        mod = _load_named("gtpu_listroute", "listroutemod.c")
+        if mod is not None:
+            from gatekeeper_tpu.utils.rawjson import RawJSON
+
+            try:
+                mod.bind(RawJSON)
+            except TypeError as e:  # not the class this was written for
+                sys.stderr.write(f"gtpu_listroute unusable ({e}); "
+                                 "using the per-object loop\n")
+                _mods["gtpu_listroute"] = None
+    return _mods.get("gtpu_listroute")
+
+
 def _build_flags() -> list:
     """The full compiler invocation prefix (compiler + every flag).
     ``GTPU_NATIVE_CFLAGS`` appends extra flags (sanitizer builds, the

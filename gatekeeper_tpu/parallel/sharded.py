@@ -1200,7 +1200,7 @@ class ShardedEvaluator:
         ``route`` mirrors the audit manager's kind-bucketed routing
         (make_kind_router): objects stream into per-group chunks so each
         group warms its own (slimmer) schema/layout/sweep fn."""
-        from gatekeeper_tpu.utils.rawjson import peek_kind
+        from gatekeeper_tpu.ops.listroute import route_chunks
 
         # per-group compile state, built lazily on each group's first chunk
         state: dict = {}  # g -> (cons_g, flattener, needs) or None
@@ -1260,20 +1260,11 @@ class ShardedEvaluator:
             buckets.setdefault((g, self._pad(len(ch))), (cons_g, ch))
 
         if route:
-            router = make_kind_router(constraints)
-            bufs: dict = {}
-            for obj in objects:
-                g = router(peek_kind(obj))
-                if not g:
-                    continue
-                buf = bufs.setdefault(g, [])
-                buf.append(obj)
-                if len(buf) >= chunk_size:
-                    scan_chunk(g, buf)
-                    bufs[g] = []
-            for g, buf in bufs.items():
-                if buf:
-                    scan_chunk(g, buf)
+            # the audit's own chunking: the warmed chunks are the
+            # measured ones
+            for g, buf in route_chunks(objects, make_kind_router(constraints),
+                                       chunk_size, [0], [0, 0]):
+                scan_chunk(g, buf)
         else:
             g_all = frozenset(c.kind for c in constraints)
             buf = []

@@ -26,7 +26,7 @@ class RawJSON(dict):
     __slots__ = ("raw", "_loaded")
 
     def __init__(self, raw: bytes):
-        super().__init__()
+        # dict.__new__ already made the empty dict: no super().__init__()
         self.raw = raw
         self._loaded = False
 

@@ -428,3 +428,57 @@ def test_timestamps_ignore_a_wall_clock_that_steps():
     assert spans["child"]["start_ts"] == 5001.0
     assert spans["child"]["duration_s"] == 2.0
     assert spans["root"]["events"][0]["ts"] == 5003.0
+
+
+# --- the lister's routing in the account (native/listroutemod.c) ------------
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+@pytest.mark.parametrize("raw", [True, False], ids=["rawjson", "dicts"])
+def test_account_closes_and_counts_every_listed_object(toy, pipeline, raw):
+    """With the native routing call on the calling thread the account
+    still closes on the wall, and ``list_fast + list_slow`` is the
+    listed objects of the pass: head-form RawJSON all fast, plain dicts
+    all through ``peek_kind``."""
+    from gatekeeper_tpu.ops import native
+    from gatekeeper_tpu.utils.rawjson import as_raw
+
+    client, evaluator = toy
+    objects = _objects(40)
+    lister = (lambda: (as_raw(o) for o in objects)) if raw \
+        else (lambda: iter(objects))
+    mgr = AuditManager(
+        client, lister=lister,
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline=pipeline),
+        evaluator=evaluator)
+    mgr.audit()  # whatever compiles, compiles here
+    mgr.perf = {}
+    tracer = tracing.Tracer(seed=0)
+    t0 = time.perf_counter()
+    with tracing.activate(tracer):
+        run = mgr.audit()
+    wall = time.perf_counter() - t0
+    perf = mgr.perf
+    assert run.total_objects == 40
+    assert perf["list_fast"] + perf["list_slow"] == 40
+    fast = 40 if raw and native.load_listroute() is not None else 0
+    assert (perf["list_fast"], perf["list_slow"]) == (fast, 40 - fast)
+    spans = tracer.traces()[0]["spans"]
+    if pipeline == "on":
+        account = (perf["list"] + perf["pipe_source_stall"]
+                   + perf["pipe_drain"] + perf["report"])
+        assert perf["list"] + perf["pipe_source_stall"] \
+            + perf["pipe_drain"] == pytest.approx(perf["pipe_wall"],
+                                                   abs=1e-6)
+        # what is left is _audit_impl's preamble and the thread starts
+        assert 0.8 * wall < account <= wall
+        listed = [s for s in spans if s["name"] == "pipeline.source"]
+    else:
+        listed = [s for s in spans if s["name"] == "audit.chunk.list"]
+    # the counts ride the listing's span, cumulative over the pass
+    assert [s["attributes"].get("list_fast", 0)
+            + s["attributes"].get("list_slow", 0)
+            for s in listed[:3]] == [16, 32, 40]
+    # a second pass adds to both
+    mgr.audit()
+    assert mgr.perf["list_fast"] + mgr.perf["list_slow"] == 80

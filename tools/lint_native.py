@@ -1,16 +1,17 @@
 """Native-module lint: warning-clean and sanitizer-clean C kernels.
 
-Two gates over ``native/flattenmod.c`` and ``native/flattenjsonmod.c``:
+Two gates over ``native/flattenmod.c``, ``native/flattenjsonmod.c`` and
+``native/listroutemod.c``:
 
-- **strict compile** — both modules must build with
+- **strict compile** — every module must build with
   ``-Wall -Wextra -Werror`` (a warning in kernel code is a bug
   waiting for a compiler upgrade to find it);
 - **sanitizer corpus run** (slow) — rebuild the modules with
   ``-fsanitize=address,undefined`` through the normal
   ``ops/native.py`` build (the flag set hashes into the output dir,
   so the sanitized build can never be satisfied by a stale plain
-  binary) and run the flatten unit corpus under it in a subprocess
-  with libasan preloaded.  Memory errors or UB in the threaded
+  binary) and run the flatten and list-routing unit corpus under it in
+  a subprocess with libasan preloaded.  Memory errors or UB in the threaded
   kernel abort the run.
 
 Run standalone (``python tools/lint_native.py [--asan]``) or via
@@ -26,7 +27,7 @@ import sys
 import sysconfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SOURCES = ("flattenmod.c", "flattenjsonmod.c")
+SOURCES = ("flattenmod.c", "flattenjsonmod.c", "listroutemod.c")
 STRICT_FLAGS = ["-Wall", "-Wextra", "-Werror"]
 
 
@@ -84,7 +85,8 @@ def asan_corpus_run(timeout_s: float = 600.0) -> tuple:
     })
     cmd = [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
            os.path.join(REPO, "tests", "test_native_flatten_json.py"),
-           os.path.join(REPO, "tests", "test_native_flatten.py")]
+           os.path.join(REPO, "tests", "test_native_flatten.py"),
+           os.path.join(REPO, "tests", "test_list_routing.py")]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=timeout_s, cwd=REPO, env=env)
