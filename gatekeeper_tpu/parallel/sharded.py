@@ -1616,9 +1616,10 @@ class ShardedEvaluator:
             else self.driver._programs
         k = self.violations_limit
         tables = []
-        mask_rows = []
         offsets = {}
         c_off = 0
+        # constraint rows the table path answered / the per-object predicate
+        mask_counts = {"rows_vectorized": 0, "rows_predicate": 0}
         with self._timed("masks",
                          tracing.span("device.sweep_dispatch.masks")):
             for kind in kinds:
@@ -1628,14 +1629,24 @@ class ShardedEvaluator:
                 # that the vocab tables below must include
                 tables.append(build_param_table(prog.program, cons,
                                                 self.driver.vocab))
-                mask_rows.append(masks_mod.constraint_masks(
-                    cons, batch, self.driver.vocab, objects,
-                    sources=([flat.source] * len(objects)
-                             if flat.source else None),
-                    any_generate_name=any_gen,
-                ))
                 offsets[kind] = (c_off, c_off + len(cons))
                 c_off += len(cons)
+            # one call for the group: the chunk's columns are coded once
+            # for all its constraints
+            mask_all = masks_mod.constraint_masks(
+                [con for kind in kinds for con in by_kind[kind]],
+                batch, self.driver.vocab, objects,
+                sources=([flat.source] * len(objects)
+                         if flat.source else None),
+                any_generate_name=any_gen, counts=mask_counts,
+            )
+            mask_rows = [mask_all[lo:hi] for lo, hi in offsets.values()]
+            tracing.set_attribute("constraints", c_off)
+            for key, n_rows in mask_counts.items():
+                tracing.set_attribute(key, n_rows)
+        # written on every dispatch, a 0 too
+        self._perf_add("mask_rows_fast", mask_counts["rows_vectorized"])
+        self._perf_add("mask_rows_slow", mask_counts["rows_predicate"])
         from gatekeeper_tpu.observability import costattr
 
         complete = bool(return_bits)
@@ -1685,9 +1696,12 @@ class ShardedEvaluator:
                          tracing.span("device.sweep_dispatch.pack")):
             cols_bufs, cols_layout = pack_transfer_cols(
                 cols, pad_n, stats=self._col_stats or None)
+        # the bit-packed match mask crosses beside the columns
+        mask_bytes = c_off * pad_n // 8
+        self._perf_add("mask_wire_bytes", mask_bytes)
         self._perf_add(
             "wire_bytes",
-            sum(b.nbytes for b in cols_bufs.values()) + c_off * pad_n // 8)
+            sum(b.nbytes for b in cols_bufs.values()) + mask_bytes)
         hit_cap = 0
         budget_np = None
         with self._timed("dispatch",
@@ -1718,7 +1732,7 @@ class ShardedEvaluator:
             # bit-packed match mask: [C, pad_n/8] uint8 on the wire (8x
             # fewer bytes than bool [C, N]); unpacked to bool inside the
             # jitted sweep where the expansion fuses into the grid AND
-            mask = np.packbits(np.concatenate(mask_rows, axis=0), axis=1)
+            mask = np.packbits(mask_all, axis=1)
             mask_dev = jax.device_put(
                 mask, NamedSharding(self.mesh, P(None, "data"))
             )

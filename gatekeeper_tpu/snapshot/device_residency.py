@@ -221,8 +221,8 @@ class DeviceResidency:
     # --- sync ------------------------------------------------------------
     def _mask_rows(self, rg: ResidentGroup, batch, objects) -> np.ndarray:
         """[C, len(objects)] bool in constraint-grid order — the same
-        ``constraint_masks`` call per kind the dispatch path makes, so
-        per-object mask values are identical whether computed at patch
+        ``constraint_masks`` call over the group the dispatch path makes,
+        so per-object mask values are identical whether computed at patch
         time (here) or chunk time (the host reference lane)."""
         from gatekeeper_tpu.ir import masks as masks_mod
 
@@ -232,11 +232,10 @@ class DeviceResidency:
         else:
             any_gen = any("generateName" in (o.get("metadata") or {})
                           for o in objects)
-        rows = [masks_mod.constraint_masks(
-            rg.by_kind[kind], batch, self.evaluator.driver.vocab,
-            objects, any_generate_name=any_gen)
-            for kind in rg.kinds]
-        return np.concatenate(rows, axis=0)[:, : len(objects)]
+        return masks_mod.constraint_masks(
+            [con for kind in rg.kinds for con in rg.by_kind[kind]], batch,
+            self.evaluator.driver.vocab, objects,
+            any_generate_name=any_gen)[:, : len(objects)]
 
     def _pack(self, store, positions, pad_n: int, rg: ResidentGroup):
         """(bufs, layout, batch, objects) for a row set, under the
