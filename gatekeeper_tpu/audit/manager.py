@@ -26,10 +26,12 @@ from typing import Any, Callable, Iterable, Optional, Sequence
 import numpy as np
 
 from gatekeeper_tpu.apis.constraints import AUDIT_EP, Constraint
+from gatekeeper_tpu.audit.render_memo import RenderMemo
 from gatekeeper_tpu.client.client import Client
 from gatekeeper_tpu.drivers.base import ReviewCfg
 from gatekeeper_tpu.match.match import SOURCE_ORIGINAL
 from gatekeeper_tpu.target.review import AugmentedUnstructured
+from gatekeeper_tpu.utils.rawjson import RawJSON
 from gatekeeper_tpu.utils.unstructured import gvk_of
 
 
@@ -196,6 +198,9 @@ def _sweep_ready(pending) -> bool:
         return True
 
 
+_UNSEEN = object()  # render(): the chunk has not asked for this object yet
+
+
 class AuditManager:
     """One audit plane instance (the reference's audit Deployment pod)."""
 
@@ -262,6 +267,9 @@ class AuditManager:
         # per-phase seconds for the host-side fold/render of device sweeps
         # (the evaluator tracks its own flatten/masks/wire/dispatch/collect)
         self.perf: dict = {}
+        # last pass's rendered messages, by every input of a render
+        # (audit/render_memo.py); alive across passes
+        self._render_memo = RenderMemo()
         # per-stage breakdown of the last pipelined sweep (JSON-ready dict
         # from pipeline.executor.PipelineRun.summary + the collect stage's
         # head-of-line wait); None when the last sweep ran the serial
@@ -389,6 +397,7 @@ class AuditManager:
 
         gen_stage = self._gen_stage()
         self._gen_reset(gen_stage is not None)
+        self._begin_pass(constraints)
 
         limit = self.config.violations_limit
         kept: dict = {(c.kind, c.name): [] for c in constraints}
@@ -495,6 +504,7 @@ class AuditManager:
             self._publish_metrics(run)
             self._finish(run)
         self._perf_add("report", time.perf_counter() - t0)
+        self._render_memo.end_pass()
 
     # --- snapshot lane (gatekeeper_tpu/snapshot/) -------------------------
     def _snapshot_mode(self) -> bool:
@@ -573,6 +583,7 @@ class AuditManager:
             return run
         snap = self.snapshot
         self._snapshot_ready(constraints)
+        self._begin_pass(constraints)
         rows = snap.all_rows() if full else snap.dirty_rows()
         self.perf["snapshot_rows_evaluated"] = (
             self.perf.get("snapshot_rows_evaluated", 0.0)
@@ -747,28 +758,78 @@ class AuditManager:
         per-cluster derivation (same path the snapshot tick uses)."""
         return self._snapshot_collect(constraints)
 
-    def _render_fn(self, source=SOURCE_ORIGINAL):
-        """(render, review_cache): the exact-engine render for one
-        (constraint, object) hit — the same path the relist fold uses,
-        so messages/details are bit-identical across audit sources."""
+    def _begin_pass(self, constraints) -> None:
+        """Size the render memo for the pass and put the renderer's
+        counters into ``perf``, a 0 too: a pass of nothing but hits still
+        reports how many renders it ran."""
+        self._render_memo.begin_pass(len(constraints),
+                                     self.config.violations_limit)
+        for key in ("n_renders", "render_memo_hits", "render_memo_bypass"):
+            self.perf[key] = self.perf.get(key, 0)
+        self._perf_add("render", 0.0)
+
+    def _render_fn(self, source=SOURCE_ORIGINAL, reviews=None):
+        """``render(con, obj, cache_key=None)``: the exact-engine render
+        for one (constraint, object) hit, the one path of the relist fold,
+        the snapshot lane and the generator stage, so messages/details are
+        bit-identical across audit sources.  ``cache_key`` names the
+        object within the caller's chunk; ``reviews`` is the caller's
+        review cache under those keys.
+
+        The render memo stands in front of the interpreter.  Its key holds
+        every input of a render: the driver's ``render_token`` for the
+        constraint (template modules, the Constraint object, the data
+        epoch where the template reads ``data``), ``source``, and the
+        bytes of an object that was an unloaded ``RawJSON`` when the chunk
+        first asked for it (after that only this fold loads it, to read
+        it).  The review is built here from the object and ``source``
+        alone, so it carries no namespace object, and ``cfg`` is the one
+        built here, which asks for no trace and no stats.  Anything else
+        renders as ever and is counted in ``perf["render_memo_bypass"]``:
+        a loaded or plain-dict object, a driver or template without a
+        token."""
         target = self.client.target
         driver = next(
             (d for d in self.client.drivers if hasattr(d, "query_batch")),
             None,
         )
         cfg = ReviewCfg(enforcement_point=AUDIT_EP)
-        cache: dict = {}
+        if reviews is None:
+            reviews = {}
+        raws: dict = {}  # cache_key -> the object's bytes, None = bypass
+        memo = self._render_memo
+        token_of = getattr(driver, "render_token", None)
 
         def render(con, obj, cache_key=None):
-            self.perf["n_renders"] = self.perf.get("n_renders", 0) + 1
+            perf = self.perf
+            raw = raws.get(cache_key, _UNSEEN)  # None is never filed
+            if raw is _UNSEEN:
+                raw = obj.raw if type(obj) is RawJSON \
+                    and not obj._loaded else None
+                if cache_key is not None:
+                    raws[cache_key] = raw
+            key = None
+            if raw is not None and token_of is not None:
+                token = token_of(con)
+                if token is not None:
+                    key = (token, source, raw)
+                    results = memo.get(key)
+                    if results is not None:
+                        perf["render_memo_hits"] = \
+                            perf.get("render_memo_hits", 0) + 1
+                        return results
+            if key is None:
+                perf["render_memo_bypass"] = \
+                    perf.get("render_memo_bypass", 0) + 1
+            perf["n_renders"] = perf.get("n_renders", 0) + 1
             t0 = time.perf_counter()
-            review = cache.get(cache_key) if cache_key is not None \
+            review = reviews.get(cache_key) if cache_key is not None \
                 else None
             if review is None:
                 review = target.handle_review(AugmentedUnstructured(
                     object=obj, source=source))
                 if cache_key is not None:
-                    cache[cache_key] = review
+                    reviews[cache_key] = review
             if hasattr(driver, "render_query"):
                 results = driver.render_query(
                     target.name, con, review, cfg).results
@@ -776,6 +837,8 @@ class AuditManager:
                 results = driver._interp.query(
                     target.name, [con], review, cfg).results
             self._attr_render(con, time.perf_counter() - t0)
+            if key is not None:
+                memo.put(key, results)
             return results
 
         return render
@@ -1997,11 +2060,9 @@ class AuditManager:
                         kept[key].append(
                             self._violation(con, objects[oi], msg, details))
             return
+        from gatekeeper_tpu.observability import tracing
+
         target = self.client.target
-        driver = next(
-            (d for d in self.client.drivers if hasattr(d, "query_batch")),
-            None,
-        )
         review_cache: dict = {}
 
         def get_review(oi):
@@ -2018,21 +2079,11 @@ class AuditManager:
             return [get_review(oi) for oi in range(len(objects))]
 
         exact = self.config.exact_totals
-        cfg = ReviewCfg(enforcement_point=AUDIT_EP)
+        render_obj = self._render_fn(source, review_cache)
+        hits0 = self.perf.get("render_memo_hits", 0)
 
         def render(con, oi):
-            self.perf["n_renders"] = self.perf.get("n_renders", 0) + 1
-            t0 = time.perf_counter()
-            if hasattr(driver, "render_query"):
-                results = driver.render_query(
-                    self.client.target.name, con, get_review(oi), cfg
-                ).results
-            else:
-                results = driver._interp.query(
-                    self.client.target.name, [con], get_review(oi), cfg
-                ).results
-            self._attr_render(con, time.perf_counter() - t0)
-            return results
+            return render_obj(con, objects[oi], oi)
 
         for con, total, kept_list in self.fold_swept(
                 swept, len(objects), render, limit, exact,
@@ -2052,6 +2103,11 @@ class AuditManager:
         if rest:
             self._eval_via_drivers(rest, objects, get_reviews(), kept,
                                    totals, limit, overrides=overrides)
+        # on the chunk's fold span (pipeline.stage.fold_render, or
+        # audit.chunk.collect_fold on the serial schedule)
+        tracing.set_attribute(
+            "render_memo_hits",
+            self.perf.get("render_memo_hits", 0) - hits0)
 
     def _chunk_via_query_batch(self, driver, constraints, objects, reviews,
                                kept, totals, limit, overrides=None):

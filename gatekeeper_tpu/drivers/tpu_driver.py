@@ -31,6 +31,7 @@ from gatekeeper_tpu.apis.templates import ConstraintTemplate
 from gatekeeper_tpu.client.types import QueryResponse, Result, Stat, StatsEntry
 from gatekeeper_tpu.drivers.base import ReviewCfg
 from gatekeeper_tpu.drivers.rego_driver import RegoDriver
+from gatekeeper_tpu.drivers.render_token import RenderToken, module_reads
 from gatekeeper_tpu.ir import masks as masks_mod
 from gatekeeper_tpu.ir.lower_rego import lower_template
 from gatekeeper_tpu.ir.program import (CompiledProgram, LowerError,
@@ -124,6 +125,7 @@ class TpuDriver:
         self._inv_cache: dict = {}  # kind -> (versions, cols, exact)
         self._render_specs: dict = {}  # kind -> Optional[list[(spec, col)]]
         self._render_idx: dict = {}  # spec.key() -> (version, value -> entries)
+        self._render_reads: dict = {}  # kind -> (compiled, pure, reads_data)
         self._dev_cache: dict = {}  # host array id -> device array (bounded)
         # extdata/lane.ExtDataLane: explicit attachment wins over the
         # process-active lane (see _active_extdata)
@@ -553,6 +555,35 @@ class TpuDriver:
             data_override={"inventory": {"namespace": ns_tree,
                                          "cluster": cluster_tree}},
         )
+
+    def render_token(self, constraint):
+        """A hashable token for everything ``render_query`` reads for this
+        constraint besides the review, or None where a render must never
+        be memoized (drivers/render_token.py).  It changes whenever the
+        template's compiled modules or the Constraint object are replaced
+        and, for a template that reads ``data``, whenever the data
+        document changed.  Read BEFORE the render it stands for: a data
+        write lands in the store before it bumps the epoch, so a result
+        filed under an older token is never staler than the token."""
+        if constraint.kind in self._cel_kinds:
+            # the CEL evaluator binds the review and the constraint's
+            # parameters and has no data document; none of its functions
+            # (lang/cel/cel.py) reads a clock, a socket or a file
+            compiled = self._cel._templates.get(constraint.kind)
+            return None if compiled is None \
+                else RenderToken(compiled, constraint, 0)
+        compiled = self._interp._templates.get(constraint.kind)
+        if compiled is None:
+            return None
+        reads = self._render_reads.get(constraint.kind)
+        if reads is None or reads[0] is not compiled:
+            reads = (compiled, *module_reads(compiled.modules))
+            self._render_reads[constraint.kind] = reads
+        _, pure, reads_data = reads
+        if not pure:
+            return None
+        return RenderToken(compiled, constraint,
+                           self._data_version if reads_data else 0)
 
     def _render_restrict_specs(self, kind):
         """List of (InvTableSpec, subject column) when every inventory

@@ -10,6 +10,7 @@ import os
 import subprocess
 import sys
 import sysconfig
+import threading
 from typing import Optional
 
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native",
@@ -18,9 +19,20 @@ _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 
 _mods: dict = {}
 _tried: set = set()
+# two flatten workers ask for one module at once on a process's first
+# chunk: the second waits for the first's build instead of reading "tried,
+# none there" and sending its chunk down the dict lane
+_load_lock = threading.Lock()
 
 
 def _load_named(name: str, src_file: str) -> Optional[object]:
+    if name in _mods:
+        return _mods[name]
+    with _load_lock:
+        return _load_named_locked(name, src_file)
+
+
+def _load_named_locked(name: str, src_file: str) -> Optional[object]:
     if name in _mods or name in _tried:
         return _mods.get(name)
     _tried.add(name)

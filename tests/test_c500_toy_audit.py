@@ -64,8 +64,7 @@ def load(client) -> int:
     return n
 
 
-@pytest.fixture(scope="module")
-def world():
+def build_world() -> dict:
     cfg = manifest.read_json(os.path.join(
         ROOT, "benchmark", "configs", "library-c500.json"))
     objects = list(cluster.Cluster(cfg["cluster"], N_OBJECTS,
@@ -87,6 +86,11 @@ def world():
         interp.add_data(obj)
     return {"client": client, "tpu": tpu, "interp": interp,
             "objects": objects, "lines": lines, "n": n_constraints}
+
+
+@pytest.fixture(scope="module")
+def world():
+    return build_world()
 
 
 def test_the_toy_set_has_three_constraints_a_template(world):

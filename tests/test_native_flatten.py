@@ -202,3 +202,31 @@ def test_native_extract_extras_matches_python():
                                       nat.ragged_keysets[spec_].sid)
         np.testing.assert_array_equal(py.ragged_keysets[spec_].count,
                                       nat.ragged_keysets[spec_].count)
+
+
+def test_a_second_thread_waits_for_the_first_load(monkeypatch):
+    """Two flatten workers ask for one module at once on a process's
+    first chunk: the second gets the module the first is still building,
+    not "tried, none there" (which sent its chunk down the dict lane)."""
+    import threading
+    import time
+
+    built = object()
+    building = threading.Event()
+
+    def slow_build(name, src_file):
+        building.set()
+        time.sleep(0.2)
+        return built
+
+    monkeypatch.setattr(native, "_build", slow_build)
+    monkeypatch.setattr(native, "_mods", {})
+    monkeypatch.setattr(native, "_tried", set())
+    got = []
+    first = threading.Thread(target=lambda: got.append(
+        native._load_named("gtpu_flatten", "flattenmod.c")))
+    first.start()
+    assert building.wait(5.0)
+    got.append(native._load_named("gtpu_flatten", "flattenmod.c"))
+    first.join()
+    assert got == [built, built]

@@ -11,6 +11,7 @@ import pytest
 
 from gatekeeper_tpu.apis.constraints import AUDIT_EP
 from gatekeeper_tpu.audit.manager import AuditConfig, AuditManager
+from gatekeeper_tpu.audit.render_memo import RenderMemo
 from gatekeeper_tpu.client.client import Client
 from gatekeeper_tpu.drivers.tpu_driver import TpuDriver
 from gatekeeper_tpu.metrics import registry as M
@@ -453,6 +454,8 @@ def test_account_closes_and_counts_every_listed_object(toy, pipeline, raw):
         evaluator=evaluator)
     mgr.audit()  # whatever compiles, compiles here
     mgr.perf = {}
+    # the measured pass is one that renders, as the first after a boot
+    mgr._render_memo = RenderMemo()
     tracer = tracing.Tracer(seed=0)
     t0 = time.perf_counter()
     with tracing.activate(tracer):
@@ -460,6 +463,7 @@ def test_account_closes_and_counts_every_listed_object(toy, pipeline, raw):
     wall = time.perf_counter() - t0
     perf = mgr.perf
     assert run.total_objects == 40
+    assert perf["n_renders"] > 0 and perf["render_memo_hits"] == 0
     assert perf["list_fast"] + perf["list_slow"] == 40
     fast = 40 if raw and native.load_listroute() is not None else 0
     assert (perf["list_fast"], perf["list_slow"]) == (fast, 40 - fast)
@@ -482,3 +486,71 @@ def test_account_closes_and_counts_every_listed_object(toy, pipeline, raw):
     # a second pass adds to both
     mgr.audit()
     assert mgr.perf["list_fast"] + mgr.perf["list_slow"] == 80
+
+
+# --- the renderer's counters in the account (audit/render_memo.py) ----------
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+@pytest.mark.parametrize("raw", [True, False], ids=["rawjson", "dicts"])
+def test_renderer_counters_are_written_on_every_pass(toy, pipeline, raw):
+    """``n_renders``, ``render``, ``render_memo_hits`` and
+    ``render_memo_bypass`` are in ``perf`` after every pass, a 0 too, and
+    hits plus interpreter renders (of which the bypasses are a part) is
+    the renders the fold asked for: unloaded RawJSON all hit on the second
+    pass, plain dicts bypass the memo on every pass."""
+    from gatekeeper_tpu.utils.rawjson import as_raw
+
+    client, evaluator = toy
+    objects = _objects(40)
+    lister = (lambda: (as_raw(o) for o in objects)) if raw \
+        else (lambda: iter(objects))
+    mgr = AuditManager(
+        client, lister=lister,
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline=pipeline),
+        evaluator=evaluator)
+    asked = []
+    render_fn = mgr._render_fn
+
+    def counting(*args, **kw):
+        render = render_fn(*args, **kw)
+
+        def counted(con, obj, cache_key=None):
+            asked.append(cache_key)
+            return render(con, obj, cache_key)
+        return counted
+
+    mgr._render_fn = counting
+    keys = ("render_memo_hits", "n_renders", "render_memo_bypass", "render")
+    first = mgr.audit()
+    n = len(asked)
+    assert n > 0 and all(k in mgr.perf for k in keys)
+    assert mgr.perf["render_memo_hits"] == 0
+    assert mgr.perf["n_renders"] == n
+    assert mgr.perf["render_memo_bypass"] == (0 if raw else n)
+    mgr.perf = {}
+    second = mgr.audit()
+    assert len(asked) == 2 * n and all(k in mgr.perf for k in keys)
+    hits, renders = mgr.perf["render_memo_hits"], mgr.perf["n_renders"]
+    assert hits + renders == n
+    assert (hits, renders, mgr.perf["render_memo_bypass"]) == \
+        ((n, 0, 0) if raw else (0, n, n))
+    assert (mgr.perf["render"] == 0.0) == raw
+    assert [(v.message, v.name) for vs in second.kept.values() for v in vs] \
+        == [(v.message, v.name) for vs in first.kept.values() for v in vs]
+
+
+def test_a_pass_with_nothing_to_render_still_writes_the_counters(toy):
+    client, evaluator = toy
+    clean = [o for o in _objects(40) if o["metadata"]["labels"]]
+    mgr = AuditManager(
+        client, lister=lambda: iter(clean),
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline="on"),
+        evaluator=evaluator)
+    run = mgr.audit()
+    assert sum(run.total_violations.values()) == 0
+    assert {k: mgr.perf[k] for k in ("render_memo_hits", "n_renders",
+                                     "render_memo_bypass", "render")} == \
+        {"render_memo_hits": 0, "n_renders": 0, "render_memo_bypass": 0,
+         "render": 0.0}
