@@ -15,7 +15,7 @@ with depth cut to 8 chunks (``--objects`` raises it):
               XLA twin (``topk_violations`` + sums);
 2. audit      262,144 synthetic cluster objects streamed as RawJSON from
               a JSONL spill through ``ShardedEvaluator(tpu, make_mesh(1))``
-              -> ``AuditManager.audit()`` (the ``bench.py sweep`` shape:
+              -> ``AuditManager.audit()`` (the benchmark's audit shape:
               violating-object totals, kept violations rendered).  Pass 1
               (warm pass + compile) is set-up; pass 2 must trace nothing,
               drop or retry no chunk, and flatten on the raw C lane;
@@ -133,6 +133,58 @@ class Recorder:
 
 
 # --- phase 1: kernels ------------------------------------------------------
+
+def build_client():
+    """TpuDriver + CELDriver -> Client with the entire shipped library."""
+    from gatekeeper_tpu.apis.constraints import AUDIT_EP, WEBHOOK_EP
+    from gatekeeper_tpu.client.client import Client
+    from gatekeeper_tpu.drivers.cel_driver import CELDriver
+    from gatekeeper_tpu.drivers.tpu_driver import TpuDriver
+    from gatekeeper_tpu.target.target import K8sValidationTarget
+    from gatekeeper_tpu.utils.synthetic import load_library
+
+    cel = CELDriver()
+    tpu = TpuDriver(cel_driver=cel)
+    client = Client(target=K8sValidationTarget(),
+                    drivers=[tpu, cel],
+                    enforcement_points=[WEBHOOK_EP, AUDIT_EP])
+    nt, nc = load_library(client)
+    fb = tpu.fallback_kinds()
+    check(not fb, f"library templates fell back to interpreter: {fb}")
+    return client, tpu, nt, nc
+
+
+def spill_corpus(client, n: int, spill_fd: int, seed: int = 0) -> int:
+    """Stream ``n`` synthetic cluster objects to the JSONL spill (the
+    reference's disk list-cache) and sync the Ingresses into the
+    inventory for the referential join.  Returns the Ingress count."""
+    from gatekeeper_tpu.utils.synthetic import iter_cluster_objects
+
+    n_ing = 0
+    with os.fdopen(spill_fd, "wb") as f:
+        for o in iter_cluster_objects(n, seed):
+            if o.get("kind") == "Ingress":
+                client.add_data(o)  # referential inventory sync
+                n_ing += 1
+            f.write(json.dumps(o, separators=(",", ":")).encode())
+            f.write(b"\n")
+    return n_ing
+
+
+def spill_lister(path: str, limit: int = 0):
+    """A lister streaming the spill as RawJSON (``limit`` > 0: only its
+    first ``limit`` objects)."""
+    from gatekeeper_tpu.utils.rawjson import RawJSON
+
+    def lister():
+        with open(path, "rb") as f:
+            for i, line in enumerate(f):
+                if limit and i >= limit:
+                    return
+                yield RawJSON(line.rstrip(b"\n"))
+
+    return lister
+
 
 def phase_kernels(shapes, interpret: bool) -> dict:
     import jax
@@ -312,6 +364,26 @@ def _answer(resp: dict) -> tuple:
             frozenset(r.get("warnings") or ()))
 
 
+def _admission_body(i: int) -> bytes:
+    """The AdmissionReview of a CREATE of synthetic cluster object ``i``."""
+    from gatekeeper_tpu.utils.synthetic import make_cluster_objects
+    from gatekeeper_tpu.utils.unstructured import gvk_of
+
+    obj = make_cluster_objects(1, seed=i)[0]
+    g, v, k = gvk_of(obj)
+    return json.dumps({
+        "apiVersion": "admission.k8s.io/v1", "kind": "AdmissionReview",
+        "request": {
+            "uid": f"u{i}", "operation": "CREATE",
+            "kind": {"group": g, "version": v, "kind": k},
+            "name": obj["metadata"].get("name", ""),
+            "namespace": obj["metadata"].get("namespace", ""),
+            "userInfo": {"username": "load"},
+            "object": obj,
+        },
+    }).encode()
+
+
 def phase_admission(client, metrics, seed: int, requests: int, conns: int,
                     sequential: int) -> dict:
     """Batcher -> ValidationHandler -> WebhookServer over real HTTP.
@@ -329,9 +401,7 @@ def phase_admission(client, metrics, seed: int, requests: int, conns: int,
     from gatekeeper_tpu.target.review import AugmentedUnstructured
     from gatekeeper_tpu.webhook.policy import Batcher, ValidationHandler
     from gatekeeper_tpu.webhook.server import WebhookServer
-    from tools.loadtest_webhook import make_body
-
-    bodies = [make_body(seed * 100_003 + i) for i in range(sequential)]
+    bodies = [_admission_body(seed * 100_003 + i) for i in range(sequential)]
     batcher = Batcher(client, metrics=metrics)  # pumped below, not started
     check(batcher.small_batch < conns <= batcher.max_batch,
           f"a burst of {conns} would not be one grid flush")
@@ -626,7 +696,6 @@ def main(argv=None) -> int:
             r.update(phase_kernels(size["kernel_shapes"],
                                    interpret=not on_tpu))
 
-        from bench import build_client, spill_corpus, spill_lister
         from gatekeeper_tpu.metrics.registry import MetricsRegistry
         from gatekeeper_tpu.parallel.sharded import (ShardedEvaluator,
                                                      make_mesh)

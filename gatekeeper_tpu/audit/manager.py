@@ -157,8 +157,8 @@ class AuditRun:
     failed_chunks: int = 0
     retried_chunks: int = 0
     # effective ingest/dispatch geometry of the pass, recorded so
-    # SWEEP1M history entries and `--once` output are self-describing
-    # (no cross-referencing of flags to know what a run measured)
+    # `--once` output is self-describing (no cross-referencing of flags
+    # to know what a run measured)
     flatten_workers: int = 0
     n_devices: int = 0
     shard_chunks: int = 0
@@ -335,8 +335,8 @@ class AuditManager:
     # --- one sweep (reference: audit(), manager.go:258) -----------------
     def audit(self) -> AuditRun:
         """One sweep under its root span: the per-stage busy/wall/idle
-        numbers the ROADMAP says to read from the bench JSON are ALSO
-        recorded as attributes here, so a trace timeline carries them."""
+        numbers are recorded as attributes here, so a trace timeline
+        carries them."""
         from gatekeeper_tpu.observability import tracing
 
         with tracing.span("audit.sweep") as sp:
@@ -1912,8 +1912,8 @@ class AuditManager:
                                    s["queue_highwater"], lab)
         self.metrics.set_gauge(M.PIPELINE_DEVICE_WAIT,
                                self.pipe_stats.get("device_wait_s", 0.0))
-        # sweep-level aggregates (previously only in the bench JSON):
-        # wall vs summed stage busy is the overlap proof, scrapeable now
+        # sweep-level aggregates: wall vs summed stage busy is the
+        # overlap proof
         self.metrics.set_gauge(M.PIPELINE_WALL,
                                self.pipe_stats.get("wall_s", 0.0))
         self.metrics.set_gauge(

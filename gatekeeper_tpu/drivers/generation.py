@@ -273,8 +273,8 @@ def warm_yield_s(cpu_count: Optional[int] = None) -> float:
 
     Tracing is GIL-held Python: on a 1-core host, back-to-back kernel
     traces starve the serving thread for the whole warm, so each trace
-    leaves a bounded 5ms gap (the measured storm-P99 sweet spot —
-    CHURN_BENCH pins 1-core behavior unchanged).  Hosts with spare
+    leaves a bounded 5ms gap (``tests/test_generation.py`` pins the
+    1-core value).  Hosts with spare
     cores need (almost) none: the serving thread runs on another core
     while the warm traces, and every gap only stretches the warm —
     which delays the swap the serving path is waiting on.  Few-core

@@ -31,8 +31,7 @@ that sharing real:
   and each cluster's segment folds back into its own verdict store
   bit-identically to N independent sweeps (segments keep canonical row
   order; verdict grids are per-row).  For K small clusters the
-  dispatch count and padding waste collapse ~K-fold — the measurable
-  1-core win FLEET_BENCH.json records.
+  dispatch count and padding waste collapse ~K-fold.
 
 Packing rules (what keeps the fold bit-identical by construction):
 segments stay contiguous and in canonical row order; only rows of the
@@ -150,8 +149,7 @@ class FleetCluster:
 
     def sweep_independent(self, full: bool = True) -> AuditRun:
         """The unpacked reference: this cluster swept alone through the
-        standard snapshot audit path (the fleet differential's oracle,
-        and the sequential lane FLEET_BENCH compares against)."""
+        standard snapshot audit path (the fleet differential's oracle)."""
         if full:
             return self.manager.audit()
         return self.manager.audit_tick()
@@ -383,7 +381,7 @@ class FleetEvaluator:
         already-built snapshot ticks (O(churn)), a cold one takes the
         full build+evaluate.  ``pack=False`` keeps per-cluster
         dispatches (the N-independent-sweeps geometry) while still
-        sharing the runtimes — the bench's sequential lane."""
+        sharing the runtimes."""
         from gatekeeper_tpu.observability import tracing
 
         t0 = time.time()

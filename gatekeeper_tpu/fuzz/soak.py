@@ -107,8 +107,8 @@ def default_chaos_plan(seed: int = 0):
 
 def _library_docs(keep: int = 3) -> list:
     """First ``keep`` shipped templates + their sample constraints as
-    unstructured docs (the bench_replay idiom, inlined so the harness
-    has no tools/ dependency)."""
+    unstructured docs (the `--candidate` input shape of `gator
+    replay`)."""
     from gatekeeper_tpu.utils.synthetic import library_dir
     from gatekeeper_tpu.utils.unstructured import load_yaml_file
 

@@ -388,10 +388,9 @@ RESILIENCE_DEADLINE_EXCEEDED = \
 RESILIENCE_STALE_SERVED = "resilience_stale_served_count"  # {dependency}
 RESILIENCE_DEGRADED = "resilience_degraded_count"  # {component, to}
 RESILIENCE_CHUNKS_FAILED = "resilience_audit_chunks_failed_count"
-# sweep-level pipeline aggregates (the ROADMAP's "read stage_busy_sum_s
-# vs wall_s" numbers, scraped instead of dug out of the bench JSON): wall
-# seconds of the last pipelined sweep and the sum of stage busy seconds
-# across stages (> wall == measured overlap)
+# sweep-level pipeline aggregates (the "stage_busy_sum_s vs wall_s"
+# numbers): wall seconds of the last pipelined sweep and the sum of
+# stage busy seconds across stages (> wall == measured overlap)
 PIPELINE_WALL = "audit_pipeline_wall_seconds"
 PIPELINE_STAGE_BUSY_SUM = "audit_pipeline_stage_busy_sum_seconds"
 # span tracer (observability/tracing.py): tail-sampler outcomes — how
@@ -418,7 +417,7 @@ FLATTEN_WORKER_FALLBACKS = "flatten_worker_fallback_count"
 # deduped miss list), per-key outcomes (warm = resident column hit with
 # zero transport, fetched = landed through a bulk call, perkey = the
 # reference lane's single-key fetches), and the resident column size —
-# together the "round-trips collapsed" story EXTDATA_BENCH measures
+# together the "round-trips collapsed" story
 EXTDATA_BULK_CALLS = "extdata_bulk_calls_count"  # {provider}
 EXTDATA_KEYS = "extdata_keys_count"  # {provider, outcome}
 EXTDATA_COLUMN_KEYS = "extdata_column_keys"  # gauge {provider}

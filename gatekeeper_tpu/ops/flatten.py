@@ -811,10 +811,9 @@ def round_up(n: int, bucket: int = 8) -> int:
 
 # --- multiprocess flatten worker pool (--flatten-workers) ------------------
 #
-# The sweep's host ceiling is the columnize loop (SWEEP1M: flatten 13.9s
-# of a 42.9s 1M-object pass), and a single process cannot scale it past
-# one core's worth of GIL-held assembly no matter how many pthreads the
-# C columnizer runs.  The pool fans contiguous SPANS of a chunk's raw
+# A single process cannot scale the columnize loop past one core's worth
+# of GIL-held assembly no matter how many pthreads the C columnizer
+# runs.  The pool fans contiguous SPANS of a chunk's raw
 # JSON byte items (bytes pickle cheaply; no DOM ever crosses the process
 # boundary) across N worker processes, each running the C columnizer
 # against a batch-local vocab; the parent then interns each worker's

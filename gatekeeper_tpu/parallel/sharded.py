@@ -799,7 +799,7 @@ class ShardedEvaluator:
         # device-dispatch counter: every real sweep dispatch (incl. the
         # reduced lane's masks fallback re-dispatch) bumps it — the
         # fleet packing win (K clusters' chunks collapsing into one
-        # dispatch) reads the delta, as does FLEET_BENCH
+        # dispatch) reads the delta
         self.dispatch_count = 0
         # warm-state record (drivers/generation.WarmStateCache): every
         # NEW fused executable's serializable descriptor + the input
@@ -824,7 +824,7 @@ class ShardedEvaluator:
         self._bucket = 2
         # per-phase wall-clock totals (seconds), reset via perf_reset():
         # flatten / masks / wire_pack / dispatch (device_put + jit call) /
-        # collect (device->host) — published by bench.py.  The lock makes
+        # collect (device->host).  The lock makes
         # accumulation safe under the staged pipeline, where flatten /
         # dispatch / collect run on different stage threads.
         self.perf: dict = {}

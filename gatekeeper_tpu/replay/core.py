@@ -116,8 +116,8 @@ def load_candidate(docs, compile_cache_dir: str = "",
     """Build the candidate evaluation runtime from unstructured docs
     (templates + constraints + cluster fixtures).  With a warm
     ``compile_cache_dir`` every template loads via the shared compile
-    cache — zero fresh lowerings, the replay-at-sweep-speed invariant
-    ``REPLAY_BENCH.json`` pins.
+    cache — zero fresh lowerings, the invariant ``tests/test_replay.py``
+    pins.
 
     ``namespaces`` (name -> v1/Namespace object) overrides the fixtures
     found in ``docs`` — pass :func:`namespaces_from_spill` output to

@@ -2,10 +2,9 @@
 
 PR 6 cut the steady-state sweep to O(churn) and PR 12's compile cache
 cut the restart COMPILE cost to zero — but a restarted auditor still
-relists + reflattens the world before its first sweep (SNAPSHOT_BENCH:
-3.42s for 20k objects, and that is the cheap part of a real cluster).
-This module spills the complete resident audit state to disk and loads
-it back on boot:
+relists + reflattens the world before its first sweep.  This module
+spills the complete resident audit state to disk and loads it back on
+boot:
 
 - per-group tall ColumnBatches, trimmed to real extents and re-padded to
   capacity on load (``GroupStore.export_rows``/``import_rows``);

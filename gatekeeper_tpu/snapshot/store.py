@@ -1,11 +1,10 @@
 """Resident columnar cluster snapshot: sweep cost O(churn), not O(cluster).
 
-Every relist-mode audit pass re-lists and re-flattens the whole cluster
-(SWEEP1M: flatten alone is 13.9s of the 42.9s 1M-object sweep).  The
-reference never does that — its watch manager / cachemanager keep a
-synced cache and the audit reads from it (PAPER.md L1/L2:
-``AddData``/``RemoveData`` on the Driver seam).  This module is the
-columnar version of that cache:
+Every relist-mode audit pass re-lists and re-flattens the whole
+cluster.  The reference never does that — its watch manager /
+cachemanager keep a synced cache and the audit reads from it (PAPER.md
+L1/L2: ``AddData``/``RemoveData`` on the Driver seam).  This module is
+the columnar version of that cache:
 
 - the flattened column arrays (plus vocab sids and canon columns) stay
   RESIDENT between sweeps, one tall :class:`ColumnBatch` per kind-group

@@ -1,8 +1,8 @@
 """Placement of JAX's persistent compilation cache (XLA executables).
 
 One rule for every entry point that compiles (``python -m
-gatekeeper_tpu``, the fleet runner, ``gator bench``, ``bench.py``, the
-evaluate sidecar, ``chip_smoke.py``): the cache directory is placed from
+gatekeeper_tpu``, the fleet runner, ``gator bench``, the evaluate
+sidecar, ``chip_smoke.py``): the cache directory is placed from
 OUTSIDE the program.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 already reads it and this module never touches
 ``jax_compilation_cache_dir``; otherwise the cache lives at one fixed

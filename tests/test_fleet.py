@@ -17,14 +17,14 @@
    cluster's heaviest tenant deterministically.
 5. Satellites: `/v1/mutate` raw-bytes ingest (outcome parity + the
    column differential lane), the flight recorder / `gator decisions`
-   `cluster` axis, and the FLEET_BENCH smoke (dispatch reduction >= 2x
-   at K=4).
+   `cluster` axis, and packed vs sequential at K=4 same-library
+   clusters (dispatch reduction >= 2x).
 
 Wall-budget note: one module-scoped fleet (5-template library slice,
-<=48 objects per cluster) and a shared compile-cache dir; the bench
-smoke reuses the same cache (tier-1 budget was freed by moving two
-overlapping heavy tests to the slow lane — see test_pipeline.py /
-test_tracing_integration.py).
+<=48 objects per cluster) and a shared compile-cache dir; the packed
+vs sequential test reuses the same cache (tier-1 budget was freed by
+moving two overlapping heavy tests to the slow lane — see
+test_pipeline.py / test_tracing_integration.py).
 """
 
 from __future__ import annotations
@@ -536,25 +536,37 @@ def test_fleet_config_roundtrip(tmp_path):
         load_fleet_config(str(p))
 
 
-# --- 6. FLEET_BENCH smoke --------------------------------------------------
+# --- 6. packed against sequential, K=4 same-library clusters ---------------
+
+def _fleet_pass(fleet, pack):
+    """One full fleet pass with every snapshot row re-dirtied first, so
+    both lanes evaluate identical row sets.  Returns (runs, dispatches)."""
+    for fc in fleet.clusters.values():
+        for rows in fc.snapshot.all_rows().values():
+            fc.snapshot._dirty.update(g for g, _p in rows)
+    ev = fleet.runtimes()[0].evaluator
+    d0 = ev.dispatch_count
+    runs = fleet.sweep(full=True, pack=pack)
+    return runs, ev.dispatch_count - d0
+
 
 def test_bench_fleet_smoke_pins_dispatch_reduction(fleet_ctx):
-    """tools/bench_fleet.py --smoke in-process (shared compile cache):
-    K=4 small clusters packed vs sequential — dispatch reduction >= 2x,
-    verdicts bit-identical, second cluster zero lowering."""
-    import importlib.util
-    import pathlib
-
-    tools = pathlib.Path(__file__).resolve().parent.parent / "tools"
-    spec = importlib.util.spec_from_file_location(
-        "bench_fleet", tools / "bench_fleet.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    rec = bench.run_bench(k=4, n_objects=40, write=False,
-                          cache_dir=fleet_ctx["cache_dir"])
-    hl = rec["headline"]
-    assert hl["verdicts_bit_identical"]
-    assert hl["second_cluster_zero_lowering"]
-    assert hl["dispatch_reduction"] >= 2.0, hl
-    assert rec["lanes"]["packed"]["dispatches"] < \
-        rec["lanes"]["sequential"]["dispatches"]
+    """K=4 small clusters over one library, packed vs sequential (shared
+    compile cache): dispatch reduction >= 2x, verdicts bit-identical,
+    every cluster past the first attaches with zero lowering."""
+    k = 4
+    skip = tuple(_all_kinds()[_KEEP:])
+    fleet = FleetEvaluator(chunk_size=500, exact_totals=False)
+    for i in range(k):
+        fleet.add_cluster(f"c{i:02d}", _source(40, 11 + i), "lib",
+                          _builder(fleet_ctx["cache_dir"], skip))
+    try:
+        assert fleet.shared_boots == k - 1
+        sequential, n_seq = _fleet_pass(fleet, pack=False)
+        packed, n_packed = _fleet_pass(fleet, pack=True)
+        for cid, run in packed.items():
+            _assert_identical(run, sequential[cid])
+        assert n_packed < n_seq
+        assert n_seq / max(1, n_packed) >= 2.0, (n_seq, n_packed)
+    finally:
+        fleet.stop()

@@ -23,8 +23,6 @@ Acceptance pins:
 
 import http.client
 import json
-import os
-import sys
 import threading
 import time
 
@@ -38,8 +36,7 @@ from gatekeeper_tpu.resilience import qos
 from gatekeeper_tpu.resilience.faults import FaultPlan, inject
 from gatekeeper_tpu.webhook.policy import ValidationHandler
 from gatekeeper_tpu.webhook.server import WebhookServer
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from tests.traffic_helpers import drive_tenant_mix
 
 
 class _EmptyResponses:
@@ -599,15 +596,12 @@ def test_gator_decisions_reader_matches_debug_semantics(tmp_path):
         == 2
 
 
-# --- bench harness smoke ---------------------------------------------------
+# --- a tenant mix against a live server ------------------------------------
 
 def test_bench_tenant_mix_smoke_toy_scale():
-    """The ``bench.py --burst`` multi-tenant mix driver at toy scale:
-    per-tenant stats + a computable isolation_ratio against a live
-    server with QoS on (the full-library run happens in the bench lane,
-    not tier-1)."""
-    import bench
-
+    """A multi-tenant closed-loop mix at toy scale against a live server
+    with QoS on: every tenant's requests are answered or shed, none
+    errors, and the quiet tenant survives the noisy one."""
     reg = MetricsRegistry()
     cfg = qos.QoSConfig(tenant_inflight_cap=2)
     ctl = ovl.OverloadController(ovl.OverloadConfig(
@@ -622,9 +616,9 @@ def test_bench_tenant_mix_smoke_toy_scale():
                                   namespace=ns)).encode()
                  for i in range(8)]
             for ns in ("tenant-a", "tenant-b", "kube-system")}
-        anchor = bench.drive_tenant_mix(srv.port, [
+        anchor = drive_tenant_mix(srv.port, [
             {"name": "tenant-b", "conc": 1, "n": 6}], bodies)
-        mix = bench.drive_tenant_mix(srv.port, [
+        mix = drive_tenant_mix(srv.port, [
             {"name": "tenant-a", "conc": 6, "n": 24},
             {"name": "tenant-b", "conc": 1, "n": 6},
             {"name": "kube-system", "conc": 1, "n": 4},

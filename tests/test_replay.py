@@ -18,8 +18,8 @@
    verdict store — differential bit-identity, constraint-drop diff,
    section integrity, and the TWO-WAY vocab prefix rule (snapshot ⊆
    current is a hit; a diverged overlap is a counted vocab miss).
-6. ``bench.py replay --smoke`` rides tier-1 so REPLAY_BENCH.json's
-   pins (bit-identity, zero-fresh-lowerings) cannot rot.
+6. The whole round trip once more on a second, smaller corpus served
+   from the warm cache: record, read, replay identical and modified.
 7. ``gator decisions`` + flight-recorder sink: truncated-tail vs
    malformed accounting, torn-tail sink repair on append.
 
@@ -31,7 +31,6 @@ candidate load after the first is all cache hits.
 from __future__ import annotations
 
 import copy
-import importlib.util
 import json
 import os
 import shutil
@@ -51,38 +50,29 @@ from gatekeeper_tpu.snapshot import (ClusterSnapshot, SnapshotConfig,
 from gatekeeper_tpu.sync.source import FakeCluster
 from gatekeeper_tpu.utils.synthetic import make_cluster_objects
 from gatekeeper_tpu.utils.unstructured import name_of
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_tool(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "tools", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from tests import traffic_helpers
 
 
-@pytest.fixture(scope="module")
-def bench():
-    return _load_tool("bench_replay")
-
-
-@pytest.fixture(scope="module")
-def corpus(bench, tmp_path_factory):
-    """A recorded corpus: the bench's serving stack (real
-    ValidationHandler + capture-mode flight recorder) answers 90
-    synthetic admissions over a 5-template library slice; the sink and
-    the warm compile cache are shared module-wide."""
-    cache_dir = str(tmp_path_factory.mktemp("replay-cc"))
-    sink = os.path.join(str(tmp_path_factory.mktemp("replay-sink")),
-                        "decisions.jsonl")
-    docs = bench._library_docs()
-    bodies = bench._admission_bodies(90)
-    serve = bench._serve_and_record(docs, bodies, sink, cache_dir)
+def _record(n_requests, cache_dir, sink_dir):
+    """Serve ``n_requests`` synthetic admissions over the 5-template
+    library slice and read the sink back as a replay corpus."""
+    sink = os.path.join(str(sink_dir), "decisions.jsonl")
+    docs = traffic_helpers.library_docs()
+    bodies = traffic_helpers.admission_bodies(n_requests)
+    serve = traffic_helpers.serve_and_record(docs, bodies, sink, cache_dir)
     records, counts = core.read_corpus(sink)
     return {"cache_dir": cache_dir, "sink": sink, "docs": docs,
             "serve": serve, "records": records, "counts": counts}
+
+
+@pytest.fixture(scope="module")
+def corpus(tmp_path_factory):
+    """A recorded corpus: a serving stack (real
+    ValidationHandler + capture-mode flight recorder) answers 90
+    synthetic admissions over a 5-template library slice; the sink and
+    the warm compile cache are shared module-wide."""
+    return _record(90, str(tmp_path_factory.mktemp("replay-cc")),
+                   tmp_path_factory.mktemp("replay-sink"))
 
 
 def _replay(corpus, docs, **kw):
@@ -382,17 +372,23 @@ def test_replay_cli_from_spill(corpus, spilled, tmp_path, capsys):
     assert report["differential"]["bit_identical"]
 
 
-# --- 6. the bench smoke (REPLAY_BENCH.json cannot rot) ---------------------
+# --- 6. the round trip on a second corpus, served from the warm cache ------
 
-def test_bench_replay_smoke(corpus, bench):
-    rec = bench.run_bench(n_requests=60, write=False,
-                          cache_dir=corpus["cache_dir"])
-    assert rec["headline"]["bit_identical"]
-    assert rec["headline"]["zero_fresh_lowerings"]
-    assert rec["identical"]["divergences_total"] == 0
-    assert rec["corpus"]["records"] == 60
-    mod = rec["modified"]
-    assert "skipped" in mod or mod["newly_allowed"] > 0
+def test_bench_replay_smoke(corpus, tmp_path):
+    second = _record(60, corpus["cache_dir"], tmp_path)
+    assert len(second["records"]) == 60
+    ident = _replay(second, second["docs"], differential=True)
+    assert ident["differential"]["bit_identical"]
+    cc = ident["compile_cache"]
+    assert cc["misses"] == 0 and cc["hits"] > 0  # zero fresh lowerings
+    assert ident["divergences_total"] == 0
+    # modified lane: drop the first constraint with recorded denies (a
+    # corpus that recorded none would assert on noise)
+    if second["serve"]["denies"]:
+        drop = _dropped_deny_constraint(second)
+        docs = [d for d in second["docs"]
+                if not (reader.is_constraint(d) and name_of(d) == drop)]
+        assert _replay(second, docs)["newly_allowed"] > 0
 
 
 # --- 7. gator decisions + sink hardening -----------------------------------

@@ -27,9 +27,7 @@ cache; every runtime after the first loads with zero fresh lowerings.
 from __future__ import annotations
 
 import copy
-import importlib.util
 import json
-import os
 import urllib.error
 import urllib.request
 
@@ -45,16 +43,7 @@ from gatekeeper_tpu.replay.shadow import SHADOW_OBJECTIVE, ShadowLane
 from gatekeeper_tpu.utils.unstructured import name_of
 from gatekeeper_tpu.webhook.policy import ValidationResponse
 from gatekeeper_tpu.webhook.server import WebhookServer
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_replay", os.path.join(REPO, "tools", "bench_replay.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from tests import traffic_helpers
 
 
 @pytest.fixture(scope="module")
@@ -62,10 +51,9 @@ def ctx(tmp_path_factory):
     """Serving (full 3-template library) and candidate (same library
     minus one deny-firing constraint) runtimes over one shared compile
     cache, plus the traffic split by the serving verdict."""
-    bench = _load_bench()
     cache_dir = str(tmp_path_factory.mktemp("shadow-cc"))
-    full = bench._library_docs(3)
-    bodies = bench._admission_bodies(40, seed=5)
+    full = traffic_helpers.library_docs(3)
+    bodies = traffic_helpers.admission_bodies(40, seed=5)
     serving = core.load_candidate(full, compile_cache_dir=cache_dir)
     served = [serving.handler.handle(copy.deepcopy(b)) for b in bodies]
     denied = [b for b, r in zip(bodies, served) if not r.allowed]

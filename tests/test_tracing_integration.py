@@ -235,7 +235,7 @@ def test_pipelined_sweep_emits_chunk_scoped_stage_spans(tmp_path):
     assert any(s["name"] == "device.sweep_collect" for s in spans)
 
     # Chrome export of this sweep is a valid trace-event file with the
-    # chunk indices riding the args (the bench.py --trace artifact shape)
+    # chunk indices riding the args (the --trace artifact shape)
     path = tmp_path / "sweep_trace.json"
     export.write_chrome_trace(str(path), tracer)
     doc = json.loads(path.read_text())
@@ -343,31 +343,6 @@ def test_gator_bench_prints_span_summary(tmp_path, capsys):
                for e in doc["traceEvents"])
     # the bench-scoped tracer did not leak into the process
     assert tracing.active_tracer() is None
-
-
-@pytest.mark.slow
-def test_bench_py_trace_artifact(tmp_path):
-    """Acceptance: ``bench.py --trace out.json`` over the library corpus
-    writes a valid Chrome trace-event file with pipeline stage spans
-    (chunk indices) and device dispatch spans."""
-    import os
-    import subprocess
-    import sys
-
-    out = tmp_path / "trace.json"
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--pipeline=on", f"--trace={out}",
-         "800", "256"],
-        cwd="/root/repo", timeout=560, capture_output=True, text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    doc = json.loads(out.read_text())
-    evs = doc["traceEvents"]
-    assert any(e["ph"] == "X" and e["name"].startswith("pipeline.stage.")
-               and "chunk" in e["args"] for e in evs)
-    assert any(e.get("name") == "device.sweep_dispatch" for e in evs)
-    assert any(e.get("name") == "audit.sweep"
-               and "stage_busy_sum_s" in e["args"] for e in evs)
 
 
 def test_retry_and_breaker_events_ride_the_ambient_span():
