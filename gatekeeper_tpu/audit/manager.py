@@ -1476,7 +1476,9 @@ class AuditManager:
             # this generator twice — a stale buffer would double-expand
             self._gen_buf = []
         chunk_size = self.config.chunk_size
-        counts = [0, 0]  # listed by the native call / one at a time
+        # listed by the native call / one at a time / taken off the
+        # cyclic collector's lists by the native call
+        counts = [0, 0, 0]
         try:
             if use_router:
                 from gatekeeper_tpu.observability import tracing
@@ -1492,6 +1494,7 @@ class AuditManager:
                         chunk_size, counter, counts, kind_filter, tee):
                     tracing.set_attribute("list_fast", counts[0])
                     tracing.set_attribute("list_slow", counts[1])
+                    tracing.set_attribute("list_untracked", counts[2])
                     cg = cons_of_group.get(g)
                     if cg is None:
                         cg = [c for c in constraints if c.kind in g]
@@ -1517,10 +1520,13 @@ class AuditManager:
                 if chunk:
                     yield chunk, constraints
         finally:
-            # both on every pass, a 0 too: the share of the listing that
-            # ran as native calls (benchmark: list.fast_share)
+            # all three on every pass, a 0 too: the share of the listing
+            # that ran as native calls (benchmark: list.fast_share) and
+            # the share the collector no longer walks
+            # (python_gc.untracked_share)
             self._perf_add("list_fast", counts[0])
             self._perf_add("list_slow", counts[1])
+            self._perf_add("list_untracked", counts[2])
 
     # --- serial schedule (eager-poll, the one-core-safe path) ------------
     def _sweep_serial(self, constraints, kind_filter, use_router, device,

@@ -7,9 +7,12 @@ pass's calling thread, under the GIL every stage behind it shares, so it
 runs as one native call per chunk (``native/listroutemod.c``) wherever
 the module builds: the call reads the kind from the head of an unloaded
 ``RawJSON``'s bytes and asks ``peek_kind`` for every object whose head
-settles nothing.  :func:`route_chunks_py` is the per-object loop it
-replaces: the fallback, and the reference the native call is tested
-against (``tests/test_list_routing.py``).
+settles nothing.  On the way it takes every unloaded, still empty
+``RawJSON`` off the cyclic collector's lists (such an object can be part
+of no cycle; ``utils/rawjson`` puts it back the moment it loads).
+:func:`route_chunks_py` is the per-object loop it replaces: the fallback,
+and the reference the native call is tested against
+(``tests/test_list_routing.py``); its objects stay tracked.
 """
 
 from __future__ import annotations
@@ -27,7 +30,8 @@ def route_chunks(objects, router, chunk_size, counter, counts,
     grows by every other object, routed or not (an empty group: no
     template reaches the kind).  ``counts[0]`` grows by the objects the
     native call settled by itself, ``counts[1]`` by those that went
-    through ``peek_kind``, one at a time.
+    through ``peek_kind``, one at a time, and ``counts[2]`` by those the
+    native call took off the cyclic collector's lists.
 
     ``tee(obj, kind)``, if given, sees every object with its kind before
     the filter; such a stream stays on the per-object loop."""

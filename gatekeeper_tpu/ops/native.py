@@ -80,10 +80,13 @@ def load_listroute() -> Optional[object]:
     if "gtpu_listroute" not in _tried:
         mod = _load_named("gtpu_listroute", "listroutemod.c")
         if mod is not None:
-            from gatekeeper_tpu.utils.rawjson import RawJSON
+            from gatekeeper_tpu.utils import rawjson
 
+            # what route() takes off the collector's lists a load puts
+            # back: in place before bind(), without which route() refuses
+            rawjson._gc_track = mod.track
             try:
-                mod.bind(RawJSON)
+                mod.bind(rawjson.RawJSON)
             except TypeError as e:  # not the class this was written for
                 sys.stderr.write(f"gtpu_listroute unusable ({e}); "
                                  "using the per-object loop\n")

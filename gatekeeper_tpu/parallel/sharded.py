@@ -1263,7 +1263,7 @@ class ShardedEvaluator:
             # the audit's own chunking: the warmed chunks are the
             # measured ones
             for g, buf in route_chunks(objects, make_kind_router(constraints),
-                                       chunk_size, [0], [0, 0]):
+                                       chunk_size, [0], [0, 0, 0]):
                 scan_chunk(g, buf)
         else:
             g_all = frozenset(c.kind for c in constraints)

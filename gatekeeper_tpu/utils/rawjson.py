@@ -13,11 +13,31 @@ once and self-populates.  The flatten fast path recognizes the class and
 reads ``.raw`` without ever triggering the parse; only slow-path matchers
 and violation rendering — a tiny fraction of a sweep — pay for
 materialization.
+
+An unloaded instance refers to one ``bytes`` and one ``bool`` and can be
+part of no cycle, so the audit lister's native router
+(native/listroutemod.c) takes it off the cyclic collector's lists; it
+goes back on them here, in :func:`_mark_loaded`, before anything can be
+put into it.
 """
 
 from __future__ import annotations
 
 import json
+
+# native/listroutemod.c's track(), set by ops/native.load_listroute()
+# when it binds the module to RawJSON.  Only that module untracks, so
+# while this is None every instance is still tracked.
+_gc_track = None
+
+
+def _mark_loaded(r: "RawJSON") -> None:
+    """``r`` is about to hold its document: from here on the collector
+    has to see it (that CPython 3.12's dict tracks itself when a container
+    goes in is that interpreter's rule, not a contract)."""
+    r._loaded = True
+    if _gc_track is not None:
+        _gc_track(r)
 
 
 class RawJSON(dict):
@@ -32,7 +52,7 @@ class RawJSON(dict):
 
     def _load(self):
         if not self._loaded:
-            self._loaded = True
+            _mark_loaded(self)
             obj = json.loads(self.raw)
             if isinstance(obj, dict):
                 dict.update(self, obj)
@@ -137,7 +157,7 @@ class RawJSON(dict):
 
 def _restore_loaded(raw: bytes, state: dict) -> "RawJSON":
     r = RawJSON(raw)
-    r._loaded = True
+    _mark_loaded(r)
     dict.update(r, state)
     return r
 

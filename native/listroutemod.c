@@ -15,6 +15,14 @@
  * the two forms utils/rawjson._HEAD_KIND accepts, with no quote and no
  * backslash inside either value, and a kind that is UTF-8.  Everything
  * else goes through peek_kind, object by object, and is counted.
+ *
+ * What it takes off the cyclic collector's lists on the way: every such
+ * unloaded RawJSON whose dict is still empty.  It refers to one bytes and
+ * one bool and so can be part of no cycle, yet as an instance of a dict
+ * subclass it is tracked from birth, and a pass's worth of them, each
+ * alive as long as its chunk, is what promoted into CPython's full
+ * collections.  utils/rawjson puts the object back through track() the
+ * moment it loads, before a container can go into it.
  */
 
 #define PY_SSIZE_T_CLEAN
@@ -118,17 +126,26 @@ py_head_kind(PyObject *self, PyObject *arg)
 /* The entry of an unloaded RawJSON's kind, read from the head of its
  * bytes: a new reference, or NULL with *err 0 where the head settles
  * nothing (the caller asks peek_kind) and *err 1 with an error set.
- * kinds maps kind bytes to entries and is filled here, once per kind. */
+ * kinds maps kind bytes to entries and is filled here, once per kind.
+ * An object that is unloaded, holds a bytes and has an empty dict leaves
+ * the collector's lists here, whether or not its head then settles its
+ * kind, and *untracked grows by one. */
 static PyObject *
-head_entry(PyObject *obj, PyObject *kinds, PyObject *entry_of, int *err)
+head_entry(PyObject *obj, PyObject *kinds, PyObject *entry_of, int *err,
+           Py_ssize_t *untracked)
 {
     *err = 0;
     PyObject *loaded = *(PyObject **)((char *)obj + off_loaded);
     PyObject *raw = *(PyObject **)((char *)obj + off_raw);
     const char *kind;
     Py_ssize_t len;
-    if (loaded != Py_False || raw == NULL || !PyBytes_CheckExact(raw) ||
-        !head_kind(PyBytes_AS_STRING(raw), PyBytes_GET_SIZE(raw),
+    if (loaded != Py_False || raw == NULL || !PyBytes_CheckExact(raw))
+        return NULL;
+    if (PyDict_GET_SIZE(obj) == 0) {
+        PyObject_GC_UnTrack(obj); /* no effect on one already off */
+        ++*untracked;
+    }
+    if (!head_kind(PyBytes_AS_STRING(raw), PyBytes_GET_SIZE(raw),
                    &kind, &len))
         return NULL;
     *err = 1;
@@ -186,7 +203,8 @@ add_to(PyObject *lst, Py_ssize_t i, Py_ssize_t n)
  * iterator is exhausted.  An exception of the iterator, of peek_kind or
  * of entry_of passes through, with bufs as they stood.  On every way out
  * counter[0] grows by the objects counted, counts[0] by those the head
- * scan settled and counts[1] by those handed to peek_kind. */
+ * scan settled, counts[1] by those handed to peek_kind and counts[2] by
+ * those taken off the collector's lists. */
 static PyObject *
 route(PyObject *self, PyObject *args)
 {
@@ -205,7 +223,7 @@ route(PyObject *self, PyObject *args)
         PyErr_SetString(PyExc_TypeError, "route() takes an iterator");
         return NULL;
     }
-    Py_ssize_t listed = 0, fast = 0, slow = 0;
+    Py_ssize_t listed = 0, fast = 0, slow = 0, untracked = 0;
     PyObject *full = NULL, *obj = NULL, *entry = NULL;
     int failed = 0;
     while (full == NULL) {
@@ -218,7 +236,7 @@ route(PyObject *self, PyObject *args)
         entry = NULL;
         if (Py_TYPE(obj) == raw_type) {
             int err;
-            entry = head_entry(obj, kinds, entry_of, &err);
+            entry = head_entry(obj, kinds, entry_of, &err, &untracked);
             if (err)
                 break;
         }
@@ -273,6 +291,7 @@ route(PyObject *self, PyObject *args)
     add_to(counter, 0, listed);
     add_to(counts, 0, fast);
     add_to(counts, 1, slow);
+    add_to(counts, 2, untracked);
     if (failed) {
         Py_XDECREF(full);
         return NULL;
@@ -282,6 +301,18 @@ route(PyObject *self, PyObject *args)
     return full;
 }
 
+/* track(obj): put an object that route() took off the collector's lists
+ * back on them.  Nothing to do for one that is on them (tracking it twice
+ * is an error) or whose type the collector does not know. */
+static PyObject *
+track(PyObject *self, PyObject *obj)
+{
+    (void)self;
+    if (PyObject_IS_GC(obj) && !PyObject_GC_IsTracked(obj))
+        PyObject_GC_Track(obj);
+    Py_RETURN_NONE;
+}
+
 static PyMethodDef methods[] = {
     {"bind", bind, METH_O,
      "Take the RawJSON class whose instances the head scan reads."},
@@ -289,6 +320,8 @@ static PyMethodDef methods[] = {
      "The kind in the head of a document's bytes, or None."},
     {"route", route, METH_VARARGS,
      "Route listed objects into per-group chunk buffers until one fills."},
+    {"track", track, METH_O,
+     "Put an object back on the cyclic collector's lists."},
     {NULL, NULL, 0, NULL},
 };
 

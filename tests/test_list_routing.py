@@ -3,8 +3,12 @@
 (ops/listroute.route_chunks_py).  Same objects, by identity, in the same
 order in the same chunks, the same constraint subset with each, the same
 ``counter[0]``, the same exception at the same object; and the head scan
-reads exactly what ``peek_kind``'s anchored regex reads."""
+reads exactly what ``peek_kind``'s anchored regex reads.  The one thing the
+native call does beside: it takes every unloaded, still empty ``RawJSON``
+of the exact class off the cyclic collector's lists and counts it
+(tests/test_rawjson_untracked.py has the mechanism by itself)."""
 
+import gc
 import random
 from types import SimpleNamespace
 
@@ -150,6 +154,12 @@ CASES = {
 }
 
 
+def _acyclic(o):
+    """What the native call may take off the collector's lists."""
+    return (type(o) is RawJSON and o._loaded is False
+            and type(o.raw) is bytes and dict.__len__(o) == 0)
+
+
 def _run(make, chunk_size, opts, use_native, monkeypatch):
     """One pass of ``_chunk_source`` over fresh objects; what it did, with
     objects named by their position in the listing."""
@@ -157,6 +167,7 @@ def _run(make, chunk_size, opts, use_native, monkeypatch):
         monkeypatch.setattr(native, "load_listroute", lambda: None)
     objs = make()
     pos = {id(o): i for i, o in enumerate(objs)}
+    acyclic = [_acyclic(o) for o in objs]  # as the lister hands them over
     lister = (lambda: iter(objs))
     if "raises" in opts:
         lister = _raising(lambda: objs, opts["raises"],
@@ -185,10 +196,17 @@ def _run(make, chunk_size, opts, use_native, monkeypatch):
     if opts.get("tee"):
         tee = ([pos[id(o)] for o in mgr._gen_buf], sorted(mgr._gen_ns))
     monkeypatch.undo()
+    listed = opts.get("pulled", opts.get("raises", len(objs)))
     return SimpleNamespace(
         chunks=chunks, counter=counter[0], error=error, yields=yields,
         tee=tee, fast=mgr.perf["list_fast"], slow=mgr.perf["list_slow"],
-        listed=opts.get("pulled", opts.get("raises", len(objs))))
+        untracked=mgr.perf["list_untracked"], listed=listed,
+        acyclic=sum(acyclic[:listed]),
+        # off the lists: pulled, and still as acyclic as it arrived (a
+        # peek_kind that had to parse loaded it, and so put it back)
+        off_lists=[i for i, o in enumerate(objs) if not gc.is_tracked(o)],
+        still_acyclic=[i for i, o in enumerate(objs[:listed])
+                       if acyclic[i] and _acyclic(o)])
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -204,10 +222,17 @@ def test_native_routing_is_the_per_object_loop(case, monkeypatch):
     # every object pulled off the lister is counted once, on either path
     assert (want.fast, want.slow) == (0, want.listed)
     assert got.fast + got.slow == got.listed
+    # the loop untracks nothing; the native call every pulled object that
+    # can hold no cycle, and nothing else
+    assert want.untracked == 0 and want.off_lists == []
+    native_ran = not opts.get("tee")
+    assert got.untracked == (got.acyclic if native_ran else 0)
+    assert got.off_lists == (got.still_acyclic if native_ran else [])
     if case == "non-utf8-kind":
         assert got.error[0] is UnicodeDecodeError
         # the one that raised was handed to peek_kind
         assert (got.fast, got.slow) == (13, 1) and got.counter == 13
+        assert got.untracked == 14
     if "raises" in opts:
         assert got.error == (RuntimeError, "lister died")
     if opts.get("tee"):
@@ -215,12 +240,17 @@ def test_native_routing_is_the_per_object_loop(case, monkeypatch):
         assert got.tee[0] and got.tee[1]
     elif case in ("head-apiversion-first", "head-kind-first",
                   "kind-no-template-matches", "two-groups-filling-together"):
-        assert got.slow == 0 and got.fast == got.listed
+        assert got.slow == 0 and got.fast == got.listed == got.untracked
     elif case in ("kind-after-nested-kinds", "escaped-kind", "plain-dict",
                   "whitespace-in-the-head", "rawjson-subclass"):
         assert got.fast == 0
+        # a head that settles nothing is as acyclic as one that does
+        assert got.untracked == (
+            0 if case in ("plain-dict", "rawjson-subclass") else got.listed)
+        if case == "escaped-kind":  # peek_kind parsed each: tracked again
+            assert got.off_lists == []
     elif case == "loaded-rawjson":
-        assert got.fast == got.slow == 30
+        assert got.fast == got.slow == got.untracked == 30
     elif got.listed:
         assert got.fast > 0 and got.slow > 0
     if case not in ("empty-lister", "lister-raises-at-once",
@@ -239,6 +269,7 @@ def test_the_fallback_is_the_loop_when_the_module_does_not_build(
         objs, lambda k: frozenset([k]), 4, counter, counts))
     assert [len(c) for _g, c in out] == [4, 2, 1, 1, 1, 1]
     assert counter == [10] and counts == [0, 10]
+    assert all(gc.is_tracked(o) for o in objs)
 
 
 def test_warm_pass_routes_with_the_audits_generator(monkeypatch):

@@ -439,7 +439,8 @@ def test_account_closes_and_counts_every_listed_object(toy, pipeline, raw):
     """With the native routing call on the calling thread the account
     still closes on the wall, and ``list_fast + list_slow`` is the
     listed objects of the pass: head-form RawJSON all fast, plain dicts
-    all through ``peek_kind``."""
+    all through ``peek_kind``; ``list_untracked`` is those the native call
+    took off the cyclic collector's lists, a 0 where it took none."""
     from gatekeeper_tpu.ops import native
     from gatekeeper_tpu.utils.rawjson import as_raw
 
@@ -467,6 +468,7 @@ def test_account_closes_and_counts_every_listed_object(toy, pipeline, raw):
     assert perf["list_fast"] + perf["list_slow"] == 40
     fast = 40 if raw and native.load_listroute() is not None else 0
     assert (perf["list_fast"], perf["list_slow"]) == (fast, 40 - fast)
+    assert perf["list_untracked"] == fast
     spans = tracer.traces()[0]["spans"]
     if pipeline == "on":
         account = (perf["list"] + perf["pipe_source_stall"]
@@ -483,9 +485,12 @@ def test_account_closes_and_counts_every_listed_object(toy, pipeline, raw):
     assert [s["attributes"].get("list_fast", 0)
             + s["attributes"].get("list_slow", 0)
             for s in listed[:3]] == [16, 32, 40]
-    # a second pass adds to both
+    assert [s["attributes"]["list_untracked"] for s in listed[:3]] == \
+        [16 * fast // 40, 32 * fast // 40, fast]
+    # a second pass adds to all three
     mgr.audit()
     assert mgr.perf["list_fast"] + mgr.perf["list_slow"] == 80
+    assert mgr.perf["list_untracked"] == 2 * fast
 
 
 # --- the renderer's counters in the account (audit/render_memo.py) ----------

@@ -10,7 +10,8 @@ Two gates over ``native/flattenmod.c``, ``native/flattenjsonmod.c`` and
   ``-fsanitize=address,undefined`` through the normal
   ``ops/native.py`` build (the flag set hashes into the output dir,
   so the sanitized build can never be satisfied by a stale plain
-  binary) and run the flatten and list-routing unit corpus under it in
+  binary) and run the flatten and list-routing unit corpus (the
+  router's untrack and ``track()`` cases with it) under it in
   a subprocess with libasan preloaded.  Memory errors or UB in the threaded
   kernel abort the run.
 
@@ -86,7 +87,8 @@ def asan_corpus_run(timeout_s: float = 600.0) -> tuple:
     cmd = [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
            os.path.join(REPO, "tests", "test_native_flatten_json.py"),
            os.path.join(REPO, "tests", "test_native_flatten.py"),
-           os.path.join(REPO, "tests", "test_list_routing.py")]
+           os.path.join(REPO, "tests", "test_list_routing.py"),
+           os.path.join(REPO, "tests", "test_rawjson_untracked.py")]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=timeout_s, cwd=REPO, env=env)
