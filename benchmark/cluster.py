@@ -36,6 +36,7 @@ import json
 import random
 
 SHARD = 32_768  # objects per shard; one audit chunk
+_LABELLED_KINDS = ("Pod", "Service", "Ingress", "Deployment")
 
 _REPO_OK = "openpolicyagent/"
 _REPOS_BAD = ("docker.io/rando/", "quay.io/other/")
@@ -91,6 +92,21 @@ class Cluster:
         self._ns_cum = list(itertools.accumulate(
             1.0 / (r + 1) ** float(ns["zipf_s"])
             for r in range(len(self.namespaces))))
+        self._listed = bool(ns.get("listed", False))
+        self._ns_labels = {key: self._rule(f"namespaces.labels.{key}", rule)
+                           for key, rule in ns.get("labels", {}).items()}
+        if self._ns_labels and not self._listed:
+            raise ValueError("cluster.namespaces.labels belong to the "
+                             "cluster's own Namespace objects: it needs "
+                             "cluster.namespaces.listed")
+        self._labels = {
+            kind: {key: self._rule(f"labels.{kind}.{key}", rule, drawn=True)
+                   for key, rule in rules.items()}
+            for kind, rules in spec.get("labels", {}).items()}
+        unlabelled = set(self._labels) - set(_LABELLED_KINDS)
+        if unlabelled:
+            raise ValueError(f"cluster.labels names {sorted(unlabelled)}; "
+                             f"it labels {_LABELLED_KINDS} only")
         pod = spec["pod"]
         self._cont_values = pod["containers"]["values"]
         self._cont_cum = list(itertools.accumulate(
@@ -115,6 +131,37 @@ class Cluster:
 
     def namespace(self, rng) -> str:
         return self._pick(rng, self.namespaces, self._ns_cum)
+
+    @classmethod
+    def _rule(cls, where: str, rule: dict, drawn: bool = False):
+        """A label rule of the configuration, checked, as a function of
+        (stream, namespace index) that gives the value or None for no
+        label.  ``{"cycle": c, "format": f}`` gives the namespace of index
+        k the value ``f.format(k % c)``; ``{"values", "weights", "absent"}``
+        is a weighted draw that leaves the label out with probability
+        ``absent`` (0 if not given).  ``drawn``: only the second will do,
+        an object has no index."""
+        keys = set(rule)
+        if keys == {"cycle", "format"} and not drawn \
+                and int(rule["cycle"]) > 0:
+            cycle, fmt = int(rule["cycle"]), str(rule["format"])
+            return lambda rng, k: fmt.format(k % cycle)
+        if {"values", "weights"} <= keys <= {"values", "weights", "absent"} \
+                and len(rule["values"]) == len(rule["weights"]) > 0 \
+                and min(rule["weights"]) > 0:
+            absent, values = float(rule.get("absent", 0.0)), rule["values"]
+            cum = list(itertools.accumulate(rule["weights"]))
+            return lambda rng, k: (None if rng.random() < absent
+                                   else cls._pick(rng, values, cum))
+        raise ValueError(f"cluster.{where}: no label rule in {rule}")
+
+    def _label(self, rng, kind: str, meta: dict) -> None:
+        """The configuration's labels of ``kind``, drawn from the object's
+        own stream into ``meta``; no draw where it names none."""
+        for key, rule in self._labels.get(kind, {}).items():
+            value = rule(rng, None)
+            if value is not None:
+                meta.setdefault("labels", {})[key] = value
 
     def _image_pool(self, rng) -> list:
         # a cluster runs a bounded set of images which Pods share, not one
@@ -255,6 +302,7 @@ class Cluster:
     def _pod(self, rng, i: int, ns: str) -> dict:
         meta: dict = {"name": f"pod-{i}", "namespace": ns,
                       "labels": {"app": f"app{rng.randrange(50)}"}}
+        self._label(rng, "Pod", meta)
         if rng.random() < self.dev["apparmor_set"]:
             meta["annotations"] = {
                 "container.apparmor.security.beta.kubernetes.io/c0":
@@ -274,6 +322,7 @@ class Cluster:
                 rng.choice(_EXTERNAL_IPS_BAD)
                 if rng.random() < d["external_ip_bad"] else "203.0.113.0"]
         meta: dict = {"name": f"svc-{i}", "namespace": ns}
+        self._label(rng, "Service", meta)
         if rng.random() >= d["no_owner_annotation"]:
             meta["annotations"] = {"a8r.io/owner":
                                    f"team-{rng.randrange(8)}"}
@@ -292,6 +341,7 @@ class Cluster:
             hosts.append("*.example.com")
         spec: dict = {"rules": [{"host": h} for h in hosts]}
         meta: dict = {"name": f"ing-{i}", "namespace": ns}
+        self._label(rng, "Ingress", meta)
         if rng.random() >= d["ingress_http"]:
             spec["tls"] = [{"hosts": hosts}]
             meta["annotations"] = {
@@ -304,8 +354,10 @@ class Cluster:
         replicas = (rng.choice((1, 60))
                     if rng.random() < self.dev["replicas_out_of_range"]
                     else rng.choice((3, 3, 5, 8, 12, 20)))
+        meta: dict = {"name": f"dep-{i}", "namespace": ns}
+        self._label(rng, "Deployment", meta)
         return {"apiVersion": "apps/v1", "kind": "Deployment",
-                "metadata": {"name": f"dep-{i}", "namespace": ns},
+                "metadata": meta,
                 "spec": {"replicas": replicas, "template": {"spec": {
                     "containers": [self._container(rng, "c0")]}}}}
 
@@ -319,6 +371,21 @@ class Cluster:
             labels["gatekeeper"] = "true"
         return {"apiVersion": "v1", "kind": "Namespace",
                 "metadata": {"name": f"ns-x{i}", "labels": labels}}
+
+    def _own_namespace(self, k: int) -> dict:
+        """The Namespace object of ``ns-k`` under ``namespaces.listed``:
+        ``owner`` and ``gatekeeper`` as a filler draws them, then the
+        configured labels, all from a stream keyed on the name, so that it
+        is one object for every seed, in the corpus and in the lookup."""
+        name = self.namespaces[k]
+        rng = random.Random(f"namespace:{name}")
+        obj = self._namespace(rng, 0, "")
+        obj["metadata"]["name"] = name
+        for key, rule in self._ns_labels.items():
+            value = rule(rng, k)
+            if value is not None:
+                obj["metadata"]["labels"][key] = value
+        return obj
 
     def _binding(self, rng, i: int, ns: str, kind="RoleBinding") -> dict:
         subject = {"kind": "User", "apiGroup": "rbac.authorization.k8s.io",
@@ -349,11 +416,24 @@ class Cluster:
                             else "vocabulary")
         # the first two Pods of the cluster are the widest there can be
         extremes = [] if shard else [_Extreme(False), _Extreme(True)]
+        # under namespaces.listed the shard's first Namespace objects are
+        # the cluster's own; the draws after them stay the fillers ns-x<i>
+        own = len(self.namespaces) if self._listed and not shard else 0
+        listed = 0
         lo = shard * SHARD
         for i in range(lo, min(self.n, lo + SHARD)):
             kind = self._pick(kind_rng, self._kinds, self._kind_cum)
             draw = extremes.pop() if extremes and kind == "Pod" else rng
-            yield self._makers[kind](draw, i, self.namespace(rng))
+            ns = self.namespace(rng)
+            if kind == "Namespace" and listed < own:
+                yield self._own_namespace(listed)
+                listed += 1
+            else:
+                yield self._makers[kind](draw, i, ns)
+        if listed < own:
+            raise ValueError(
+                f"cluster.namespaces.listed: shard 0 draws {listed} objects "
+                f"of kind Namespace, the cluster has {own} namespaces")
 
     def stream(self):
         """Objects of the cluster's kind mix without end, every field drawn
@@ -366,7 +446,11 @@ class Cluster:
 
     def namespace_objects(self) -> dict:
         """The Namespace object of every namespace the others live in, by
-        name (what the webhook's namespace lookup serves)."""
+        name (what the webhook's namespace lookup serves): under
+        ``namespaces.listed`` the very objects the corpus lists."""
+        if self._listed:
+            return {name: self._own_namespace(k)
+                    for k, name in enumerate(self.namespaces)}
         rng = random.Random(f"{self.seed}:namespaces")
         out = {}
         for name in self.namespaces:

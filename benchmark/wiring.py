@@ -140,17 +140,21 @@ class Program:
 
         a = self.config["audit"]
         if self.evaluator is None:
+            # what the configuration defines and nothing else: every lane
+            # option (flatten lane, collect, workers, pipeline, shard
+            # chunks, audit source) is the constructor's own default, so a
+            # PR that deletes one need not edit this file
+            # (tests/benchmark/test_wiring_defaults.py)
             self.evaluator = ShardedEvaluator(              # :883-889
                 self.tpu, make_mesh(self.chips),
-                violations_limit=a["violations_limit"], flatten_lane="auto",
-                metrics=self.metrics, collect="reduced", flatten_workers=0)
+                violations_limit=a["violations_limit"],
+                metrics=self.metrics)
         return AuditManager(                                # :1005-1029
             self.client, lister=lister,
             config=AuditConfig(
                 interval_s=60.0, violations_limit=a["violations_limit"],
-                chunk_size=a["chunk_size"], pipeline="auto",
-                pipeline_flatten_workers=0, shard_chunks=0,
-                audit_source="relist", exact_totals=a["exact_totals"]),
+                chunk_size=a["chunk_size"],
+                exact_totals=a["exact_totals"]),
             evaluator=self.evaluator, metrics=self.metrics)
 
     def build_serving(self, namespace_objects: dict):

@@ -19,10 +19,11 @@ if ROOT not in sys.path:
 
 from benchmark import manifest, readers  # noqa: E402
 
+# the cells the entries were written for; a later cell may list them too
 CELLS = ["full.audit-sweep", "psp.audit-sweep"]
 NEW = ["list.fast_share", "list.cpu_s_per_pass"]
-# PR 24's, whose own test of these entries pins them as the manifest's last
-# (tests/conftest.py says why that one is deselected)
+# PR 24's, held here too since their own test once pinned them as the
+# manifest's last and was deselected for it (it runs again since PR 31)
 PR24 = ["list.busy_s_per_pass", "audit_schedule.critical_occupancy",
         "audit_schedule.host_blocked_share", "pack_h2d.launch_s_per_pass",
         "fold_render.render_s_per_pass", "python_gc.full_span_s_per_pass",
@@ -67,7 +68,7 @@ def test_the_manifest_resolves_with_the_two_new_metrics():
 def test_an_entry_agrees_with_its_file_in_both_audit_cells(name):
     entry = {m["name"]: m for m in entries()}[name]
     assert entry["moves"] == "audit_pass_s"
-    assert entry["workloads"] == CELLS
+    assert set(CELLS) <= set(entry["workloads"])
     assert entry["layer"] == metric(name)["layer"]
     assert entry["unit"] == metric(name)["unit"]
     # and a cell loads it with its reader

@@ -19,6 +19,7 @@ if ROOT not in sys.path:
 from benchmark import manifest, readers  # noqa: E402
 
 NAME = "fold_render.memo_hit_share"
+# the cells the entry was written for; a later cell may list it too
 CELLS = ["full.audit-sweep", "psp.audit-sweep", "c500.audit-sweep"]
 
 
@@ -35,18 +36,20 @@ def read(name: str, manager: dict, passes: int = 2):
     return out[name]["value"] if name in out else None
 
 
-def test_the_entry_agrees_with_its_file_and_lists_the_three_audit_cells():
+def test_the_entry_agrees_with_its_file_and_lists_the_audit_cells():
     assert manifest.check() == []
     entries = manifest.read_json(manifest.MANIFEST)["per_layer"]
-    entry = {m["name"]: m for m in entries}[NAME]
+    entry = dict({m["name"]: m for m in entries}[NAME])
     spec = metric()
+    assert set(CELLS) <= set(entry.pop("workloads"))
     assert entry == {
         "name": NAME, "unit": spec["unit"], "better": "higher",
         "source": "program_counter", "layer": spec["layer"],
-        "moves": "audit_pass_s", "workloads": CELLS}
+        "moves": "audit_pass_s"}
     assert spec["layer"] == "fold_render" and spec["unit"] == "1"
-    # appended: nothing the benchmark had stands after it
-    assert entries[-1] is entry
+    # appended: everything the benchmark had then stands before it
+    names = [m["name"] for m in entries]
+    assert names.index(NAME) > names.index("pack_h2d.mask_bytes_per_object")
     for cell in CELLS:
         assert NAME in {p["name"] for p in manifest.Cell(cell).per_layer}
 
