@@ -225,6 +225,10 @@ class AuditManager:
         self.lister = lister
         self.config = config or AuditConfig()
         self.evaluator = evaluator
+        if evaluator is not None:
+            # the sweep's match masks find a namespaced object's Namespace
+            # where this client's interpreter does (target.Matcher.match)
+            evaluator.namespace_of = client.target.cache.get
         self.status_writer = status_writer
         self.export_system = export_system
         self.event_sink = event_sink
@@ -252,6 +256,9 @@ class AuditManager:
         self._gen_ns: dict = {}
         self._gen_kinds: set = set()
         self._gen_verdicts: dict = {}
+        # snapshot mode: the target's NamespaceCache version the kept
+        # verdicts were matched under (_follow_namespace_labels)
+        self._ns_labels_seen: Optional[int] = None
         # snapshot spill writer (snapshot/persist.py): a clean resync
         # requests a background spill, run_forever's exit flushes a
         # final one (the drain guarantee); None = persistence off
@@ -565,7 +572,23 @@ class AuditManager:
                       rows=snap.live_count(),
                       generation=snap.generation)
         snap.pump()
+        self._follow_namespace_labels()
         return rebuilt
+
+    def _follow_namespace_labels(self) -> None:
+        """A verdict under a ``namespaceSelector`` follows the labels of
+        the row's Namespace as the target's cache holds them (what
+        ``Client.add_data`` synced), and no watch event on the row says
+        that they moved.  When they have since this manager last looked,
+        the groups that carry such a matcher are evaluated whole; the
+        others, and every tick between two such changes, stay O(churn)."""
+        from gatekeeper_tpu.ir.masks import reads_namespace_labels
+
+        version = self.client.target.cache.version
+        if version != self._ns_labels_seen:
+            self._ns_labels_seen = version
+            self.snapshot.mark_groups_dirty(
+                lambda store: reads_namespace_labels(store.cons))
 
     def _audit_snapshot_impl(self, full: bool) -> AuditRun:
         t0 = time.time()

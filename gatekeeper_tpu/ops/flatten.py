@@ -616,6 +616,10 @@ class ColumnBatch:
     # uint8 [N] metadata.generateName presence (native JSON path only;
     # lets mask building skip materializing RawJSON objects)
     has_generate_name: np.ndarray = None
+    # the labels the constraints' selectors read (native JSON path only,
+    # for the same reason; host-side, never shipped): () -> the column of
+    # metadata.labels itself, (key,) -> that label's, each a ScalarColumn
+    labels: dict = None
 
     def arrays(self) -> dict[str, np.ndarray]:
         """Stable name -> array mapping (the device-transfer payload)."""
@@ -1102,7 +1106,8 @@ class Flattener:
     def __init__(self, schema: Schema, vocab: Optional[Vocab] = None,
                  use_native: bool = True, bucket: int = 8,
                  width_targets: Optional[dict] = None,
-                 lane: str = "auto", workers: int = 0):
+                 lane: str = "auto", workers: int = 0,
+                 label_keys: Sequence[str] = ()):
         # prefix-axis dedup: extraction runs over the exec schema; the
         # requested (orig) specs are aliased onto the exec columns after
         # flatten (same numpy arrays — identity the wire packer dedups on)
@@ -1150,6 +1155,9 @@ class Flattener:
         # for metrics/span attribution; 'raw' batches that fell back to
         # the dict lane on a parse reject report the lane they landed on
         self.lane_used: str = ""
+        # label keys the raw lane columnizes into ``ColumnBatch.labels``
+        # (ir/masks.py:selector_label_keys of the chunk's constraints)
+        self.label_keys = tuple(label_keys)
 
     def _apply_alias(self, batch: ColumnBatch) -> ColumnBatch:
         for orig, new in self.alias.items():
@@ -1467,6 +1475,12 @@ class Flattener:
          batch.has_generate_name) = out["identity"]
         for spec, (kind, num, sid) in zip(schema.scalars, out["scalars"]):
             batch.scalars[spec] = ScalarColumn(kind, num, sid)
+        if self.label_keys:
+            at = {p: i for i, p in enumerate(
+                self._scalar_paths(schema))}
+            batch.labels = {
+                path[2:]: ScalarColumn(*out["scalars"][at[path]])
+                for path in self._label_paths()}
         for axis, cnt in zip(axes, out["axes"]):
             batch.axis_counts[axis] = cnt
         for spec, (kind, num, sid) in zip(schema.raggeds, out["raggeds"]):
@@ -1505,13 +1519,26 @@ class Flattener:
                                   + _time.perf_counter() - _t0)
         return batch
 
-    @staticmethod
-    def _columnizer_specs(schema, axes, axis_index) -> tuple:
+    def _label_paths(self) -> list:
+        return [("metadata", "labels")] + [
+            ("metadata", "labels", k) for k in self.label_keys]
+
+    def _scalar_paths(self, schema) -> list:
+        """The scalar paths the columnizer extracts: the schema's, then
+        the label paths the schema does not already hold (one path, one
+        column: the kernel's path trie keeps a single column a node)."""
+        paths = [tuple(s.path) for s in schema.scalars]
+        if self.label_keys:
+            have = set(paths)
+            paths += [p for p in self._label_paths() if p not in have]
+        return paths
+
+    def _columnizer_specs(self, schema, axes, axis_index) -> tuple:
         """The plain-tuple spec bundle ``flatten_json_batch`` consumes —
         shared by the in-process call and the worker-pool jobs (the
         tuples pickle cheaply; workers never see Schema objects)."""
         return (
-            [tuple(s.path) for s in schema.scalars],
+            self._scalar_paths(schema),
             [a.segments for a in axes],
             [(axis_index[r.axis], tuple(r.subpath))
              for r in schema.raggeds],
@@ -1640,7 +1667,7 @@ class Flattener:
         ref = Flattener(self.orig_schema, ref_vocab,
                         use_native=self.use_native, bucket=self.bucket,
                         width_targets=self.width_targets,
-                        lane="differential")
+                        lane="differential", label_keys=self.label_keys)
         ref.nthreads = max(1, len(flatten_worker_spans(len(objects),
                                                        self.workers)))
         bref = ref.flatten(objects, pad_n=pad_n, reviews=reviews)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from gatekeeper_tpu.ir.masks import selector_label_keys
 from gatekeeper_tpu.ir.program import (build_param_table, col_key,
                                         needed_fields, pack_batch_cols,
                                         slim_cols, vocab_tables)
@@ -767,6 +768,12 @@ class ShardedEvaluator:
         self.driver = driver
         self.mesh = mesh
         self.violations_limit = violations_limit
+        # name -> Namespace object or None: where the match masks find a
+        # swept object's Namespace for ``namespaceSelector``.  The audit
+        # manager sets it to its target's NamespaceCache (what
+        # ``Client.add_data`` fills and the interpreter's matcher falls
+        # back to)
+        self.namespace_of = None
         # --flatten-lane: how sweep chunks columnize (ops/flatten.py
         # FLATTEN_LANES) — auto takes the raw-bytes threaded C lane when
         # the lister hands over bytes and the native module built
@@ -962,11 +969,12 @@ class ShardedEvaluator:
         return (self.mesh.size == 1
                 and self.mesh.devices.flat[0].platform == "tpu")
 
-    def _flattener(self, schema: Schema) -> Flattener:
+    def _flattener(self, schema: Schema, label_keys=()) -> Flattener:
         return Flattener(schema, self.driver.vocab, bucket=self._bucket,
                          width_targets=self._width_targets or None,
                          lane=self.flatten_lane,
-                         workers=self.flatten_workers)
+                         workers=self.flatten_workers,
+                         label_keys=label_keys)
 
     def _needs_union(self, kinds, alias: Optional[dict] = None,
                      programs=None) -> dict:
@@ -1235,7 +1243,8 @@ class ShardedEvaluator:
             fl = Flattener(schema, self.driver.vocab,
                            bucket=self._bucket,
                            lane=self.flatten_lane,
-                           workers=self.flatten_workers)
+                           workers=self.flatten_workers,
+                           label_keys=selector_label_keys(cons_g))
             st = (cons_g, fl, self._needs_union(lowered, fl.alias))
             state[g] = st
             return st
@@ -1445,7 +1454,9 @@ class ShardedEvaluator:
         from gatekeeper_tpu.observability import tracing
 
         t0 = time.perf_counter()
-        fl = self._flattener(schema)
+        # the labels the group's selectors read ride beside the identity
+        # columns, so the masks load no object to match it
+        fl = self._flattener(schema, selector_label_keys(constraints))
         with tracing.span("ops.flatten.columnize", n=n,
                           lane=self.flatten_lane) as sp:
             batch = fl.flatten(objects, pad_n=pad_n)
@@ -1618,8 +1629,9 @@ class ShardedEvaluator:
         tables = []
         offsets = {}
         c_off = 0
-        # constraint rows the table path answered / the per-object predicate
-        mask_counts = {"rows_vectorized": 0, "rows_predicate": 0}
+        # constraint rows the tables answered / the per-object predicate,
+        # and what the selector tables were evaluated on
+        mask_counts: dict = {}
         with self._timed("masks",
                          tracing.span("device.sweep_dispatch.masks")):
             for kind in kinds:
@@ -1633,20 +1645,28 @@ class ShardedEvaluator:
                 c_off += len(cons)
             # one call for the group: the chunk's columns are coded once
             # for all its constraints
-            mask_all = masks_mod.constraint_masks(
-                [con for kind in kinds for con in by_kind[kind]],
-                batch, self.driver.vocab, objects,
-                sources=([flat.source] * len(objects)
-                         if flat.source else None),
-                any_generate_name=any_gen, counts=mask_counts,
-            )
+            try:
+                mask_all = masks_mod.constraint_masks(
+                    [con for kind in kinds for con in by_kind[kind]],
+                    batch, self.driver.vocab, objects,
+                    sources=([flat.source] * len(objects)
+                             if flat.source else None),
+                    any_generate_name=any_gen, counts=mask_counts,
+                    namespace_of=self.namespace_of,
+                )
+            finally:
+                # written on every dispatch, a 0 too, and where the
+                # oracle raised (a Namespace that was never synced)
+                for key, counted in (("mask_rows_fast", "rows_vectorized"),
+                                     ("mask_rows_slow", "rows_predicate"),
+                                     ("mask_rows_selector", "rows_selector"),
+                                     ("mask_ns_missing", "ns_missing"),
+                                     ("masks_selector", "selector_s")):
+                    self._perf_add(key, mask_counts.get(counted, 0))
             mask_rows = [mask_all[lo:hi] for lo, hi in offsets.values()]
             tracing.set_attribute("constraints", c_off)
-            for key, n_rows in mask_counts.items():
-                tracing.set_attribute(key, n_rows)
-        # written on every dispatch, a 0 too
-        self._perf_add("mask_rows_fast", mask_counts["rows_vectorized"])
-        self._perf_add("mask_rows_slow", mask_counts["rows_predicate"])
+            for key in ("rows_vectorized", "rows_predicate"):
+                tracing.set_attribute(key, mask_counts[key])
         from gatekeeper_tpu.observability import costattr
 
         complete = bool(return_bits)

@@ -29,8 +29,14 @@ pad slots gather row 0 but carry a False mask column (exactly the
 False pad masks of a host chunk), and masks are computed per
 (constraint, object) by the same ``constraint_masks`` the dispatch
 path runs — per-object pure, so patch-time masks equal chunk-time
-masks.  ``tests/test_device_residency.py`` pins verdict bit-identity
-across clean, dirty-sliver and post-evict ticks.
+masks.  That holds for every matcher but ``namespaceSelector``, which
+reads the labels of another object (the row's Namespace, as the
+target's NamespaceCache holds it at the time of the pass): a group
+with one declines the lane, with the reason logged once, and its host
+chunks compute their masks anew every pass.
+``tests/test_device_residency.py`` pins verdict bit-identity across
+clean, dirty-sliver and post-evict ticks, and that a relabelled
+Namespace moves the verdicts of its objects.
 
 Degradation: the built-in ``device_residency_evict`` action
 (resilience/overload.py) demotes every resident group back to host
@@ -122,9 +128,9 @@ class DeviceResidency:
     per group per tick: it syncs the device mirror (full upload on
     layout change, scatter-patch for dirty rows, nothing when clean)
     and returns the :class:`ResidentGroup`, or None when the lane is
-    unavailable (no device, multi-chip mesh, extdata joins, eviction
-    degradation active) — callers then take the host-column path
-    unchanged."""
+    unavailable (no device, multi-chip mesh, extdata joins, a
+    ``namespaceSelector``, eviction degradation active) — callers then
+    take the host-column path unchanged."""
 
     def __init__(self, evaluator, metrics=None, mode: str = "auto",
                  cluster: str = ""):
@@ -226,16 +232,9 @@ class DeviceResidency:
         time (here) or chunk time (the host reference lane)."""
         from gatekeeper_tpu.ir import masks as masks_mod
 
-        if batch.has_generate_name is not None:
-            any_gen = bool(
-                batch.has_generate_name[: len(objects)].any())
-        else:
-            any_gen = any("generateName" in (o.get("metadata") or {})
-                          for o in objects)
         return masks_mod.constraint_masks(
             [con for kind in rg.kinds for con in rg.by_kind[kind]], batch,
-            self.evaluator.driver.vocab, objects,
-            any_generate_name=any_gen)[:, : len(objects)]
+            self.evaluator.driver.vocab, objects)[:, : len(objects)]
 
     def _pack(self, store, positions, pad_n: int, rg: ResidentGroup):
         """(bufs, layout, batch, objects) for a row set, under the
@@ -333,6 +332,16 @@ class DeviceResidency:
         if not self.available():
             return None
         if store.batch is None or not store.lowered:
+            return None
+        from gatekeeper_tpu.ir.masks import reads_namespace_labels
+
+        if reads_namespace_labels(store.cons):
+            # a resident row's mask is computed when the row changes; this
+            # matcher's answer changes with the Namespace's labels, which
+            # are another row's
+            self._log_fallback("namespaceSelector (the mask follows the "
+                               "Namespace's labels; group keeps host "
+                               "columns)")
             return None
         ev = self.evaluator
         progs = ev.driver._programs

@@ -1107,6 +1107,16 @@ class ClusterSnapshot:
         with self.lock:
             self._dirty.difference_update(gids)
 
+    def mark_groups_dirty(self, wanted) -> None:
+        """Put every live row of the routed groups that ``wanted(store)``
+        picks back into the dirty set: what their verdicts were computed
+        from has changed outside their rows."""
+        with self.lock:
+            for store in self.routed_stores():
+                if wanted(store):
+                    self._dirty.update(store.gids[p]
+                                       for p in store.live_positions())
+
     def dirty_count(self) -> int:
         return len(self._dirty)
 

@@ -40,23 +40,41 @@ class NamespaceCache:
 
     def __init__(self):
         self._namespaces: dict[str, dict] = {}
+        # moves whenever what a namespaceSelector would read may have: a
+        # Namespace that comes, goes, or comes again with other labels (or
+        # as the very object the cache holds, which may have been edited
+        # in place).
+        # Who keeps an answer that was matched on these labels (the
+        # snapshot audit's verdicts of clean rows) compares it
+        self.version = 0
 
     def add(self, obj: dict) -> None:
         group, _, kind = gvk_of(obj)
         if kind == "Namespace" and group == "":
-            name = (obj.get("metadata") or {}).get("name", "")
+            meta = obj.get("metadata") or {}
+            name = meta.get("name", "")
             if name:
+                old = self._namespaces.get(name)
+                unchanged = old is not None and old is not obj and (
+                    old.get("metadata") or {}).get("labels") \
+                    == meta.get("labels")
+                if not unchanged:
+                    self.version += 1
                 self._namespaces[name] = obj
 
     def remove(self, obj: dict) -> None:
         group, _, kind = gvk_of(obj)
         if kind == "Namespace" and group == "":
-            self._namespaces.pop((obj.get("metadata") or {}).get("name", ""), None)
+            name = (obj.get("metadata") or {}).get("name", "")
+            if self._namespaces.pop(name, None) is not None:
+                self.version += 1
 
     def get(self, name: str) -> Optional[dict]:
         return self._namespaces.get(name)
 
     def wipe(self) -> None:
+        if self._namespaces:
+            self.version += 1
         self._namespaces.clear()
 
 

@@ -29,27 +29,3 @@ REFERENCE = "/root/reference"
 @pytest.fixture(scope="session")
 def reference_dir():
     return REFERENCE
-
-
-# tests/benchmark/test_pass_accounting_metrics.py pins PR 24's seven
-# per_layer entries as the LAST seven of BENCHMARK.json, so it fails the
-# moment a later PR appends one, and a PR may not edit a file the
-# benchmark already has.  Its other assertions are repeated, without that
-# pin, in tests/benchmark/test_list_routing_metrics.py; a `benchmark` PR
-# should relax the line and drop this.  tests/benchmark/
-# test_render_memo_metric.py pins PR 28's entry as the last one in the
-# same way (`entries[-1] is entry`); tests/benchmark/
-# test_gc_untracked_metric.py repeats its other assertions.
-_STALE_PINS = {
-    "tests/benchmark/test_pass_accounting_metrics.py::"
-    "test_the_manifest_lists_the_new_metrics_in_both_audit_cells",
-    "tests/benchmark/test_render_memo_metric.py::"
-    "test_the_entry_agrees_with_its_file_and_lists_the_three_audit_cells",
-}
-
-
-def pytest_collection_modifyitems(config, items):
-    stale = [i for i in items if i.nodeid in _STALE_PINS]
-    if stale:
-        items[:] = [i for i in items if i.nodeid not in _STALE_PINS]
-        config.hook.pytest_deselected(items=stale)

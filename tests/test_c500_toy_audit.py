@@ -175,10 +175,10 @@ def test_reduced_audit_is_the_interpreters(world, monkeypatch):
         assert a["rows_predicate"] == 0
 
 
-def test_a_selector_constraint_is_counted_slow_and_stays_exact(world):
-    """One labelSelector constraint joins the set: its row goes through
-    the per-object predicate in every chunk of its group, and the verdicts
-    stay the interpreter's."""
+def test_a_selector_constraint_is_counted_and_stays_exact(world):
+    """One labelSelector constraint joins the set: its row is answered
+    from the selector table (until PR 32 by the per-object predicate) in
+    every chunk of its group, and the verdicts stay the interpreter's."""
     from gatekeeper_tpu.apis.constraints import Constraint
 
     doc = json.loads(json.dumps(
@@ -194,8 +194,9 @@ def test_a_selector_constraint_is_counted_slow_and_stays_exact(world):
                                   violations_limit=LIMIT, collect="reduced")
     swept = ev.sweep(cons, services, return_bits=True)
     kcons, _idx, _valid, _counts, hits = swept["K8sBlockNodePort"]
-    assert ev.perf["mask_rows_slow"] == 1
-    assert ev.perf["mask_rows_fast"] == len(cons) - 1
+    assert ev.perf["mask_rows_slow"] == 0
+    assert ev.perf["mask_rows_fast"] == len(cons)
+    assert ev.perf["mask_rows_selector"] == 1
     ci = [c.name for c in kcons].index("selected-block-node-port")
     got = set(sharded.violation_rows(hits, ci, len(services)).tolist())
     want = {i for i, s in enumerate(services)

@@ -11,8 +11,10 @@ host-column reference manager across
    demotes to host columns mid-flight, release re-promotes lazily);
 
 plus the zero-H2D pin — a warm clean-rows tick reports
-``tick_h2d_bytes == 0`` — the mask-mirror differential, and the
-eviction/generation seams.
+``tick_h2d_bytes == 0`` — the mask-mirror differential, the
+eviction/generation seams, and the one matcher whose answer is not the
+row's own: under a ``namespaceSelector`` the lane declines, so a Namespace
+relabelled between ticks moves the verdicts of its objects.
 
 Wall-budget note: one module corpus (6-template slice, 100 objects)
 behind a module-scoped compile cache dir, same shape as
@@ -246,6 +248,85 @@ def test_residency_auto_mode_declines_on_cpu_host(corpus):
         assert residency.upload_count == 0
     else:  # accelerator host: auto promotes
         assert residency.available()
+
+
+def _selector_world():
+    """One template, two rows that select by the Namespace's ``tenant``
+    label, two Namespaces with a Pod each; the Namespaces synced."""
+    tpu = TpuDriver(cel_driver=CELDriver())
+    client = Client(target=K8sValidationTarget(), drivers=[tpu],
+                    enforcement_points=[AUDIT_EP])
+    client.add_template(load_yaml_file(os.path.join(
+        library_dir(), "general", "requiredlabels", "template.yaml"))[0])
+    for tenant in ("a", "b"):
+        client.add_constraint({
+            "apiVersion": "constraints.gatekeeper.sh/v1beta1",
+            "kind": "K8sRequiredLabels",
+            "metadata": {"name": f"owner-{tenant}"},
+            "spec": {"match": {
+                "kinds": [{"apiGroups": [""], "kinds": ["Pod"]}],
+                "namespaceSelector": {"matchLabels": {"tenant": tenant}}},
+                "parameters": {"labels": [{"key": "owner"}]}}})
+    cluster = FakeCluster()
+    for ns, tenant in (("ns-1", "a"), ("ns-2", "b")):
+        ns_obj = {"apiVersion": "v1", "kind": "Namespace",
+                  "metadata": {"name": ns, "labels": {"tenant": tenant}}}
+        client.add_data(ns_obj)
+        cluster.apply(ns_obj)
+        cluster.apply({"apiVersion": "v1", "kind": "Pod",
+                       "metadata": {"name": f"pod-{ns}", "namespace": ns},
+                       "spec": {"containers": []}})
+    return client, tpu, cluster
+
+
+def _violators(run) -> dict:
+    return {name: sorted(v.name for v in vs)
+            for (_kind, name), vs in run.kept.items()}
+
+
+@pytest.mark.parametrize("lane", ["resident", "host"])
+def test_a_relabelled_namespace_moves_its_objects_verdicts(lane):
+    """A row's mask under a ``namespaceSelector`` follows the labels of
+    another row, so a mirror patched only where rows change would go
+    stale: the resident lane declines such a group (nothing is uploaded,
+    the reason is logged) and both lanes answer with the Namespace labels
+    synced before the tick."""
+    client, tpu, cluster = _selector_world()
+    ev = ShardedEvaluator(tpu, make_mesh(1), violations_limit=20)
+    residency = (DeviceResidency(ev, mode="on") if lane == "resident"
+                 else None)
+    snap = ClusterSnapshot(ev, SnapshotConfig())
+    mgr = _snap_manager(client, ev, lambda: iter(cluster.list()), snap,
+                        residency=residency)
+    ing = WatchIngester(snap, cluster, gvks_of(cluster.list())).start()
+    try:
+        assert _violators(mgr.audit()) == {"owner-a": ["pod-ns-1"],
+                                           "owner-b": ["pod-ns-2"]}
+        swept = mgr.perf["snapshot_rows_evaluated"]
+        assert _violators(mgr.audit_tick()) == {"owner-a": ["pod-ns-1"],
+                                                "owner-b": ["pod-ns-2"]}
+        assert mgr.perf["snapshot_rows_evaluated"] == swept  # O(churn)
+        moved = {"apiVersion": "v1", "kind": "Namespace",
+                 "metadata": {"name": "ns-2", "labels": {"tenant": "a"}}}
+        client.add_data(moved)
+        cluster.apply(moved)
+        ing.pump()
+        # only the Namespace's own row is dirty; its Pod's verdict moves
+        assert _violators(mgr.audit_tick()) == {
+            "owner-a": ["pod-ns-1", "pod-ns-2"], "owner-b": []}
+        assert ev.perf["mask_ns_missing"] == 0
+        # synced again with the labels it has: nothing to follow
+        swept = mgr.perf["snapshot_rows_evaluated"]
+        client.add_data(copy.deepcopy(moved))
+        assert _violators(mgr.audit_tick()) == {
+            "owner-a": ["pod-ns-1", "pod-ns-2"], "owner-b": []}
+        assert mgr.perf["snapshot_rows_evaluated"] == swept
+        if residency is not None:
+            assert residency.upload_count == 0 and not residency._groups
+            assert any("namespaceSelector" in reason
+                       for reason in residency._logged_reasons)
+    finally:
+        ing.stop()
 
 
 def test_residency_off_mode_and_bad_mode(corpus):

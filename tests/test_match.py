@@ -118,3 +118,44 @@ def test_wildcard_globs():
     assert wildcard.matches("*sys*", "kube-system")
     assert not wildcard.matches("kube", "kube-system")
     assert not wildcard.matches_generate_name("*-system", "kube-")
+
+
+# --- NamespaceCache.version: what a kept namespaceSelector answer compares ----
+
+def _ns(name, labels=None, **meta):
+    return {"apiVersion": "v1", "kind": "Namespace",
+            "metadata": {"name": name, "labels": labels, **meta}}
+
+
+@pytest.mark.parametrize("step,moves", [
+    (lambda c: c.add(_ns("b", {"tenant": "t1"})), True),   # comes
+    (lambda c: c.add(_ns("a", {"tenant": "t0"})), False),  # the same again
+    (lambda c: c.add(_ns("a", {"tenant": "t0"},
+                         annotations={"x": "y"})), False),
+    (lambda c: c.add(_ns("a", {"tenant": "t9"})), True),   # other labels
+    (lambda c: c.add(_ns("a", None)), True),               # labels gone
+    (lambda c: c.remove(_ns("a")), True),                  # goes
+    (lambda c: c.remove(_ns("never-there")), False),
+    (lambda c: c.wipe(), True),
+    (lambda c: c.add({"apiVersion": "v1", "kind": "Pod",
+                      "metadata": {"name": "a"}}), False),
+])
+def test_namespace_cache_version_moves_with_what_a_selector_reads(step,
+                                                                  moves):
+    from gatekeeper_tpu.target.target import NamespaceCache
+
+    cache = NamespaceCache()
+    cache.add(_ns("a", {"tenant": "t0"}))
+    before = cache.version
+    step(cache)
+    assert (cache.version != before) == moves
+    if not moves:
+        assert cache.get("a")["metadata"]["labels"] == {"tenant": "t0"}
+
+
+def test_an_empty_namespace_cache_wiped_stays_where_it_is():
+    from gatekeeper_tpu.target.target import NamespaceCache
+
+    cache = NamespaceCache()
+    cache.wipe()
+    assert cache.version == 0
