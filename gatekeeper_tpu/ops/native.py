@@ -94,6 +94,11 @@ def load_listroute() -> Optional[object]:
     return _mods.get("gtpu_listroute")
 
 
+def load_wirepack() -> Optional[object]:
+    """The wire pack of a sweep chunk (native/wirepackmod.c)."""
+    return _load_named("gtpu_wirepack", "wirepackmod.c")
+
+
 def _build_flags() -> list:
     """The full compiler invocation prefix (compiler + every flag).
     ``GTPU_NATIVE_CFLAGS`` appends extra flags (sanitizer builds, the

@@ -35,6 +35,7 @@ from gatekeeper_tpu.ir.masks import selector_label_keys
 from gatekeeper_tpu.ir.program import (build_param_table, col_key,
                                         needed_fields, pack_batch_cols,
                                         slim_cols, vocab_tables)
+from gatekeeper_tpu.ops import native
 from gatekeeper_tpu.ops.flatten import Flattener, Schema, Vocab
 
 
@@ -154,8 +155,24 @@ def _wire_dtype(dt: str, mn: float, mx: float) -> tuple:
     return dt, 0
 
 
-def pack_transfer_cols(cols: dict, pad_n: int,
-                       stats: Optional[dict] = None) -> tuple:
+def _transfer_columns(cols: dict):
+    """The per-object columns in wire order, as ``(key, sub, array,
+    alias)``: table columns (fn:/st:/inv:/ext:) left out, and ``alias``
+    the ``(key, sub)`` that already ships this very numpy array (prefix-
+    axis dedup, ops/flatten.dedup_schema: ship once, alias on device)."""
+    seen: dict = {}  # id(array) -> (key, sub)
+    for key in sorted(k for k in cols
+                      if not k.startswith(("fn:", "st:", "inv:", "ext:"))):
+        val = cols[key]
+        items = sorted(val.items()) if isinstance(val, dict) \
+            else [(None, val)]
+        for sub, a in items:
+            first = seen.setdefault(id(a), (key, sub))
+            yield key, sub, a, first if first != (key, sub) else None
+
+
+def pack_transfer_cols_py(cols: dict, pad_n: int,
+                          stats: Optional[dict] = None) -> tuple:
     """Pack every per-object column into ONE [pad_n, W] buffer per dtype.
 
     Every transfer command carries a fixed cost on any host<->device
@@ -193,95 +210,193 @@ def pack_transfer_cols(cols: dict, pad_n: int,
     :func:`unpack_transfer_cols` inside the jitted sweep; store_dtype
     "const" marks an elided column whose value rides in the last slot.
     Table columns (fn:/st:/inv: — shared, device-cached) are excluded.
+
+    This is the numpy form: several passes a column, and what
+    :func:`pack_transfer_cols` runs for a drifted chunk, without stats,
+    and where ``native/wirepackmod.c`` does not build; the tests hold
+    the native pass to its buffers byte for byte.
     """
     parts: dict = {}
     widths: dict = {}
     layout: list = []
-    seen: dict = {}  # id(array) -> (key, sub): identity alias dedup
-    for key in sorted(k for k in cols
-                      if not k.startswith(("fn:", "st:", "inv:", "ext:"))):
-        val = cols[key]
-        items = sorted(val.items()) if isinstance(val, dict) \
-            else [(None, val)]
-        for sub, a in items:
-            ref = seen.get(id(a))
-            if ref is not None:
-                # same numpy array under two keys (prefix-axis dedup,
-                # ops/flatten.dedup_schema): ship once, alias on device
-                layout.append((key, sub, "alias", 0, (), 0, a.dtype.str,
-                               ref))
+    for key, sub, a, ref in _transfer_columns(cols):
+        if ref is not None:
+            layout.append((key, sub, "alias", 0, (), 0, a.dtype.str, ref))
+            continue
+        a = np.ascontiguousarray(a)
+        dt = a.dtype.str
+        tail = a.shape[1:]
+        st = stats.get((key, sub)) if stats is not None else None
+        dict_vals = None
+        narrowable = dt in ("<i4", "<i8", "|i1") or (
+            dt == "<f4" and st is not None and len(st) > 4 and st[4])
+        if st is not None and (st[2] is not None or narrowable) \
+                and a.size:
+            amn = a.min().item()
+            amx = a.max().item()
+            if st[2] is not None and amn == amx == st[2]:
+                # corpus-constant and this chunk agrees: elide
+                layout.append((key, sub, "const", 0, tail, 0, dt,
+                               st[2]))
                 continue
-            seen[id(a)] = (key, sub)
-            a = np.ascontiguousarray(a)
-            dt = a.dtype.str
-            tail = a.shape[1:]
-            st = stats.get((key, sub)) if stats is not None else None
-            dict_vals = None
-            narrowable = dt in ("<i4", "<i8", "|i1") or (
-                dt == "<f4" and st is not None and len(st) > 4 and st[4])
-            if st is not None and (st[2] is not None or narrowable) \
-                    and a.size:
-                amn = a.min().item()
-                amx = a.max().item()
-                if st[2] is not None and amn == amx == st[2]:
-                    # corpus-constant and this chunk agrees: elide
-                    layout.append((key, sub, "const", 0, tail, 0, dt,
-                                   st[2]))
-                    continue
-                eff_mn = min(st[0], amn)
-                eff_mx = max(st[1], amx)
-                if not narrowable:
-                    wdt, bias = dt, 0
-                elif dt == "<f4":
-                    # integral-float column (ports): integer wire dtype.
-                    # The chunk must re-verify integrality (a drifted
-                    # non-integral chunk would otherwise truncate —
-                    # range drift falls back, value drift must too) and
-                    # a no-fit range keeps the float dtype (falling
-                    # through to "<i4" would store floats uncast in the
-                    # int parts bucket).
-                    wdt, bias = _wire_dtype("<i4", eff_mn, eff_mx)
-                    if wdt == "<i4" or not bool(np.all(a == np.trunc(a))):
-                        wdt, bias = dt, 0
-                else:
-                    wdt, bias = _wire_dtype(dt, eff_mn, eff_mx)
-                dct = st[3] if len(st) > 3 else None
-                if dct is not None and wdt not in ("|u1", "|n1"):
-                    # u1 dictionary remap: wide-range low-cardinality
-                    # column (e.g. label-key sids) stores dictionary
-                    # indices; the sorted dictionary rides the static
-                    # layout and is gathered from a baked constant on
-                    # device.  Chunk values outside the corpus
-                    # dictionary (cluster drift) fall back to the plain
-                    # narrowed dtype — one retrace, never wrong results.
-                    dv = np.array(sorted(dct), np.int64)
-                    idx = np.searchsorted(dv, a.ravel())
-                    idx_c = np.minimum(idx, len(dv) - 1)
-                    if bool(np.all(dv[idx_c] == a.ravel())):
-                        a = idx_c.astype(np.uint8).reshape(a.shape)
-                        wdt, bias = "|u1", 0
-                        dict_vals = tuple(int(x) for x in dv)
-            else:
+            eff_mn = min(st[0], amn)
+            eff_mx = max(st[1], amx)
+            if not narrowable:
                 wdt, bias = dt, 0
-            w = int(np.prod(tail, dtype=np.int64)) if a.ndim > 1 else 1
-            if wdt == "|n1" and w % 2:
-                wdt = "|u1"  # nibble pairs need an even element count
-            if wdt == "|n1":
-                b = (a + bias).astype(np.uint8).reshape(pad_n, w)
-                a = b[:, 0::2] | (b[:, 1::2] << 4)
-                store_w = w // 2
-            elif bias:
-                a = (a + bias).astype(np.dtype(wdt))
-                store_w = w
+            elif dt == "<f4":
+                # integral-float column (ports): integer wire dtype.
+                # The chunk must re-verify integrality (a drifted
+                # non-integral chunk would otherwise truncate —
+                # range drift falls back, value drift must too) and
+                # a no-fit range keeps the float dtype (falling
+                # through to "<i4" would store floats uncast in the
+                # int parts bucket).
+                wdt, bias = _wire_dtype("<i4", eff_mn, eff_mx)
+                if wdt == "<i4" or not bool(np.all(a == np.trunc(a))):
+                    wdt, bias = dt, 0
             else:
-                store_w = w
-            off = widths.get(wdt, 0)
-            parts.setdefault(wdt, []).append(a.reshape(pad_n, store_w))
-            layout.append((key, sub, wdt, off, tail, w, dt,
-                           dict_vals if dict_vals is not None else bias))
-            widths[wdt] = off + store_w
+                wdt, bias = _wire_dtype(dt, eff_mn, eff_mx)
+            dct = st[3] if len(st) > 3 else None
+            if dct is not None and wdt not in ("|u1", "|n1"):
+                # u1 dictionary remap: wide-range low-cardinality
+                # column (e.g. label-key sids) stores dictionary
+                # indices; the sorted dictionary rides the static
+                # layout and is gathered from a baked constant on
+                # device.  Chunk values outside the corpus
+                # dictionary (cluster drift) fall back to the plain
+                # narrowed dtype — one retrace, never wrong results.
+                dv = np.array(sorted(dct), np.int64)
+                idx = np.searchsorted(dv, a.ravel())
+                idx_c = np.minimum(idx, len(dv) - 1)
+                if bool(np.all(dv[idx_c] == a.ravel())):
+                    a = idx_c.astype(np.uint8).reshape(a.shape)
+                    wdt, bias = "|u1", 0
+                    dict_vals = tuple(int(x) for x in dv)
+        else:
+            wdt, bias = dt, 0
+        w = int(np.prod(tail, dtype=np.int64)) if a.ndim > 1 else 1
+        if wdt == "|n1" and w % 2:
+            wdt = "|u1"  # nibble pairs need an even element count
+        if wdt == "|n1":
+            b = (a + bias).astype(np.uint8).reshape(pad_n, w)
+            a = b[:, 0::2] | (b[:, 1::2] << 4)
+            store_w = w // 2
+        elif bias:
+            a = (a + bias).astype(np.dtype(wdt))
+            store_w = w
+        else:
+            store_w = w
+        off = widths.get(wdt, 0)
+        parts.setdefault(wdt, []).append(a.reshape(pad_n, store_w))
+        layout.append((key, sub, wdt, off, tail, w, dt,
+                       dict_vals if dict_vals is not None else bias))
+        widths[wdt] = off + store_w
     bufs = {dt: np.concatenate(ps, axis=1) for dt, ps in parts.items()}
     return bufs, tuple(layout)
+
+
+# source dtypes the native pass reads values of (any other it only copies)
+_FUSED_SRC = ("<i4", "<i8", "|i1", "<f4", "|u1", "|b1")
+
+
+def _wire_plan(mod, cols: dict, pad_n: int, stats: dict):
+    """The chunk's wire layout as the corpus stats alone decide it, and
+    one step a shipped or checked column for ``gtpu_wirepack.pack``:
+    ``(op, source, wire dtype, offset, argument)``.
+
+    :func:`pack_transfer_cols_py` folds the chunk's own range into each
+    decision, which is why it must reduce a column before it can write
+    it.  The chunk's range can only widen a column, so as long as every
+    value fits the type the stats chose, an elided column is still the
+    corpus constant, a dictionary column holds corpus values only and an
+    integral float column is still integral, the stats decide alone:
+    each step carries its condition, the native pass checks it while it
+    writes, and one failed step sends the whole chunk to the numpy form.
+    None: a decision rests on values of a dtype that pass does not
+    read."""
+    layout: list = []
+    steps: list = []
+    widths: dict = {}
+    for key, sub, a, ref in _transfer_columns(cols):
+        if ref is not None:
+            layout.append((key, sub, "alias", 0, (), 0, a.dtype.str, ref))
+            continue
+        a = np.ascontiguousarray(a)
+        dt = a.dtype.str
+        tail = a.shape[1:]
+        w = int(np.prod(tail, dtype=np.int64)) if a.ndim > 1 else 1
+        st = stats.get((key, sub))
+        intf = dt == "<f4" and st is not None and len(st) > 4 and st[4]
+        narrowable = dt in ("<i4", "<i8", "|i1") or intf
+        op, wdt, bias, arg = mod.COPY, dt, 0, None
+        if st is not None and (st[2] is not None or narrowable) and a.size:
+            if dt not in _FUSED_SRC:
+                return None
+            if st[2] is not None:
+                if dt != "<f4" and not float(st[2]).is_integer():
+                    return None  # by hand: no integer column equals it
+                steps.append((mod.CHECK, a, None, 0, st[2]))
+                layout.append((key, sub, "const", 0, tail, 0, dt, st[2]))
+                continue
+            if narrowable:
+                wdt, bias = _wire_dtype("<i4" if intf else dt, st[0], st[1])
+                if intf and wdt == "<i4":
+                    wdt, bias = dt, 0
+            dct = st[3] if len(st) > 3 else None
+            if dct is not None and wdt not in ("|u1", "|n1"):
+                if dt not in ("<i4", "<i8"):
+                    return None
+                op, wdt, bias = mod.DICT, "|u1", 0
+                arg = np.array(sorted(dct), np.int64)
+            elif wdt != dt:
+                if wdt == "|n1" and w % 2:
+                    wdt = "|u1"
+                op = mod.NIBBLE if wdt == "|n1" else mod.BIAS
+                arg = bias
+        off = widths.get(wdt, 0)
+        steps.append((op, a, wdt, off, arg))
+        layout.append((key, sub, wdt, off, tail, w, dt,
+                       tuple(int(x) for x in arg) if op == mod.DICT
+                       else bias))
+        widths[wdt] = off + (w // 2 if wdt == "|n1" else w)
+    return tuple(layout), steps, widths
+
+
+def pack_transfer_cols(cols: dict, pad_n: int,
+                       stats: Optional[dict] = None,
+                       counts: Optional[dict] = None) -> tuple:
+    """:func:`pack_transfer_cols_py`'s buffers and layout, byte for byte
+    and element for element, by one native call for the chunk
+    (``native/wirepackmod.c``) wherever the corpus stats settle the
+    layout: each column is read once and written once, into buffers
+    allocated for this chunk (``device_put`` may still read a host
+    buffer after it returns, so none is reused).  The numpy form packs
+    the whole chunk where a column drifted out of the stats, where there
+    are no stats, and where the module does not build.
+
+    ``counts``, if given, receives ``fused`` and ``numpy``: the columns
+    the layout ships (aliases and constants left out), under the path
+    that packed them."""
+    out = None
+    mod = native.load_wirepack() if stats else None
+    plan = _wire_plan(mod, cols, pad_n, stats) if mod is not None else None
+    if plan is not None:
+        layout, steps, widths = plan
+        bufs = {wdt: np.empty((pad_n, width),
+                              np.uint8 if wdt == "|n1" else np.dtype(wdt))
+                for wdt, width in widths.items()}
+        steps = [(op, a, bufs.get(wdt), off, arg)
+                 for op, a, wdt, off, arg in steps]
+        if not mod.pack(steps, pad_n):
+            out = bufs, layout
+    fused = out is not None
+    if not fused:
+        out = pack_transfer_cols_py(cols, pad_n, stats)
+    if counts is not None:
+        shipped = sum(1 for e in out[1] if e[2] not in ("alias", "const"))
+        counts["fused"] = shipped if fused else 0
+        counts["numpy"] = 0 if fused else shipped
+    return out
 
 
 def unpack_transfer_cols(bufs: dict, layout: tuple, pad_n: int) -> dict:
@@ -1714,8 +1829,14 @@ class ShardedEvaluator:
         # inventory tables (device-cached on content), and the mask.
         with self._timed("wire_pack",
                          tracing.span("device.sweep_dispatch.pack")):
+            pack_counts: dict = {}
             cols_bufs, cols_layout = pack_transfer_cols(
-                cols, pad_n, stats=self._col_stats or None)
+                cols, pad_n, stats=self._col_stats or None,
+                counts=pack_counts)
+            # written on every dispatch, a 0 too
+            for path, shipped in pack_counts.items():
+                self._perf_add("wire_cols_" + path, shipped)
+                tracing.set_attribute("wire_cols_" + path, shipped)
         # the bit-packed match mask crosses beside the columns
         mask_bytes = c_off * pad_n // 8
         self._perf_add("mask_wire_bytes", mask_bytes)

@@ -16,6 +16,8 @@ from gatekeeper_tpu.client.client import Client
 from gatekeeper_tpu.drivers.tpu_driver import TpuDriver
 from gatekeeper_tpu.metrics import registry as M
 from gatekeeper_tpu.observability import tracing
+from gatekeeper_tpu.ops import native
+from gatekeeper_tpu.parallel import sharded
 from gatekeeper_tpu.parallel.sharded import ShardedEvaluator, make_mesh
 from gatekeeper_tpu.pipeline import PipelineError, Stage, StagedPipeline
 from gatekeeper_tpu.target.target import K8sValidationTarget
@@ -251,6 +253,80 @@ def test_dispatch_parts_keep_their_perf_keys(toy):
     for key in ("flatten", "masks", "wire_pack", "wire_bytes", "dispatch",
                 "collect", "d2h_bytes"):
         assert evaluator.perf.get(key, 0.0) > 0.0, key
+
+
+# --- the wire pack's two paths ----------------------------------------------
+
+def _kept(run):
+    return (dict(run.total_violations),
+            {key: sorted((v.message, v.kind, v.namespace, v.name)
+                         for v in vs) for key, vs in run.kept.items()})
+
+
+def _pack_pass(toy, evaluator, monkeypatch):
+    """One serial pass over the toy cluster: (what it kept, the evaluator's
+    counters, the columns its layouts shipped, its pack spans)."""
+    client, _ = toy
+    layouts = []
+    real = sharded.pack_transfer_cols
+
+    def recording(*args, **kw):
+        out = real(*args, **kw)
+        layouts.append(out[1])
+        return out
+
+    monkeypatch.setattr(sharded, "pack_transfer_cols", recording)
+    evaluator.perf_reset()
+    objects = _objects(40)
+    mgr = AuditManager(
+        client, lister=lambda: iter(objects),
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline="off"),
+        evaluator=evaluator)
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        run = mgr.audit()
+    monkeypatch.setattr(sharded, "pack_transfer_cols", real)
+    shipped = [sum(e[2] not in ("alias", "const") for e in layout)
+               for layout in layouts]
+    spans = [s["attributes"] for t in tracer.traces() for s in t["spans"]
+             if s["name"] == "device.sweep_dispatch.pack"]
+    return _kept(run), dict(evaluator.perf), shipped, spans
+
+
+def test_wire_cols_are_counted_by_the_path_that_packed_them(
+        toy, monkeypatch):
+    client, plain = toy
+    # no corpus stats: no plan, every column by the numpy form, and both
+    # counters written all the same
+    kept0, perf, shipped, spans = _pack_pass(toy, plain, monkeypatch)
+    assert len(shipped) == 3 and all(shipped)
+    assert perf["wire_cols_fused"] == 0
+    assert perf["wire_cols_numpy"] == sum(shipped)
+    assert [(a["wire_cols_fused"], a["wire_cols_numpy"]) for a in spans] \
+        == [(0, n) for n in shipped]
+
+    warmed = ShardedEvaluator(plain.driver, make_mesh(), violations_limit=5)
+    warmed.warm_pass(client.constraints(), _objects(40), 16)
+    assert warmed._col_stats
+    kept1, perf, shipped, spans = _pack_pass(toy, warmed, monkeypatch)
+    have = native.load_wirepack() is not None
+    fused = sum(shipped) if have else 0
+    assert len(shipped) == 3 and all(shipped)
+    assert perf["wire_cols_fused"] == fused
+    assert perf["wire_cols_numpy"] == sum(shipped) - fused
+    assert [a["wire_cols_fused"] + a["wire_cols_numpy"] for a in spans] \
+        == shipped
+
+    # the module unloaded: share 0, the same columns, the same verdicts
+    monkeypatch.setattr(native, "load_wirepack", lambda: None)
+    kept2, perf, shipped2, spans = _pack_pass(toy, warmed, monkeypatch)
+    assert shipped2 == shipped
+    assert perf["wire_cols_fused"] == 0
+    assert perf["wire_cols_numpy"] == sum(shipped)
+    assert [(a["wire_cols_fused"], a["wire_cols_numpy"]) for a in spans] \
+        == [(0, n) for n in shipped]
+    assert kept0 == kept1 == kept2 and sum(kept0[0].values()) > 0
 
 
 # --- full collections on the program's timeline -----------------------------

@@ -1,7 +1,7 @@
 """Native-module lint: warning-clean and sanitizer-clean C kernels.
 
-Two gates over ``native/flattenmod.c``, ``native/flattenjsonmod.c`` and
-``native/listroutemod.c``:
+Two gates over ``native/flattenmod.c``, ``native/flattenjsonmod.c``,
+``native/listroutemod.c`` and ``native/wirepackmod.c``:
 
 - **strict compile** — every module must build with
   ``-Wall -Wextra -Werror`` (a warning in kernel code is a bug
@@ -10,8 +10,8 @@ Two gates over ``native/flattenmod.c``, ``native/flattenjsonmod.c`` and
   ``-fsanitize=address,undefined`` through the normal
   ``ops/native.py`` build (the flag set hashes into the output dir,
   so the sanitized build can never be satisfied by a stale plain
-  binary) and run the flatten and list-routing unit corpus (the
-  router's untrack and ``track()`` cases with it) under it in
+  binary) and run the flatten, list-routing and wire-pack unit corpus
+  (the router's untrack and ``track()`` cases with it) under it in
   a subprocess with libasan preloaded.  Memory errors or UB in the threaded
   kernel abort the run.
 
@@ -28,7 +28,8 @@ import sys
 import sysconfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SOURCES = ("flattenmod.c", "flattenjsonmod.c", "listroutemod.c")
+SOURCES = ("flattenmod.c", "flattenjsonmod.c", "listroutemod.c",
+           "wirepackmod.c")
 STRICT_FLAGS = ["-Wall", "-Wextra", "-Werror"]
 
 
@@ -89,6 +90,12 @@ def asan_corpus_run(timeout_s: float = 600.0) -> tuple:
            os.path.join(REPO, "tests", "test_native_flatten.py"),
            os.path.join(REPO, "tests", "test_list_routing.py"),
            os.path.join(REPO, "tests", "test_rawjson_untracked.py")]
+    # the wire pack's cases that compile nothing: XLA's compiler does not
+    # run under the preloaded libasan
+    cmd += [os.path.join(REPO, "tests", "test_transfer_pack.py") + "::" + t
+            for t in ("test_row_counts_around_the_native_block",
+                      "test_stats_written_by_hand_never_give_another_answer",
+                      "test_the_steps_that_failed_are_named")]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=timeout_s, cwd=REPO, env=env)
