@@ -202,6 +202,19 @@ class TpuDriver:
         answer from the on-disk compile cache when the entry's vocab
         snapshot replays here (zero lowering, zero trial), else lower +
         trial-build and persist the result (program or error)."""
+        from gatekeeper_tpu.observability import tracing
+
+        with tracing.span("driver.lower", kind=kind, engine=engine) as sp:
+            program, err, cached = self._lower_or_cached_impl(
+                kind, engine, template, lower_fn)
+            sp.set_attribute("lowered", program is not None)
+            sp.set_attribute("cached", cached)
+            if err is not None:
+                sp.set_attribute("error", err)
+        return program, err, cached
+
+    def _lower_or_cached_impl(self, kind: str, engine: str, template,
+                              lower_fn) -> tuple:
         cache = self._compile_cache
         digest = ""
         if cache is not None:

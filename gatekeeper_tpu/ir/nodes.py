@@ -108,6 +108,14 @@ class ParamElemFieldNum(Expr):
 
 
 @dataclass(frozen=True)
+class ParamElemFieldPresent(Expr):
+    """The current object-list element has the field (CEL has(r.max))."""
+
+    param: str
+    field: tuple
+
+
+@dataclass(frozen=True)
 class StrFnNum(Expr):
     """Vocab-table numeric function of a string feature (units.parse /
     units.parse_bytes): table[sid] with validity mask."""
@@ -411,6 +419,10 @@ class ParamSpec:
     name: str
     kind: str  # num | str | bool | strlist | numlist | objlist
     fields: tuple = ()  # objlist: ((path_tuple, "num"|"str"), ...)
+    # a parameter the host computes from the constraint's parameters when
+    # the table is built: ``derive.value(params) -> (ok, value)``, absent
+    # where not ok (ir/lower_cel.py CelDerive)
+    derive: object = None
 
 
 @dataclass

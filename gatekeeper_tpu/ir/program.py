@@ -326,6 +326,17 @@ def p_get(params: dict, name: str, default=None):
     return deep_get(params, name.split("."), default)
 
 
+def _with_derived(params: dict, specs: list) -> dict:
+    """``params`` with every host-derived parameter beside its own (a
+    derivation that errs leaves its name absent)."""
+    out = dict(params)
+    for spec in specs:
+        ok, value = spec.derive.value(params)
+        if ok:
+            out[spec.name] = value
+    return out
+
+
 def build_param_table(program: N.Program, constraints, vocab: Vocab) -> dict:
     """Pack constraint parameters into arrays [C, ...] for vmap.
 
@@ -339,6 +350,9 @@ def build_param_table(program: N.Program, constraints, vocab: Vocab) -> dict:
         (con.parameters or {}) if isinstance(con.parameters, dict) else {}
         for con in constraints
     ]
+    derived = [s for s in program.params if s.derive is not None]
+    if derived:
+        params_by_con = [_with_derived(p, derived) for p in params_by_con]
     for spec in program.params:
         vals = [p_get(p, spec.name) for p in params_by_con]
         # every param row carries a kind tag: 0 absent, 1 false, 2 true,
@@ -1019,6 +1033,10 @@ def eval_expr(ctx: _Ctx, e: N.Expr):
         return ctx.row[f"{e.name}__kind"] > 0
     if isinstance(e, N.ParamBoolIs):
         return ctx.row[f"{e.name}__kind"] == (2 if e.want else 1)
+    if isinstance(e, N.ParamElemFieldPresent):
+        if ctx.elem_k is None:
+            raise LowerError("ParamElemFieldPresent outside AnyParamList")
+        return ctx.row[f"{e.param}.{'.'.join(e.field)}__fpresent"]
     if isinstance(e, N.KindIs):
         a = _feat_arrays(ctx, e.col)
         ragged = isinstance(e.col, RaggedCol)
@@ -1178,7 +1196,15 @@ def eval_expr(ctx: _Ctx, e: N.Expr):
         return out if out is not None else jnp.bool_(False)
     if isinstance(e, N.AnyAxis):
         if ctx.axis is not None:
-            raise LowerError("nested AnyAxis unsupported (flatten the axis)")
+            # a CLOSED reduction under another axis's item (it reads no
+            # column of that axis; the lowerer's to hold): one answer per
+            # object, the same for every outer item
+            outer, ctx.axis = ctx.axis, None
+            try:
+                closed = eval_expr(ctx, e)  # [N] (+K)
+            finally:
+                ctx.axis = outer
+            return closed[:, None]
         counts = ctx.cols[axis_key(e.axis)]  # [N]
         ctx.axis = e.axis
         try:

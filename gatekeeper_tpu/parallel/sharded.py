@@ -1782,6 +1782,15 @@ class ShardedEvaluator:
             tracing.set_attribute("constraints", c_off)
             for key in ("rows_vectorized", "rows_predicate"):
                 tracing.set_attribute(key, mask_counts[key])
+        # constraint rows of this dispatch, and those whose program
+        # ir/lower_cel.py produced: written on every dispatch, a 0 too
+        cel_kinds = self.driver._cel_kinds
+        for key, rows in (
+                ("sweep_rows", c_off),
+                ("sweep_rows_cel", sum(len(by_kind[kind]) for kind in kinds
+                                       if kind in cel_kinds))):
+            self._perf_add(key, rows)
+            tracing.set_attribute(key, rows)
         from gatekeeper_tpu.observability import costattr
 
         complete = bool(return_bits)

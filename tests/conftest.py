@@ -39,9 +39,17 @@ def reference_dir():
 # repeated, as containment and relative order, in tests/benchmark/
 # test_fused_share_metric.py; a `benchmark` PR should relax the two lines
 # and drop this (PERF.md section 7, ROADMAP S0b).
+#
+# Its neighbour holds the manifest to exactly four cells, four
+# configurations and PR 32's entries as the last of both lists, so it fails
+# the moment a `model_config` PR appends its cell (PR 34: `library-cel`,
+# `cel.audit-sweep`).  Its other assertions are repeated, as containment,
+# in tests/benchmark/test_library_cel.py.
 _STALE_PINS = {
     "tests/benchmark/test_c500sel_library.py::"
     "test_the_cell_reports_what_the_control_reports_and_the_two_new",
+    "tests/benchmark/test_c500sel_library.py::"
+    "test_the_manifest_resolves_with_four_cells_and_four_configurations",
 }
 
 

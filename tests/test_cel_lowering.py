@@ -438,20 +438,28 @@ validations:
     _assert_agreement(tpu, [con], objs)
 
 
-def test_cel_two_variable_value_body_falls_back():
+def test_cel_two_variable_value_body_has_list_semantics():
     """A two-variable body that can decide from the VALUE alone has real
-    list semantics (index keys don't error it) — must fall back, and
-    agree with the oracle through query_batch."""
+    list semantics (index keys don't error it): the list branch binds the
+    index to a value on which only string methods err, so it lowers and
+    agrees with the oracle on maps and on lists."""
     tpu, con = _mini_cel("""
 validations:
   - expression: 'object.metadata.labels.all(k, v, v != "")'
     message: empty label value
 """, kind="K8sCelTwoVarVal")
-    assert "K8sCelTwoVarVal" in tpu.fallback_kinds(), tpu.lowered_kinds()
+    assert "K8sCelTwoVarVal" in tpu.lowered_kinds(), tpu.fallback_kinds()
     objs = [
         {"apiVersion": "v1", "kind": "Pod",
          "metadata": {"name": "a", "labels": {"x": ""}}},
         {"apiVersion": "v1", "kind": "Pod",
          "metadata": {"name": "b", "labels": {"x": "1"}}},
+        {"apiVersion": "v1", "kind": "Pod",
+         "metadata": {"name": "c", "labels": ["1", ""]}},
+        {"apiVersion": "v1", "kind": "Pod",
+         "metadata": {"name": "d", "labels": ["1", 2]}},
+        {"apiVersion": "v1", "kind": "Pod",
+         "metadata": {"name": "e", "labels": []}},
+        {"apiVersion": "v1", "kind": "Pod", "metadata": {"name": "f"}},
     ]
     _assert_agreement(tpu, [con], objs)
