@@ -31,8 +31,8 @@ from gatekeeper_tpu.client.client import Client
 from gatekeeper_tpu.drivers.base import ReviewCfg
 from gatekeeper_tpu.match.match import SOURCE_ORIGINAL
 from gatekeeper_tpu.target.review import AugmentedUnstructured
-from gatekeeper_tpu.utils.rawjson import RawJSON
-from gatekeeper_tpu.utils.unstructured import gvk_of
+from gatekeeper_tpu.utils.rawjson import RawJSON, peek_identity
+from gatekeeper_tpu.utils.unstructured import gvk_of, split_api_version
 
 
 @dataclass
@@ -782,12 +782,13 @@ class AuditManager:
         return self._snapshot_collect(constraints)
 
     def _begin_pass(self, constraints) -> None:
-        """Size the render memo for the pass and put the renderer's
-        counters into ``perf``, a 0 too: a pass of nothing but hits still
-        reports how many renders it ran."""
+        """Size the render memo for the pass and put the renderer's and
+        :meth:`_violation`'s counters into ``perf``, a 0 too: a pass of
+        nothing but hits still reports how many renders it ran."""
         self._render_memo.begin_pass(len(constraints),
                                      self.config.violations_limit)
-        for key in ("n_renders", "render_memo_hits", "render_memo_bypass"):
+        for key in ("n_renders", "render_memo_hits", "render_memo_bypass",
+                    "violation_peeked", "violation_loaded"):
             self.perf[key] = self.perf.get(key, 0)
         self._perf_add("render", 0.0)
 
@@ -2109,7 +2110,9 @@ class AuditManager:
 
         exact = self.config.exact_totals
         render_obj = self._render_fn(source, review_cache)
-        hits0 = self.perf.get("render_memo_hits", 0)
+        span_keys = ("render_memo_hits", "violation_peeked",
+                     "violation_loaded")
+        before = [self.perf.get(key, 0) for key in span_keys]
 
         def render(con, oi):
             return render_obj(con, objects[oi], oi)
@@ -2134,9 +2137,8 @@ class AuditManager:
                                    totals, limit, overrides=overrides)
         # on the chunk's fold span (pipeline.stage.fold_render, or
         # audit.chunk.collect_fold on the serial schedule)
-        tracing.set_attribute(
-            "render_memo_hits",
-            self.perf.get("render_memo_hits", 0) - hits0)
+        for key, n0 in zip(span_keys, before):
+            tracing.set_attribute(key, self.perf.get(key, 0) - n0)
 
     def _chunk_via_query_batch(self, driver, constraints, objects, reviews,
                                kept, totals, limit, overrides=None):
@@ -2162,8 +2164,23 @@ class AuditManager:
 
     def _violation(self, con, obj, msg, details,
                    override=None) -> Violation:
-        group, version, kind = gvk_of(obj)
-        meta = obj.get("metadata") or {}
+        """The kept violation of ``con`` on ``obj``.  The four strings
+        that name the object come off the bytes of an unloaded ``RawJSON``
+        where ``peek_identity`` settles them, so a violation whose render
+        the memo answered loads nothing; otherwise from the object, which
+        then loads.  ``perf`` counts the two ways."""
+        perf = self.perf
+        ident = peek_identity(obj)
+        if ident is not None:
+            perf["violation_peeked"] = perf.get("violation_peeked", 0) + 1
+            api_version, kind, name, namespace = ident
+            group, version = split_api_version(api_version)
+        else:
+            perf["violation_loaded"] = perf.get("violation_loaded", 0) + 1
+            group, version, kind = gvk_of(obj)
+            meta = obj.get("metadata") or {}
+            name = meta.get("name", "") or ""
+            namespace = meta.get("namespace", "") or ""
         actions = con.actions_for(AUDIT_EP)
         action = actions[0] if actions else con.enforcement_action
         if override is not None:
@@ -2184,8 +2201,8 @@ class AuditManager:
             group=group,
             version=version,
             kind=kind,
-            name=meta.get("name", "") or "",
-            namespace=meta.get("namespace", "") or "",
+            name=name,
+            namespace=namespace,
             details=details,
         )
 

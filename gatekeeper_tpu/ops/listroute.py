@@ -10,6 +10,11 @@ the module builds: the call reads the kind from the head of an unloaded
 settles nothing.  On the way it takes every unloaded, still empty
 ``RawJSON`` off the cyclic collector's lists (such an object can be part
 of no cycle; ``utils/rawjson`` puts it back the moment it loads).
+Besides the head, the same module reads a kept violation's identity off
+such an object's bytes for the audit fold (``identity()``, behind
+``utils/rawjson.peek_identity``): apiVersion, kind, metadata.name and
+metadata.namespace in one validating pass over the whole document, so
+that the fold loads no object to name it.
 :func:`route_chunks_py` is the per-object loop it replaces: the fallback,
 and the reference the native call is tested against
 (``tests/test_list_routing.py``); its objects stay tracked.

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import json
 
+from gatekeeper_tpu.ops import native as _native
+
 # native/listroutemod.c's track(), set by ops/native.load_listroute()
 # when it binds the module to RawJSON.  Only that module untracks, so
 # while this is None every instance is still tracked.
@@ -233,6 +235,27 @@ def peek_kind(obj) -> str:
         pos += 6
     v = obj.get("kind")  # exact fallback (materializes this one object)
     return v if isinstance(v, str) else ""
+
+
+def peek_identity(obj):
+    """``(apiVersion, kind, name, namespace)`` of an unloaded ``RawJSON``
+    read from its bytes, or None.
+
+    The audit fold names every kept violation's object by these four; read
+    through the dict they cost a ``json.loads`` of the whole document and
+    leave its containers alive for the collector.  The scan is native
+    (``native/listroutemod.c:identity``): one validating pass over the
+    top-level object, the last of a repeated key winning as it does in
+    ``json.loads``.  A missing key or ``null`` is ``""``.  None wherever
+    the dict could say anything else (an escape in one of the four, a value
+    that is no string, a ``metadata`` that is no object, bytes
+    ``json.loads`` would refuse), for a loaded object, a plain dict, a
+    subclass, and where the module does not build: the caller reads the
+    object."""
+    if type(obj) is not RawJSON or obj._loaded:
+        return None
+    mod = _native.load_listroute()
+    return None if mod is None else mod.identity(obj)
 
 
 def as_raw(obj) -> "RawJSON":

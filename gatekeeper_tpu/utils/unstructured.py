@@ -61,11 +61,16 @@ def gvk_of(obj: dict) -> tuple[str, str, str]:
         api_version = ""
     if not isinstance(kind, str):
         kind = ""
+    return *split_api_version(api_version), kind
+
+
+def split_api_version(api_version: str) -> tuple[str, str]:
+    """(group, version) of an ``apiVersion`` string."""
     if "/" in api_version:
         group, version = api_version.split("/", 1)
     else:
         group, version = "", api_version
-    return group, version, kind
+    return group, version
 
 
 def api_version_of(group: str, version: str) -> str:

@@ -23,6 +23,14 @@
  * alive as long as its chunk, is what promoted into CPython's full
  * collections.  utils/rawjson puts the object back through track() the
  * moment it loads, before a container can go into it.
+ *
+ * What it reads besides the head: identity() gives the audit fold the four
+ * strings a kept violation names its object by (apiVersion, kind,
+ * metadata.name, metadata.namespace) from the bytes of such an unloaded
+ * RawJSON, in one validating pass over the whole top-level object (keys
+ * may repeat, the last wins, and metadata need not come early), so that
+ * the fold loads no object for them.  It answers None wherever json.loads
+ * and the dict would, or might, say anything else.
  */
 
 #define PY_SSIZE_T_CLEAN
@@ -301,6 +309,339 @@ route(PyObject *self, PyObject *args)
     return full;
 }
 
+/* --- identity(): a kept violation's four strings, off the bytes --------
+ *
+ * One pass over the document with a JSON skipper that accepts no more
+ * than json.loads does: strings with their escapes checked and no raw
+ * control byte, numbers by the grammar, true / false / null, objects and
+ * arrays with their commas and colons, the four whitespace bytes between
+ * tokens.  Anything else ends the scan and the answer is None. */
+
+#define ID_MAX_DEPTH 64  /* deeper than any object the API serves: None */
+#define ID_MAX_NUMBER 64 /* int() refuses 4300 digits; none comes near */
+
+typedef struct {
+    const unsigned char *end;
+    int high; /* a byte >= 0x80 was seen: the document gets decoded whole */
+} id_scan;
+
+/* one of the four values: 0 absent or null (""), 1 a plain string, 2 a
+ * value the dict would have to answer for */
+typedef struct {
+    int state;
+    const unsigned char *s;
+    Py_ssize_t n;
+} id_field;
+
+static const unsigned char *
+id_ws(const unsigned char *p, const unsigned char *end)
+{
+    while (p < end && (*p == ' ' || *p == '\n' || *p == '\r' || *p == '\t'))
+        p++;
+    return p;
+}
+
+/* p at the opening quote: the byte after the closing one, or NULL.
+ * *esc is set where the string holds a backslash.  The run of plain bytes
+ * is walked without a bound: the document is a bytes object, which ends
+ * in a NUL of its own, and a NUL stops the walk like any control byte. */
+static const unsigned char *
+id_string(id_scan *sc, const unsigned char *p, int *esc)
+{
+    const unsigned char *end = sc->end;
+    *esc = 0;
+    for (p++;; p++) {
+        while (*p >= 0x20 && *p < 0x80 && *p != '"' && *p != '\\')
+            p++;
+        if (p >= end)
+            return NULL;
+        unsigned char c = *p;
+        if (c == '"')
+            return p + 1;
+        if (c < 0x20)
+            return NULL;
+        if (c >= 0x80) {
+            sc->high = 1;
+            continue;
+        }
+        /* a backslash */
+        *esc = 1;
+        if (++p >= end)
+            return NULL;
+        if (*p == 'u') {
+            if (end - p < 5)
+                return NULL;
+            for (int i = 1; i <= 4; i++) {
+                unsigned char h = p[i];
+                if (!((h >= '0' && h <= '9') || (h >= 'a' && h <= 'f')
+                      || (h >= 'A' && h <= 'F')))
+                    return NULL;
+            }
+            p += 4;
+        } else if (*p == 0 || strchr("\"\\/bfnrt", *p) == NULL) {
+            return NULL;
+        }
+    }
+}
+
+static const unsigned char *
+id_digits(const unsigned char *p, const unsigned char *end)
+{
+    const unsigned char *q = p;
+    while (q < end && *q >= '0' && *q <= '9')
+        q++;
+    return q == p ? NULL : q;
+}
+
+/* -?(0|[1-9][0-9]*)(\.[0-9]+)?([eE][+-]?[0-9]+)? */
+static const unsigned char *
+id_number(const unsigned char *p, const unsigned char *end)
+{
+    const unsigned char *start = p;
+    if (p < end && *p == '-')
+        p++;
+    if (p < end && *p == '0')
+        p++;
+    else if ((p = id_digits(p, end)) == NULL)
+        return NULL;
+    if (p < end && *p == '.' && (p = id_digits(p + 1, end)) == NULL)
+        return NULL;
+    if (p < end && (*p == 'e' || *p == 'E')) {
+        p++;
+        if (p < end && (*p == '+' || *p == '-'))
+            p++;
+        if ((p = id_digits(p, end)) == NULL)
+            return NULL;
+    }
+    return p - start > ID_MAX_NUMBER ? NULL : p;
+}
+
+static const unsigned char *
+id_literal(const unsigned char *p, const unsigned char *end, const char *w)
+{
+    size_t n = strlen(w);
+    if ((size_t)(end - p) < n || memcmp(p, w, n) != 0)
+        return NULL;
+    return p + n;
+}
+
+/* Skip one value of any kind: the byte after it, or NULL.  Containers are
+ * walked with their kinds on a small stack, so the C stack stays flat. */
+static const unsigned char *
+id_skip(id_scan *sc, const unsigned char *p)
+{
+    const unsigned char *end = sc->end;
+    char stack[ID_MAX_DEPTH]; /* '{' or '[' per open container */
+    int depth = 0, esc;
+    for (;;) {
+        /* a value starts at p */
+        if (p >= end)
+            return NULL;
+        switch (*p) {
+        case '"':
+            p = id_string(sc, p, &esc);
+            break;
+        case '{':
+        case '[':
+            if (depth == ID_MAX_DEPTH)
+                return NULL;
+            stack[depth++] = (char)*p;
+            p = id_ws(p + 1, end);
+            if (p < end && *p == (stack[depth - 1] == '{' ? '}' : ']')) {
+                depth--;
+                p++;
+                break; /* an empty container is a finished value */
+            }
+            if (stack[depth - 1] == '[')
+                continue;
+            goto key;
+        case 't':
+            p = id_literal(p, end, "true");
+            break;
+        case 'f':
+            p = id_literal(p, end, "false");
+            break;
+        case 'n':
+            p = id_literal(p, end, "null");
+            break;
+        default:
+            p = id_number(p, end);
+        }
+        /* a value ended at p: close what it completes */
+        for (;;) {
+            if (p == NULL)
+                return NULL;
+            if (depth == 0)
+                return p;
+            p = id_ws(p, end);
+            if (p >= end)
+                return NULL;
+            if (*p == ',') {
+                p = id_ws(p + 1, end);
+                break;
+            }
+            if (*p != (stack[depth - 1] == '{' ? '}' : ']'))
+                return NULL;
+            depth--;
+            p++;
+        }
+        if (stack[depth - 1] == '[')
+            continue;
+    key:
+        if (p >= end || *p != '"'
+            || (p = id_string(sc, p, &esc)) == NULL)
+            return NULL;
+        p = id_ws(p, end);
+        if (p >= end || *p != ':')
+            return NULL;
+        p = id_ws(p + 1, end);
+    }
+}
+
+/* The value at p into *f if it is a string with no escape or null, state
+ * 2 otherwise; the byte after it, or NULL. */
+static const unsigned char *
+id_take(id_scan *sc, const unsigned char *p, id_field *f)
+{
+    if (p < sc->end && *p == '"') {
+        int esc;
+        const unsigned char *q = id_string(sc, p, &esc);
+        if (q != NULL) {
+            f->state = esc ? 2 : 1;
+            f->s = p + 1;
+            f->n = q - p - 2;
+        }
+        return q;
+    }
+    if (p < sc->end && *p == 'n') {
+        memset(f, 0, sizeof(*f));
+        return id_literal(p, sc->end, "null");
+    }
+    f->state = 2;
+    return id_skip(sc, p);
+}
+
+enum { ID_API, ID_KIND, ID_NAME, ID_NS, ID_META, ID_N };
+
+static const unsigned char *id_metadata(id_scan *, const unsigned char *,
+                                        id_field *);
+
+/* The members of the object that opens at p ('{'): the top-level object
+ * (apiVersion, kind, metadata) or, with `meta` set, the metadata object
+ * (name, namespace).  The value of each such key, the key written without
+ * an escape, is taken into its field; every other value is skipped.  A
+ * key with an escape could spell any name: NULL.  Returns the byte after
+ * the closing brace, or NULL. */
+static const unsigned char *
+id_object(id_scan *sc, const unsigned char *p, id_field *fields, int meta)
+{
+    static const char *const TOP[] = {"apiVersion", "kind", "metadata"};
+    static const char *const META[] = {"name", "namespace"};
+    const char *const *names = meta ? META : TOP;
+    const int n = meta ? 2 : 3, first = meta ? ID_NAME : ID_API;
+    const unsigned char *end = sc->end;
+    p = id_ws(p + 1, end);
+    if (p < end && *p == '}')
+        return p + 1;
+    for (;;) {
+        int esc, hit = -1;
+        if (p >= end || *p != '"')
+            return NULL;
+        const unsigned char *key = p + 1;
+        if ((p = id_string(sc, p, &esc)) == NULL || esc)
+            return NULL;
+        size_t klen = (size_t)(p - key - 1);
+        for (int i = 0; i < n; i++)
+            if (strlen(names[i]) == klen && memcmp(key, names[i], klen) == 0)
+                hit = i;
+        p = id_ws(p, end);
+        if (p >= end || *p != ':')
+            return NULL;
+        p = id_ws(p + 1, end);
+        if (hit < 0)
+            p = id_skip(sc, p);
+        else if (!meta && hit == 2) /* "metadata" */
+            p = id_metadata(sc, p, fields);
+        else
+            p = id_take(sc, p, &fields[first + hit]);
+        if (p == NULL)
+            return NULL;
+        p = id_ws(p, end);
+        if (p >= end)
+            return NULL;
+        if (*p == '}')
+            return p + 1;
+        if (*p != ',')
+            return NULL;
+        p = id_ws(p + 1, end);
+    }
+}
+
+/* the top level's "metadata": an object gives name and namespace anew
+ * (the last metadata wins whole), null gives neither, and anything else
+ * is what `obj.get("metadata") or {}` has to answer for */
+static const unsigned char *
+id_metadata(id_scan *sc, const unsigned char *p, id_field *fields)
+{
+    memset(&fields[ID_NAME], 0, 3 * sizeof(*fields)); /* name, ns, meta */
+    if (p < sc->end && *p == '{')
+        return id_object(sc, p, fields, 1);
+    if (p < sc->end && *p == 'n')
+        return id_literal(p, sc->end, "null");
+    fields[ID_META].state = 2;
+    return id_skip(sc, p);
+}
+
+/* identity(obj): (apiVersion, kind, name, namespace) of an unloaded,
+ * exact-class RawJSON read from its bytes, or None where the scan cannot
+ * say exactly what the loaded dict would. */
+static PyObject *
+identity(PyObject *self, PyObject *obj)
+{
+    (void)self;
+    if (raw_type == NULL || Py_TYPE(obj) != raw_type)
+        Py_RETURN_NONE;
+    PyObject *loaded = *(PyObject **)((char *)obj + off_loaded);
+    PyObject *raw = *(PyObject **)((char *)obj + off_raw);
+    if (loaded != Py_False || raw == NULL || !PyBytes_CheckExact(raw)
+        || PyDict_GET_SIZE(obj) != 0)
+        Py_RETURN_NONE;
+    const unsigned char *p = (const unsigned char *)PyBytes_AS_STRING(raw);
+    id_scan sc = {p + PyBytes_GET_SIZE(raw), 0};
+    id_field fields[ID_N];
+    memset(fields, 0, sizeof(fields));
+    if (p >= sc.end || *p != '{'
+        || (p = id_object(&sc, p, fields, 0)) == NULL
+        || id_ws(p, sc.end) != sc.end)
+        Py_RETURN_NONE;
+    for (int i = 0; i < ID_N; i++)
+        if (fields[i].state == 2)
+            Py_RETURN_NONE;
+    if (sc.high) {
+        /* what json.loads would refuse to decode the fold must not name */
+        PyObject *whole = PyUnicode_DecodeUTF8(
+            PyBytes_AS_STRING(raw), PyBytes_GET_SIZE(raw), NULL);
+        if (whole == NULL) {
+            PyErr_Clear();
+            Py_RETURN_NONE;
+        }
+        Py_DECREF(whole);
+    }
+    PyObject *out = PyTuple_New(4);
+    if (out == NULL)
+        return NULL;
+    for (int i = 0; i < 4; i++) {
+        PyObject *s = PyUnicode_DecodeUTF8(
+            fields[i].n ? (const char *)fields[i].s : "", fields[i].n, NULL);
+        if (s == NULL) {
+            Py_DECREF(out);
+            return NULL;
+        }
+        PyTuple_SET_ITEM(out, i, s);
+    }
+    return out;
+}
+
 /* track(obj): put an object that route() took off the collector's lists
  * back on them.  Nothing to do for one that is on them (tracking it twice
  * is an error) or whose type the collector does not know. */
@@ -320,6 +661,8 @@ static PyMethodDef methods[] = {
      "The kind in the head of a document's bytes, or None."},
     {"route", route, METH_VARARGS,
      "Route listed objects into per-group chunk buffers until one fills."},
+    {"identity", identity, METH_O,
+     "(apiVersion, kind, name, namespace) of an unloaded RawJSON, or None."},
     {"track", track, METH_O,
      "Put an object back on the cyclic collector's lists."},
     {NULL, NULL, 0, NULL},

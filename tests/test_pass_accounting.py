@@ -635,3 +635,103 @@ def test_a_pass_with_nothing_to_render_still_writes_the_counters(toy):
                                      "render_memo_bypass", "render")} == \
         {"render_memo_hits": 0, "n_renders": 0, "render_memo_bypass": 0,
          "render": 0.0}
+
+
+# --- the kept violations' identity in the account (utils/rawjson.peek_identity)
+
+def _violations(run):
+    return {key: [(v.message, v.details, v.enforcement_action, v.group,
+                   v.version, v.kind, v.name, v.namespace) for v in vs]
+            for key, vs in run.kept.items()}
+
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+@pytest.mark.parametrize("raw", [True, False], ids=["rawjson", "dicts"])
+def test_every_kept_violation_is_counted_peeked_or_loaded(toy, pipeline, raw):
+    """``violation_peeked + violation_loaded`` is the kept violations of
+    the pass, both written on every pass: unloaded RawJSON whose renders
+    the memo answers are all peeked, objects a render loaded and plain
+    dicts are all read through the object; the chunk's fold span carries
+    the chunk's two counts."""
+    from gatekeeper_tpu.utils.rawjson import as_raw
+
+    client, evaluator = toy
+    objects = _objects(40)
+    lister = (lambda: (as_raw(o) for o in objects)) if raw \
+        else (lambda: iter(objects))
+    mgr = AuditManager(
+        client, lister=lister,
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline=pipeline),
+        evaluator=evaluator)
+    first = mgr.audit()
+    kept = sum(len(vs) for vs in first.kept.values())
+    assert kept > 5  # more than one chunk's
+    # the pass that rendered loaded what it rendered
+    assert (mgr.perf["violation_peeked"], mgr.perf["violation_loaded"]) \
+        == (0, kept)
+    mgr.perf = {}
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        second = mgr.audit()
+    have = raw and native.load_listroute() is not None
+    assert (mgr.perf["violation_peeked"], mgr.perf["violation_loaded"]) \
+        == ((kept, 0) if have else (0, kept))
+    assert _violations(second) == _violations(first)
+    name = "pipeline.stage.fold_render" if pipeline == "on" \
+        else "audit.chunk.collect_fold"
+    folds = [s["attributes"] for t in tracer.traces() for s in t["spans"]
+             if s["name"] == name]
+    assert len(folds) == 3
+    assert all({"violation_peeked", "violation_loaded",
+                "render_memo_hits"} <= set(a) for a in folds)
+    assert sum(a["violation_peeked"] for a in folds) \
+        == mgr.perf["violation_peeked"]
+    assert sum(a["violation_loaded"] for a in folds) \
+        == mgr.perf["violation_loaded"]
+    # a third pass adds to both
+    mgr.audit()
+    assert mgr.perf["violation_peeked"] + mgr.perf["violation_loaded"] \
+        == 2 * kept
+
+
+def test_without_the_native_module_every_violation_is_loaded_and_the_same(
+        toy, monkeypatch):
+    from gatekeeper_tpu.utils.rawjson import as_raw
+
+    client, evaluator = toy
+    objects = _objects(40)
+    mgr = AuditManager(
+        client, lister=lambda: (as_raw(o) for o in objects),
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline="on"),
+        evaluator=evaluator)
+    mgr.audit()
+    mgr.perf = {}
+    with_module = mgr.audit()
+    peeked = mgr.perf["violation_peeked"]
+    total = peeked + mgr.perf["violation_loaded"]
+    assert total == sum(len(vs) for vs in with_module.kept.values()) > 0
+    if native.load_listroute() is not None:
+        assert peeked == total
+    monkeypatch.setattr(native, "load_listroute", lambda: None)
+    mgr.perf = {}
+    without = mgr.audit()
+    assert (mgr.perf["violation_peeked"], mgr.perf["violation_loaded"]) \
+        == (0, total)
+    assert _violations(without) == _violations(with_module)
+    assert dict(without.total_violations) == \
+        dict(with_module.total_violations)
+
+
+def test_a_pass_that_keeps_nothing_still_writes_both_counters(toy):
+    client, evaluator = toy
+    clean = [o for o in _objects(40) if o["metadata"]["labels"]]
+    mgr = AuditManager(
+        client, lister=lambda: iter(clean),
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline="on"),
+        evaluator=evaluator)
+    mgr.audit()
+    assert (mgr.perf["violation_peeked"], mgr.perf["violation_loaded"]) \
+        == (0, 0)

@@ -163,6 +163,45 @@ def test_second_pass_over_the_same_bytes_renders_nothing(world):
         second, list(range(N_OBJECTS)), results, ident, LIMIT) == []
 
 
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+def test_a_pass_of_nothing_but_hits_loads_no_kept_object(world, pipeline):
+    """The memo answers every render and ``_violation`` reads the kept
+    objects' names off their bytes (``utils/rawjson.peek_identity``), so
+    the fold loads nothing: every object the second pass listed is still
+    unloaded, still empty and still off the collector's lists."""
+    import gc
+
+    from gatekeeper_tpu.ops import native
+
+    if native.load_listroute() is None:
+        pytest.skip("native/listroutemod.c does not build here")
+    corpus = Corpus(world)
+    listed: list = []
+
+    def lister():
+        listed.clear()
+        for raw in corpus.raws:
+            obj = RawJSON(raw)
+            listed.append(obj)
+            yield obj
+
+    mgr = corpus.manager(pipeline=pipeline)
+    mgr.lister = lister
+    first = mgr.audit()
+    assert any(o._loaded for o in listed)  # the renders read their objects
+    mgr.perf = {}
+    second = mgr.audit()
+    kept = sum(len(vs) for vs in second.kept.values())
+    assert counters(mgr)[1:] == (0, 0) and kept > 200
+    assert (mgr.perf["violation_peeked"], mgr.perf["violation_loaded"]) \
+        == (kept, 0)
+    assert len(listed) == N_OBJECTS
+    assert not any(o._loaded for o in listed)
+    assert not any(dict.__len__(o) for o in listed)
+    assert not any(gc.is_tracked(o) for o in listed)
+    assert canon(second) == canon(first)
+
+
 def test_serial_schedule_hits_and_marks_its_fold_span(world):
     corpus = Corpus(world)
     mgr = corpus.manager(pipeline="off")

@@ -11,7 +11,8 @@ Two gates over ``native/flattenmod.c``, ``native/flattenjsonmod.c``,
   ``ops/native.py`` build (the flag set hashes into the output dir,
   so the sanitized build can never be satisfied by a stale plain
   binary) and run the flatten, list-routing and wire-pack unit corpus
-  (the router's untrack and ``track()`` cases with it) under it in
+  (the router's untrack and ``track()`` cases and the identity scan's,
+  ``tests/test_rawjson_identity.py``, with it) under it in
   a subprocess with libasan preloaded.  Memory errors or UB in the threaded
   kernel abort the run.
 
@@ -89,7 +90,8 @@ def asan_corpus_run(timeout_s: float = 600.0) -> tuple:
            os.path.join(REPO, "tests", "test_native_flatten_json.py"),
            os.path.join(REPO, "tests", "test_native_flatten.py"),
            os.path.join(REPO, "tests", "test_list_routing.py"),
-           os.path.join(REPO, "tests", "test_rawjson_untracked.py")]
+           os.path.join(REPO, "tests", "test_rawjson_untracked.py"),
+           os.path.join(REPO, "tests", "test_rawjson_identity.py")]
     # the wire pack's cases that compile nothing: XLA's compiler does not
     # run under the preloaded libasan
     cmd += [os.path.join(REPO, "tests", "test_transfer_pack.py") + "::" + t
