@@ -30,6 +30,7 @@ from gatekeeper_tpu.audit.render_memo import RenderMemo
 from gatekeeper_tpu.client.client import Client
 from gatekeeper_tpu.drivers.base import ReviewCfg
 from gatekeeper_tpu.match.match import SOURCE_ORIGINAL
+from gatekeeper_tpu.ops.native import released_thread_time
 from gatekeeper_tpu.target.review import AugmentedUnstructured
 from gatekeeper_tpu.utils.rawjson import RawJSON, peek_identity
 from gatekeeper_tpu.utils.unstructured import gvk_of, split_api_version
@@ -1742,6 +1743,8 @@ class AuditManager:
             chunk_i = -1
             while True:
                 t0 = time.perf_counter()
+                c0 = time.thread_time()
+                r0 = released_thread_time()
                 try:
                     with tracing.span("audit.chunk.list",
                                       chunk=chunk_i + 1):
@@ -1761,6 +1764,9 @@ class AuditManager:
                               error=str(e))
                     item = None
                 self._perf_add("list", time.perf_counter() - t0)
+                self._perf_add("list_cpu", time.thread_time() - c0)
+                self._perf_add("list_released",
+                               released_thread_time() - r0)
                 if item is None:
                     break
                 chunk_i += 1
@@ -1780,9 +1786,11 @@ class AuditManager:
                          kept, totals, limit, counter, run=None):
         """Staged host pipeline: ``list -> flatten -> dispatch -> collect
         -> fold_render`` with one thread per stage and bounded inter-stage
-        queues (pipeline/executor.py).  Chunk K's flatten (GIL-released C
-        columnizer) overlaps chunk K-1's collect/fold, so host work hides
-        device/wire waits and vice versa; the collect stage's input bound
+        queues (pipeline/executor.py).  Chunk K's flatten (the C
+        columnizer's three phases release the GIL; its items loop, array
+        allocation, intern merge and assembly hold it) overlaps chunk
+        K-1's collect/fold, so host work hides device/wire waits and
+        vice versa; the collect stage's input bound
         is ``submit_window`` (in-flight device chunks: host memory + HBM),
         and the fold stage consumes chunks in submission order so output
         is bit-identical to the serial schedule."""
@@ -1849,9 +1857,15 @@ class AuditManager:
             Stage("collect", coll,
                   queue_cap=max(1, cfg.submit_window), max_retries=sr),
             Stage("fold_render", fold, queue_cap=cfg.pipeline_queue_cap),
-        ], source_cap=cfg.pipeline_queue_cap)
+        ], source_cap=cfg.pipeline_queue_cap,
+            released_clock=released_thread_time)
+        p0 = time.process_time()
         pr = pipe.run(self._chunk_source(constraints, kind_filter,
                                          use_router, counter))
+        # every thread of the process while the pipeline ran, the
+        # columnizer's pthreads and XLA's included: over pipe_wall, the
+        # cores the pass kept busy
+        self._perf_add("pipe_process_cpu", time.process_time() - p0)
         n_retries = sum(s.retries for s in pr.stages)
         if n_retries:
             if run is not None:
@@ -1873,17 +1887,20 @@ class AuditManager:
         # the pass's account, summed over passes.  The calling thread's:
         # list + pipe_source_stall + pipe_drain == pipe_wall.  Each
         # stage's: busy - cpu is time its thread held a chunk and did not
-        # run (GIL wait, or a call that released it), wait and stall are
+        # run (GIL wait, or a call that released it), cpu - released is
+        # the CPU it ran holding the GIL (an upper bound: numpy's and
+        # XLA's own released stretches are in it), wait and stall are
         # time it had no chunk or could not hand one on — together they
         # tell a GIL-bound pipeline from a starved one.
         self._perf_add("pipe_wall", pr.wall_s)
         self._perf_add("pipe_device_wait", device_wait)
         self._perf_add("list", pr.source_busy_s)
         self._perf_add("list_cpu", pr.source_cpu_s)
+        self._perf_add("list_released", pr.source_released_s)
         self._perf_add("pipe_source_stall", pr.source_stall_s)
         self._perf_add("pipe_drain", pr.drain_s)
         for st in pr.stages:
-            for what in ("busy", "wait", "stall", "cpu"):
+            for what in ("busy", "wait", "stall", "cpu", "released"):
                 self._perf_add(f"pipe_{st.name}_{what}",
                                getattr(st, what + "_s"))
             self.perf[f"pipe_{st.name}_workers"] = float(st.workers)

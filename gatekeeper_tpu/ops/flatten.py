@@ -23,6 +23,7 @@ from __future__ import annotations
 import json
 import os
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Iterable, Optional, Sequence
 
@@ -1125,8 +1126,10 @@ class Flattener:
         # (a chunk exceeding a target keeps its wider shape: one retrace,
         # never wrong results)
         self.width_targets = width_targets
-        # flatten sub-phase wall-clock (c_columnize / py_assemble /
-        # canon_fill / stabilize) — folded into the evaluator's perf dict
+        # flatten_raw's sub-phases as CPU seconds of the calling thread
+        # (items_cpu / columnize_cpu, columnize_released / stabilize_cpu)
+        # and the worker pool's wall-clock (worker_*) — folded into the
+        # evaluator's perf dict as fl_*
         self.perf: dict = {}
         # lane selection (--flatten-lane): 'auto' takes the raw-bytes
         # threaded columnizer when every object carries bytes and the
@@ -1407,7 +1410,11 @@ class Flattener:
         """Columnarize raw JSON documents (bytes or RawJSON) without ever
         materializing Python dicts: the threaded native module
         (native/flattenjsonmod.c) parses and columnizes with the GIL
-        released.  Semantics match ``flatten`` exactly (differential-tested
+        released in its three phases; the ``items`` loop here, the
+        module's array allocation and intern merge, and the assembly
+        below hold it; ``self.perf`` books the loop, the native call
+        and stabilize as thread CPU.
+        Semantics match ``flatten`` exactly (differential-tested
         in tests/test_native_flatten.py); falls back to parse+flatten when
         the native module is unavailable."""
         from gatekeeper_tpu.utils.rawjson import RawJSON
@@ -1422,33 +1429,43 @@ class Flattener:
         schema = self.schema
         axes = schema.axes()
         axis_index = {a: i for i, a in enumerate(axes)}
+        from gatekeeper_tpu.observability import tracing
+
+        c0 = time.thread_time()
         items = []
-        for o in raws:
-            if isinstance(o, RawJSON) and not o._loaded:
-                items.append(o.raw)
-            elif isinstance(o, (bytes, bytearray, memoryview)):
-                items.append(bytes(o))
-            else:
-                # plain dict, or a materialized RawJSON whose dict state
-                # may have diverged from .raw — serialize current state
-                items.append(json.dumps(o, separators=(",", ":")).encode())
+        with tracing.span("ops.flatten.items", n=len(raws)):
+            for o in raws:
+                if isinstance(o, RawJSON) and not o._loaded:
+                    items.append(o.raw)
+                elif isinstance(o, (bytes, bytearray, memoryview)):
+                    items.append(bytes(o))
+                else:
+                    # plain dict, or a materialized RawJSON whose dict
+                    # state may have diverged from .raw — serialize
+                    # current state
+                    items.append(
+                        json.dumps(o, separators=(",", ":")).encode())
+        self._perf_add("items_cpu", time.thread_time() - c0)
         nthreads = self.nthreads \
             or int(os.environ.get("GTPU_FLATTEN_THREADS", "0") or 0) \
             or (os.cpu_count() or 1)
         from gatekeeper_tpu.resilience.faults import fault_point
 
         fault_point("ops.flatten_raw", n=len(items), nthreads=nthreads)
-        import time as _time
-        _t0 = _time.perf_counter()
+        c0 = time.thread_time()
+        r0 = native.released_thread_time()
         self.last_workers_used = 0
         try:
-            out = None
-            if self.workers and len(items) > 1:
-                out = self._columnize_workers(items, schema, axes,
-                                              axis_index, pad_n)
-            if out is None:
-                out = self._call_columnizer(
-                    mod, items, schema, axes, axis_index, pad_n, nthreads)
+            with tracing.span("ops.flatten.native", n=len(items),
+                              nthreads=nthreads):
+                out = None
+                if self.workers and len(items) > 1:
+                    out = self._columnize_workers(items, schema, axes,
+                                                  axis_index, pad_n)
+                if out is None:
+                    out = self._call_columnizer(
+                        mod, items, schema, axes, axis_index, pad_n,
+                        nthreads)
         except ValueError:
             # the C parser rejected an item: malformed/truncated bytes,
             # or input past its stricter limits (e.g. >256 nesting).
@@ -1465,10 +1482,23 @@ class Flattener:
             finally:
                 self.lane = prev_lane
         self.lane_used = "raw+workers" if self.last_workers_used else "raw"
-        self.perf["c_columnize"] = (self.perf.get("c_columnize", 0.0)
-                                    + _time.perf_counter() - _t0)
-        _t0 = _time.perf_counter()
-        n = max(pad_n or 0, len(items))
+        # the native call: its released seconds are the three phases on
+        # this thread (all of them with one thread, the pthreads' spawn
+        # and join with more), the rest the arrays' allocation and fill
+        # and the intern merge, GIL held
+        self._perf_add("columnize_released",
+                       native.released_thread_time() - r0)
+        self._perf_add("columnize_cpu", time.thread_time() - c0)
+        with tracing.span("ops.flatten.assemble", n=len(items)):
+            return self._assemble_raw(out, raws, axes,
+                                      max(pad_n or 0, len(items)), reviews)
+
+    def _assemble_raw(self, out: dict, raws: Sequence, axes: list, n: int,
+                      reviews: Optional[Sequence[dict]]) -> ColumnBatch:
+        """The columnizer's arrays into a :class:`ColumnBatch` of ``n``
+        rows, the canon columns it left, stabilize and alias: Python and
+        numpy from end to end, the last two booked as thread CPU."""
+        schema = self.schema
         batch = ColumnBatch(n=n, scalars={}, raggeds={}, axis_counts={},
                             keysets={})
         (batch.group_sid, batch.kind_sid, batch.ns_sid, batch.name_sid,
@@ -1507,17 +1537,14 @@ class Flattener:
                 [c for c in schema.scalars
                  if c.path[:1] == ("__review__",)],
                 reviews)
-        self.perf["py_assemble"] = (self.perf.get("py_assemble", 0.0)
-                                    + _time.perf_counter() - _t0)
-        _t0 = _time.perf_counter()
         self._fill_canons(batch, raws)
-        self.perf["canon_fill"] = (self.perf.get("canon_fill", 0.0)
-                                   + _time.perf_counter() - _t0)
-        _t0 = _time.perf_counter()
+        c0 = time.thread_time()
         batch = self._apply_alias(self._stabilize(batch))
-        self.perf["stabilize"] = (self.perf.get("stabilize", 0.0)
-                                  + _time.perf_counter() - _t0)
+        self._perf_add("stabilize_cpu", time.thread_time() - c0)
         return batch
+
+    def _perf_add(self, key: str, value: float) -> None:
+        self.perf[key] = self.perf.get(key, 0.0) + value
 
     def _label_paths(self) -> list:
         return [("metadata", "labels")] + [

@@ -99,6 +99,26 @@ def load_wirepack() -> Optional[object]:
     return _load_named("gtpu_wirepack", "wirepackmod.c")
 
 
+# the modules that release the GIL, and so keep a released clock
+_RELEASERS = ("gtpu_flattenjson", "gtpu_wirepack")
+
+
+def released_thread_time() -> float:
+    """CPU seconds the calling thread has burnt with the GIL released
+    inside this repository's own C (the columnizer's three phases, the
+    wire pack), on the clock of ``time.thread_time()``: a thread's
+    ``cpu - released`` over a stretch is the CPU it ran holding the lock,
+    or inside someone else's C that released it (numpy, XLA), which only
+    that C could tell apart.  Sums the modules already loaded; it builds
+    and loads nothing, and reads 0.0 where none built."""
+    total = 0.0
+    for name in _RELEASERS:
+        mod = _mods.get(name)
+        if mod is not None:
+            total += mod.released_cpu()
+    return total
+
+
 def _build_flags() -> list:
     """The full compiler invocation prefix (compiler + every flag).
     ``GTPU_NATIVE_CFLAGS`` appends extra flags (sanitizer builds, the

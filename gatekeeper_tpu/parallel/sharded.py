@@ -807,7 +807,8 @@ class _PendingSweep:
 
 class _FlatChunk:
     """A host-flattened (not yet dispatched) sweep chunk — the hand-off
-    unit between the pipeline's flatten stage (GIL-released C columnizer)
+    unit between the pipeline's flatten stage (the C columnizer, GIL
+    released in its three phases and held around them)
     and the dispatch stage (masks + wire pack + device_put + jit call)."""
 
     __slots__ = ("by_kind", "kinds", "cols", "batch", "objects", "any_gen",
@@ -1555,7 +1556,9 @@ class ShardedEvaluator:
     def sweep_flatten(self, constraints: Sequence, objects: Sequence[dict],
                       return_bits: bool = False, source: str = "",
                       budget=None):
-        """Pipeline stage 1 (host, GIL-released C columnizer): schema
+        """Pipeline stage 1 (host; the C columnizer's three phases run
+        with the GIL released, the items loop, the arrays' allocation,
+        the intern merge and the assembly with it held): schema
         union + flatten + column pack/slim.  Returns a :class:`_FlatChunk`
         for :meth:`sweep_dispatch`, or {} when no kind is lowered (the
         caller's fallback lane handles everything)."""
@@ -1747,6 +1750,10 @@ class ShardedEvaluator:
         # constraint rows the tables answered / the per-object predicate,
         # and what the selector tables were evaluated on
         mask_counts: dict = {}
+        # the masks run no C of ours that lets the GIL go, so their
+        # thread's CPU seconds are what they held it for (an upper
+        # bound), and ``masks`` less ``masks_cpu`` their own wait for it
+        c0 = time.thread_time()
         with self._timed("masks",
                          tracing.span("device.sweep_dispatch.masks")):
             for kind in kinds:
@@ -1782,6 +1789,7 @@ class ShardedEvaluator:
             tracing.set_attribute("constraints", c_off)
             for key in ("rows_vectorized", "rows_predicate"):
                 tracing.set_attribute(key, mask_counts[key])
+        self._perf_add("masks_cpu", time.thread_time() - c0)
         # constraint rows of this dispatch, and those whose program
         # ir/lower_cel.py produced: written on every dispatch, a 0 too
         cel_kinds = self.driver._cel_kinds

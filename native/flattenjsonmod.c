@@ -27,6 +27,7 @@
 #include <pthread.h>
 #include <stdint.h>
 #include <string.h>
+#include <time.h>
 
 #define NPY_NO_DEPRECATED_API NPY_1_7_API_VERSION
 #include <numpy/arrayobject.h>
@@ -1860,6 +1861,24 @@ worker_main(void *arg)
     return NULL;
 }
 
+/* The released clock: the CPU seconds the calling thread burnt between
+ * Py_BEGIN_ALLOW_THREADS and Py_END_ALLOW_THREADS, on the clock of
+ * time.thread_time(), kept per thread.  With nthreads > 1 that is the
+ * spawning and joining of the pthreads, whose own CPU no thread of the
+ * interpreter's is charged; with one thread it is the phase itself.
+ * ops/native.released_thread_time() sums it with the wire pack's, so a
+ * stage's account can say held = cpu - released (PERF.md section 3). */
+static _Thread_local double released_s;
+
+static double
+thread_cpu_s(void)
+{
+    struct timespec ts;
+    if (clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts) != 0)
+        return 0.0;
+    return (double)ts.tv_sec + (double)ts.tv_nsec * 1e-9;
+}
+
 static int
 run_phase(Work *w, int phase)
 {
@@ -1882,6 +1901,17 @@ run_phase(Work *w, int phase)
     for (int t = 0; t < w->nthreads; t++)
         pthread_join(w->tc[t].thread, NULL);
     return 0;
+}
+
+/* one phase with the GIL released, booked on the released clock */
+static void
+run_phase_released(Work *w, int phase)
+{
+    Py_BEGIN_ALLOW_THREADS
+    double c0 = thread_cpu_s();
+    run_phase(w, phase);
+    released_s += thread_cpu_s() - c0;
+    Py_END_ALLOW_THREADS
 }
 
 /* ---------------- GIL-side glue ---------------- */
@@ -2389,9 +2419,7 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
     }
 
     /* phase 1: parse + fixed-dim columns (GIL released) */
-    Py_BEGIN_ALLOW_THREADS
-    run_phase(&w, 1);
-    Py_END_ALLOW_THREADS
+    run_phase_released(&w, 1);
     for (int t = 0; t < w.nthreads; t++) {
         if (w.tc[t].err == 1)
             goto oom;
@@ -2565,9 +2593,7 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
     }
 
     /* phase 2: variable-width columns (GIL released) */
-    Py_BEGIN_ALLOW_THREADS
-    run_phase(&w, 2);
-    Py_END_ALLOW_THREADS
+    run_phase_released(&w, 2);
     for (int t = 0; t < w.nthreads; t++)
         if (w.tc[t].err == 1)
             goto oom;
@@ -2639,9 +2665,7 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
     }
 
     /* phase 3: remap local sids -> global (GIL released) */
-    Py_BEGIN_ALLOW_THREADS
-    run_phase(&w, 3);
-    Py_END_ALLOW_THREADS
+    run_phase_released(&w, 3);
 
     work_free(&w, views, w.n_real, &spec_arena);
     return result;
@@ -2654,10 +2678,23 @@ error:
     return NULL;
 }
 
+/* released_cpu() -> CPU seconds the calling thread has spent in the three
+ * phases with the GIL released, since it first called in */
+static PyObject *
+released_cpu(PyObject *self, PyObject *noargs)
+{
+    (void)self;
+    (void)noargs;
+    return PyFloat_FromDouble(released_s);
+}
+
 static PyMethodDef jmethods[] = {
     {"flatten_json_batch", py_flatten_json_batch, METH_VARARGS,
      "Flatten a batch of raw JSON documents into columnar arrays "
      "(threaded, GIL-released)."},
+    {"released_cpu", released_cpu, METH_NOARGS,
+     "CPU seconds of the calling thread inside the columnizer's phases "
+     "with the GIL released."},
     {NULL, NULL, 0, NULL},
 };
 

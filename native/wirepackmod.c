@@ -18,8 +18,15 @@
  * block, so the destination rows of a block (a few hundred bytes an object
  * from some eighty columns) stay in cache while the columns land in them.
  *
- * One thread, no state between calls; the GIL is released once the steps
- * are parsed and taken back when the last block is written.
+ * One thread, no state between calls but the released clock; the GIL is
+ * released once the steps are parsed and taken back when the last block
+ * is written.
+ *
+ * The released clock: the CPU seconds the calling thread burnt between
+ * Py_BEGIN_ALLOW_THREADS and Py_END_ALLOW_THREADS, on the clock of
+ * time.thread_time(), kept per thread.  released_cpu() reads it, and
+ * ops/native.released_thread_time() sums it with the columnizer's, so a
+ * stage's account can say held = cpu - released (PERF.md section 3).
  */
 
 #define PY_SSIZE_T_CLEAN
@@ -27,6 +34,7 @@
 #include <stdint.h>
 #include <stdlib.h>
 #include <string.h>
+#include <time.h>
 
 enum { OP_CHECK = 0, OP_COPY, OP_BIAS, OP_NIBBLE, OP_DICT, N_OPS };
 enum { K_I4 = 0, K_I8, K_I1, K_U1, K_F4, K_OTHER };
@@ -436,6 +444,27 @@ parse_step(PyObject *item, Py_ssize_t n, step_t *s, Py_buffer *sv)
     return 0;
 }
 
+static _Thread_local double released_s;
+
+static double
+thread_cpu_s(void)
+{
+    struct timespec ts;
+    if (clock_gettime(CLOCK_THREAD_CPUTIME_ID, &ts) != 0)
+        return 0.0;
+    return (double)ts.tv_sec + (double)ts.tv_nsec * 1e-9;
+}
+
+/* released_cpu() -> CPU seconds the calling thread has spent in pack()
+ * with the GIL released, since it first called in */
+static PyObject *
+released_cpu(PyObject *self, PyObject *noargs)
+{
+    (void)self;
+    (void)noargs;
+    return PyFloat_FromDouble(released_s);
+}
+
 /* pack(steps, n) -> the indices of the steps that failed their check */
 static PyObject *
 pack(PyObject *self, PyObject *args)
@@ -460,12 +489,14 @@ pack(PyObject *self, PyObject *args)
                 < 0)
             goto done;
     Py_BEGIN_ALLOW_THREADS
+    double c0 = thread_cpu_s();
     for (Py_ssize_t r0 = 0; r0 < n; r0 += BLOCK_ROWS) {
         Py_ssize_t r1 = r0 + BLOCK_ROWS < n ? r0 + BLOCK_ROWS : n;
         for (Py_ssize_t i = 0; i < m; i++)
             if (plan[i].w > 0)
                 plan[i].fn(&plan[i], r0, r1);
     }
+    released_s += thread_cpu_s() - c0;
     Py_END_ALLOW_THREADS
     out = PyList_New(0);
     for (Py_ssize_t i = 0; out != NULL && i < m; i++) {
@@ -496,6 +527,9 @@ done:
 static PyMethodDef methods[] = {
     {"pack", pack, METH_VARARGS,
      "Run a chunk's wire-pack steps; the indices of those that failed."},
+    {"released_cpu", released_cpu, METH_NOARGS,
+     "CPU seconds of the calling thread inside pack() with the GIL "
+     "released."},
     {NULL, NULL, 0, NULL},
 };
 

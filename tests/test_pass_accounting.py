@@ -735,3 +735,397 @@ def test_a_pass_that_keeps_nothing_still_writes_both_counters(toy):
     mgr.audit()
     assert (mgr.perf["violation_peeked"], mgr.perf["violation_loaded"]) \
         == (0, 0)
+
+
+# --- the GIL account: held = cpu - released (PR 36) -------------------------
+
+def _thread_clock_step() -> float:
+    """The step ``time.thread_time()`` really advances by.  What the
+    kernel calls the clock's resolution is a nanosecond everywhere, but
+    where it accounts CPU by the scheduler's tick (the chip's host: 10
+    ms, PERF.md section 6) the clock stands still between ticks: a
+    sub-tick piece of work reads 0.0 and a thread that slept may be
+    charged a whole tick."""
+    steps = []
+    last = time.thread_time()
+    deadline = time.perf_counter() + 0.25
+    while len(steps) < 5 and time.perf_counter() < deadline:
+        now = time.thread_time()
+        if now != last:
+            steps.append(now - last)
+            last = now
+    return max([time.get_clock_info("thread_time").resolution]
+               + ([min(steps)] if steps else []))
+
+
+CLOCK_STEP = _thread_clock_step()
+# thread_time, perf_counter and the C's clock are read a few instructions
+# apart, an item; and each reading of a CPU clock is a step off at most
+TICK = max(2e-3, 2 * CLOCK_STEP)
+
+RELEASED_KEYS_PIPELINED = (
+    ["list_released", "pipe_process_cpu"]
+    + [f"pipe_{s}_released"
+       for s in ("flatten", "dispatch", "collect", "fold_render")])
+EVALUATOR_KEYS = ["masks_cpu"]
+FLATTEN_RAW_KEYS = ["fl_items_cpu", "fl_columnize_cpu",
+                    "fl_columnize_released", "fl_stabilize_cpu"]
+# pairs no metric would read (the pipe_<stage>_* pairs carry them): not
+# written
+UNREAD = ["flatten_cpu", "flatten_released", "masks_released",
+          "wire_pack_cpu", "wire_pack_released", "dispatch_cpu",
+          "dispatch_released", "fl_assemble_cpu", "fl_canon_fill_cpu"]
+GONE = ["fl_c_columnize", "fl_py_assemble", "fl_canon_fill", "fl_stabilize"]
+
+
+def _raw_mgr(toy, pipeline, n=40):
+    from gatekeeper_tpu.utils.rawjson import as_raw
+
+    client, evaluator = toy
+    objects = _objects(n)
+    return AuditManager(
+        client, lister=lambda: (as_raw(o) for o in objects),
+        config=AuditConfig(chunk_size=16, exact_totals=False,
+                           pipeline=pipeline),
+        evaluator=evaluator)
+
+
+def _ordered(released, cpu, busy, what):
+    assert -TICK <= released <= cpu + TICK, what
+    assert cpu <= busy + TICK, what
+
+
+def test_released_thread_time_sums_the_loaded_modules_and_loads_none(
+        monkeypatch):
+    monkeypatch.setattr(native, "_mods", {})
+    monkeypatch.setattr(native, "_tried", set())
+    assert native.released_thread_time() == 0.0
+    assert native._mods == {} and native._tried == set()  # built nothing
+
+    class Fake:
+        def __init__(self, s):
+            self.s = s
+
+        def released_cpu(self):
+            return self.s
+
+    monkeypatch.setattr(native, "_mods", {
+        "gtpu_flattenjson": Fake(0.25), "gtpu_wirepack": Fake(0.5),
+        "gtpu_listroute": Fake(8.0),  # releases nothing: not asked
+        "gtpu_flatten": None})
+    assert native.released_thread_time() == 0.75
+    monkeypatch.setattr(native, "_mods", {"gtpu_wirepack": None})
+    assert native.released_thread_time() == 0.0
+
+
+def test_the_released_clock_is_the_calling_threads_own():
+    mod = native.load_wirepack()
+    if mod is None:
+        pytest.skip("native/wirepackmod.c did not build")
+    seen = {}
+
+    def other():
+        seen["fresh"] = mod.released_cpu()
+
+    import numpy as np
+
+    rng = np.random.default_rng(36)
+    n = 1 << 16
+    cols = {"a": {"sid": rng.integers(-1, 40000, (n, 8)).astype(np.int32)}}
+    stats: dict = {}
+    sharded.col_stats_update(stats, cols)
+    sharded.merge_pad_stats(stats)
+    before = mod.released_cpu()
+    # one pack of 2 MB is under a millisecond: on a clock that steps by
+    # the scheduler's tick, pack until a few steps have gone by
+    deadline = time.perf_counter() + 20.0
+    while True:
+        counts: dict = {}
+        sharded.pack_transfer_cols(cols, n, stats=stats, counts=counts)
+        assert counts["fused"] == 1
+        if mod.released_cpu() - before >= 3 * CLOCK_STEP \
+                or time.perf_counter() > deadline:
+            break
+    assert mod.released_cpu() > before
+    t = threading.Thread(target=other)
+    t.start()
+    t.join()
+    assert seen["fresh"] == 0.0  # a thread that never called in
+
+
+def test_a_spinning_stage_holds_the_lock_for_all_its_cpu():
+    run = StagedPipeline([
+        Stage("spin", _spin(0.01), queue_cap=2),
+        Stage("sleep", _sleepy(0.01), queue_cap=2),
+        Stage("sink", lambda x: None),
+    ]).run(range(12))
+    spin = run.stage("spin")
+    assert spin.released_s == 0.0 and run.source_released_s == 0.0
+    # held = cpu - released: all of what it ran, and it ran what it was
+    # busy for (twelve items, each a step of the clock off at most)
+    assert spin.cpu_s - spin.released_s == pytest.approx(spin.cpu_s,
+                                                         rel=0.1)
+    assert spin.cpu_s > 0.5 * spin.busy_s - TICK * spin.items
+    for st in run.stages:
+        _ordered(st.released_s, st.cpu_s, st.busy_s + TICK * st.items,
+                 st.name)
+
+
+def test_the_released_clock_is_the_callers_and_read_on_each_thread():
+    # the executor knows no native code: whoever builds the pipeline
+    # hands it the clock, and each thread books what the clock advanced
+    # by around its own items; released may pass cpu, nothing is clipped
+    local = threading.local()
+
+    def clock():
+        local.s = getattr(local, "s", 0.0) + 0.25
+        return local.s
+
+    run = StagedPipeline([
+        Stage("a", lambda x: x, queue_cap=2),
+        Stage("b", lambda x: None, queue_cap=2),
+    ], released_clock=clock).run(range(4))
+    for st in run.stages:
+        assert st.released_s == pytest.approx(0.25 * 4)
+        assert st.cpu_s - st.released_s < 0.0
+    # five next() calls, the last the StopIteration
+    assert run.source_released_s == pytest.approx(0.25 * 5)
+    assert StagedPipeline([Stage("a", lambda x: None)]).run(
+        range(4)).stage("a").released_s == 0.0
+
+
+def test_a_stage_that_packs_books_its_released_cpu(monkeypatch):
+    """A stage whose fn is the wire pack of a large chunk: the native
+    call's CPU is booked released on the worker thread that ran it, and
+    with the module unloaded the stage reads 0.0 and packs the same
+    bytes."""
+    import numpy as np
+
+    rng = np.random.default_rng(36)
+    n = 1 << 16
+    cols = {"a": {"sid": rng.integers(-1, 40000, (n, 8)).astype(np.int32),
+                  "kind": rng.integers(-1, 7, (n, 8)).astype(np.int8)},
+            "b": {"count": rng.integers(0, 200, n).astype(np.int32)}}
+    stats: dict = {}
+    sharded.col_stats_update(stats, cols)
+    sharded.merge_pad_stats(stats)
+    packed = []
+
+    def pack(_):
+        counts: dict = {}
+        packed.append((sharded.pack_transfer_cols(cols, n, stats=stats,
+                                                  counts=counts), counts))
+
+    def one_run(items=3):
+        return StagedPipeline(
+            [Stage("pack", pack)],
+            released_clock=native.released_thread_time).run(range(items))
+
+    have = native.load_wirepack() is not None
+    run = one_run().stage("pack")
+    _ordered(run.released_s, run.cpu_s, run.busy_s + TICK * run.items,
+             "pack")
+    # three packs of a few milliseconds: a clock that steps by the
+    # scheduler's tick may stand still through them, so pack for ticks
+    items = 3
+    while have and run.released_s == 0.0 and items < 3000:
+        items *= 10
+        run = one_run(items).stage("pack")
+    assert (run.released_s > 0.0) == have
+    assert all(c["fused"] > 0 for _, c in packed) == have
+    with_module = packed[:3]
+    del packed[:]
+    monkeypatch.setattr(native, "load_wirepack", lambda: None)
+    run = one_run().stage("pack")
+    assert run.released_s == 0.0
+    assert [c["fused"] for _, c in packed] == [0, 0, 0]
+    (bufs, layout), _ = with_module[0]
+    for (bufs2, layout2), _ in with_module[1:] + packed:
+        assert layout2 == layout and list(bufs2) == list(bufs)
+        assert all(bufs2[k].tobytes() == bufs[k].tobytes() for k in bufs)
+
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+def test_released_is_at_most_cpu_is_at_most_busy(toy, pipeline):
+    _client, evaluator = toy
+    mgr = _raw_mgr(toy, pipeline)
+    mgr.audit()  # whatever compiles, compiles here
+    mgr.perf = {}
+    evaluator.perf_reset()
+    mgr.audit()
+    perf, eperf = mgr.perf, evaluator.perf
+    chunks = 3
+    _ordered(perf["list_released"], perf["list_cpu"],
+             perf["list"] + TICK * chunks, "list")
+    if pipeline == "on":
+        for s in ("flatten", "dispatch", "collect", "fold_render"):
+            _ordered(perf[f"pipe_{s}_released"], perf[f"pipe_{s}_cpu"],
+                     perf[f"pipe_{s}_busy"] + TICK * chunks, s)
+        # every thread of the process: at least the calling thread's own
+        assert perf["pipe_process_cpu"] >= perf["list_cpu"] - TICK
+        cores = perf["pipe_process_cpu"] / perf["pipe_wall"]
+        assert 0.0 < cores
+        # the only C of ours the flatten stage calls is the columnizer
+        assert perf["pipe_flatten_released"] == pytest.approx(
+            eperf["fl_columnize_released"], abs=TICK)
+    else:
+        assert "pipe_process_cpu" not in perf
+    # the masks call no C of ours that lets the lock go: all held
+    _ordered(0.0, eperf["masks_cpu"], eperf["masks"] + TICK * chunks,
+             "masks")
+    # the flattener's table: thread CPU, inside the flatten's seconds
+    _ordered(eperf["fl_columnize_released"], eperf["fl_columnize_cpu"],
+             eperf["flatten"] + TICK * chunks, "columnize")
+    parts = sum(eperf[k] for k in FLATTEN_RAW_KEYS
+                if k != "fl_columnize_released")
+    assert parts <= eperf["flatten"] + 3 * TICK * chunks
+
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+def test_the_account_keys_are_written_on_every_pass(toy, pipeline):
+    """Every key of the GIL account is written by a pass whether or not
+    anything was released (a 0.0 too), with a tracer installed or not:
+    the same keys, the same counts."""
+    _client, evaluator = toy
+    mgr = _raw_mgr(toy, pipeline)
+    mgr.audit()
+    seen = []
+    for traced in (False, True):
+        mgr.perf = {}
+        evaluator.perf_reset()
+        if traced:
+            with tracing.activate(tracing.Tracer(seed=0)):
+                mgr.audit()
+        else:
+            mgr.audit()
+        perf, eperf = mgr.perf, evaluator.perf
+        want = ["list_cpu", "list_released"] + (
+            RELEASED_KEYS_PIPELINED if pipeline == "on" else [])
+        for key in want:
+            assert isinstance(perf.get(key), float), key
+        for key in EVALUATOR_KEYS + FLATTEN_RAW_KEYS:
+            assert isinstance(eperf.get(key), float), key
+        assert not [k for k in GONE + UNREAD if k in eperf]
+        seen.append((sorted(perf), sorted(eperf),
+                     {k: perf[k] for k in perf if k.startswith(
+                         ("list_fast", "list_slow", "n_renders",
+                          "violation_", "render_memo_"))},
+                     {k: eperf[k] for k in eperf if k.startswith(
+                         ("wire_cols_", "mask_rows_", "sweep_rows",
+                          "wire_bytes", "d2h_bytes"))}))
+    assert seen[0] == seen[1]
+
+
+def test_dict_objects_write_the_stage_keys_and_no_flatten_raw_table(toy):
+    # the dict lane never enters flatten_raw: its table is absent, the
+    # stage's own cpu and released are there, a 0.0
+    _client, evaluator = toy
+    evaluator.perf_reset()
+    mgr = _toy_mgr(toy, "on")
+    mgr.audit()
+    assert mgr.perf["pipe_flatten_released"] == 0.0
+    assert isinstance(mgr.perf["pipe_flatten_cpu"], float)
+    assert isinstance(evaluator.perf["masks_cpu"], float)
+    assert not [k for k in FLATTEN_RAW_KEYS + GONE + UNREAD
+                if k in evaluator.perf]
+
+
+def test_with_the_native_modules_unloaded_nothing_is_released(
+        toy, monkeypatch):
+    client, plain = toy
+    warmed = ShardedEvaluator(plain.driver, make_mesh(), violations_limit=5)
+    warmed.warm_pass(client.constraints(), _objects(40), 16)
+    mgr = _raw_mgr((client, warmed), "on")
+    mgr.audit()
+    mgr.perf = {}
+    warmed.perf_reset()
+    with_modules = mgr.audit()
+    fused = warmed.perf["wire_cols_fused"]
+    assert (fused > 0) == (native.load_wirepack() is not None)
+    monkeypatch.setattr(native, "load_wirepack", lambda: None)
+    monkeypatch.setattr(native, "load_json", lambda: None)
+    mgr.perf = {}
+    warmed.perf_reset()
+    without = mgr.audit()
+    released = {k: v for k, v in list(mgr.perf.items())
+                + list(warmed.perf.items()) if k.endswith("_released")}
+    # (without the columnizer flatten_raw is not entered: no fl_* table)
+    assert set(released) >= {"list_released"} | {
+        f"pipe_{s}_released"
+        for s in ("flatten", "dispatch", "collect", "fold_render")}
+    assert set(released.values()) == {0.0}
+    assert warmed.perf["wire_cols_fused"] == 0
+    assert _kept(without) == _kept(with_modules)
+    assert sum(_kept(without)[0].values()) > 0
+
+
+def _spans_by_name(tracer):
+    by_name: dict = {}
+    by_id: dict = {}
+    for tr in tracer.traces():
+        for s in tr["spans"]:
+            by_name.setdefault(s["name"], []).append(s)
+            by_id[s["span_id"]] = s
+    return by_name, by_id
+
+
+def _inside(child, parent):
+    # float seconds near 1.8e9 resolve to ~2.4e-7
+    assert child["start_ts"] >= parent["start_ts"] - 1e-6
+    assert child["start_ts"] + child["duration_s"] <= \
+        parent["start_ts"] + parent["duration_s"] + 1e-6
+
+
+def test_the_drain_is_a_span_under_the_ambient_span():
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer), tracing.span("root") as root:
+        run = StagedPipeline([
+            Stage("sink", lambda x: (time.sleep(0.005), None)[1],
+                  queue_cap=64),
+        ]).run(range(10))
+    by_name, by_id = _spans_by_name(tracer)
+    (drain,) = by_name["pipeline.drain"]
+    assert drain["parent_id"] == root.span_id
+    assert drain["thread_id"] == threading.get_ident()
+    assert drain["attributes"]["chunks"] == 10
+    _inside(drain, by_id[root.span_id])
+    # it opens when the last next() has been handed on and is the drain
+    assert drain["start_ts"] >= max(
+        s["start_ts"] + s["duration_s"]
+        for s in by_name["pipeline.source"]) - 1e-6
+    assert drain["duration_s"] <= run.drain_s + 1e-6
+    assert drain["duration_s"] > 0.8 * run.drain_s
+
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+def test_the_flatteners_parts_are_spans_under_columnize(toy, pipeline):
+    mgr = _raw_mgr(toy, pipeline)
+    mgr.audit()
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        mgr.audit()
+    by_name, by_id = _spans_by_name(tracer)
+    columnize = by_name["ops.flatten.columnize"]
+    assert len(columnize) == 3  # one a chunk
+    assert {s["attributes"]["lane_used"] for s in columnize} == {"raw"}
+    for part in ("items", "native", "assemble"):
+        spans = by_name[f"ops.flatten.{part}"]
+        assert len(spans) == 3, part
+        assert sorted(s["parent_id"] for s in spans) == \
+            sorted(s["span_id"] for s in columnize)
+        for s in spans:
+            _inside(s, by_id[s["parent_id"]])
+            assert s["thread_id"] == by_id[s["parent_id"]]["thread_id"]
+    # in order inside their chunk's columnize span
+    for c in columnize:
+        mine = sorted((s for part in ("items", "native", "assemble")
+                       for s in by_name[f"ops.flatten.{part}"]
+                       if s["parent_id"] == c["span_id"]),
+                      key=lambda s: s["start_ts"])
+        assert [s["name"].rsplit(".", 1)[1] for s in mine] == \
+            ["items", "native", "assemble"]
+    if pipeline == "on":
+        (root,) = by_name["audit.sweep"]
+        (drain,) = by_name["pipeline.drain"]
+        assert drain["parent_id"] == root["span_id"]
+        _inside(drain, root)
