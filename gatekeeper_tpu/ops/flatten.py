@@ -1129,8 +1129,12 @@ class Flattener:
         # flatten_raw's sub-phases as CPU seconds of the calling thread
         # (items_cpu / columnize_cpu, columnize_released / stabilize_cpu)
         # and the worker pool's wall-clock (worker_*) — folded into the
-        # evaluator's perf dict as fl_*
-        self.perf: dict = {}
+        # evaluator's perf dict as fl_*; and three counts every lane
+        # writes, a 0 too: the prefill bytes the native call's workers
+        # wrote with the GIL released / those it wrote with it held, and
+        # the batches _stabilize had to re-pad
+        self.perf: dict = {"fill_released_bytes": 0, "fill_held_bytes": 0,
+                           "stabilize_repads": 0}
         # lane selection (--flatten-lane): 'auto' takes the raw-bytes
         # threaded columnizer when every object carries bytes and the
         # native module built, else the C dict walker, else Python;
@@ -1175,22 +1179,36 @@ class Flattener:
                 batch.parent_idx[orig] = batch.parent_idx[new]
         return batch
 
-    def _axis_target(self, axis: Axis) -> Optional[int]:
+    def _target(self, key: tuple) -> Optional[int]:
+        """The bucketed corpus width under ``key`` of ``width_targets``
+        (None: no target)."""
         if self.width_targets is None:
             return None
-        t = self.width_targets.get(("ax", axis.key()))
+        t = self.width_targets.get(key)
         return None if t is None else round_up(t, self.bucket)
 
+    def _axis_target(self, axis: Axis) -> Optional[int]:
+        return self._target(("ax", axis.key()))
+
     def _stabilize(self, batch: ColumnBatch) -> ColumnBatch:
-        """Pad ragged-family widths up to the corpus-stable targets."""
+        """Pad ragged-family widths up to the corpus-stable targets: a
+        second array and a copy for every column that arrives narrower.
+        The raw lane's columnizer takes the targets as floors and arrives
+        at them, so this finds nothing to pad there; the dict lane, the
+        worker-pool merge and the Python flattener size a column by the
+        batch's own widest row.  ``perf["stabilize_repads"]`` counts the
+        batches that needed it."""
         if self.width_targets is None:
             return batch
+        padded = False
 
         def pad2(a, m, fill):
+            nonlocal padded
             if a.shape[1] >= m:
                 return a
             out = np.full((a.shape[0], m) + a.shape[2:], fill, a.dtype)
             out[:, : a.shape[1]] = a
+            padded = True
             return out
 
         for spec, col in batch.raggeds.items():
@@ -1210,13 +1228,13 @@ class Flattener:
                     pad2(col.idx, m, -1))
         for spec, col in batch.ragged_keysets.items():
             m = self._axis_target(spec.axis)
-            lt = self.width_targets.get(("rks_l", spec))
-            l = None if lt is None else round_up(lt, self.bucket)
+            l = self._target(("rks_l", spec))
             sid, cnt = col.sid, col.count
             if l is not None and sid.shape[2] < l:
                 new = np.full(sid.shape[:2] + (l,), -1, sid.dtype)
                 new[:, :, : sid.shape[2]] = sid
                 sid = new
+                padded = True
             if m is not None and sid.shape[1] < m:
                 sid = pad2(sid, m, -1)
                 cnt = pad2(cnt[:, :, None], m, 0)[:, :, 0] \
@@ -1228,11 +1246,12 @@ class Flattener:
                     cnt = nc
                 batch.ragged_keysets[spec] = RaggedKeySetColumn(sid, cnt)
         for spec, col in batch.keysets.items():
-            lt = self.width_targets.get(("ks_l", spec))
-            l = None if lt is None else round_up(lt, self.bucket)
+            l = self._target(("ks_l", spec))
             if l is not None and col.sid.shape[1] < l:
                 batch.keysets[spec] = KeySetColumn(
                     pad2(col.sid, l, -1), col.count)
+        if padded:
+            self.perf["stabilize_repads"] += 1
         return batch
 
     def record_widths(self, batch: ColumnBatch, targets: dict) -> None:
@@ -1409,11 +1428,13 @@ class Flattener:
                     reviews: Optional[Sequence[dict]] = None) -> ColumnBatch:
         """Columnarize raw JSON documents (bytes or RawJSON) without ever
         materializing Python dicts: the threaded native module
-        (native/flattenjsonmod.c) parses and columnizes with the GIL
-        released in its three phases; the ``items`` loop here, the
-        module's array allocation and intern merge, and the assembly
-        below hold it; ``self.perf`` books the loop, the native call
-        and stabilize as thread CPU.
+        (native/flattenjsonmod.c) parses, prefills and columnizes with
+        the GIL released in its three phases, into arrays made once at
+        the corpus's widths (``width_targets`` as floors); the ``items``
+        loop here, the module's ``PyArray_EMPTY`` calls and intern merge,
+        and the assembly below hold it; ``self.perf`` books the loop, the
+        native call and stabilize as thread CPU, and the prefill bytes
+        by who wrote them.
         Semantics match ``flatten`` exactly (differential-tested
         in tests/test_native_flatten.py); falls back to parse+flatten when
         the native module is unavailable."""
@@ -1457,7 +1478,7 @@ class Flattener:
         self.last_workers_used = 0
         try:
             with tracing.span("ops.flatten.native", n=len(items),
-                              nthreads=nthreads):
+                              nthreads=nthreads) as sp:
                 out = None
                 if self.workers and len(items) > 1:
                     out = self._columnize_workers(items, schema, axes,
@@ -1466,6 +1487,11 @@ class Flattener:
                     out = self._call_columnizer(
                         mod, items, schema, axes, axis_index, pad_n,
                         nthreads)
+                # (the pool's merge has no such pair: its children
+                # prefill in their own processes)
+                fill_released, fill_held = out.get("fill_bytes", (0, 0))
+                sp.set_attribute("fill_released_bytes", fill_released)
+                sp.set_attribute("fill_held_bytes", fill_held)
         except ValueError:
             # the C parser rejected an item: malformed/truncated bytes,
             # or input past its stricter limits (e.g. >256 nesting).
@@ -1483,12 +1509,14 @@ class Flattener:
                 self.lane = prev_lane
         self.lane_used = "raw+workers" if self.last_workers_used else "raw"
         # the native call: its released seconds are the three phases on
-        # this thread (all of them with one thread, the pthreads' spawn
-        # and join with more), the rest the arrays' allocation and fill
-        # and the intern merge, GIL held
+        # this thread (all of them, prefill included, with one thread;
+        # the pthreads' spawn and join with more), the rest the arrays'
+        # allocation and the intern merge, GIL held
         self._perf_add("columnize_released",
                        native.released_thread_time() - r0)
         self._perf_add("columnize_cpu", time.thread_time() - c0)
+        self.perf["fill_released_bytes"] += fill_released
+        self.perf["fill_held_bytes"] += fill_held
         with tracing.span("ops.flatten.assemble", n=len(items)):
             return self._assemble_raw(out, raws, axes,
                                       max(pad_n or 0, len(items)), reviews)
@@ -1579,6 +1607,18 @@ class Flattener:
              for cc in getattr(schema, "canons", [])],
         )
 
+    def _width_floors(self, schema, axes) -> Optional[tuple]:
+        """``width_targets`` as the columnizer takes them: the least
+        width of each axis, and the least ``l`` of each keyset and ragged
+        keyset, in the specs' order, the very numbers ``_stabilize`` pads
+        to (0: no target), so the arrays come out at its shapes."""
+        if self.width_targets is None:
+            return None
+        return ([self._axis_target(a) or 0 for a in axes],
+                [self._target(("ks_l", k)) or 0 for k in schema.keysets],
+                [self._target(("rks_l", rk)) or 0
+                 for rk in schema.ragged_keysets])
+
     def _call_columnizer(self, mod, items, schema, axes, axis_index,
                          pad_n, nthreads):
         """The raw native call, specs marshalled from the exec schema."""
@@ -1590,6 +1630,7 @@ class Flattener:
             int(pad_n or len(items)),
             self.bucket,  # ragged bucket, matches round_up()
             nthreads,
+            self._width_floors(schema, axes),
         )
 
     def _columnize_workers(self, items, schema, axes, axis_index, pad_n):

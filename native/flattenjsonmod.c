@@ -15,6 +15,12 @@
  *      deterministic (thread, first-seen) order -> local->global maps.
  *   3. (no GIL, threads) sid arrays remap in-place per row range.
  *
+ * The output arrays are made under the GIL (PyArray_EMPTY: no page of
+ * them touched there) at their final width, which is the batch's own
+ * maximum or the caller's floor, whichever is wider; their prefill (0,
+ * and -1 or -2 for the sid and index arrays) is written by the workers,
+ * each over its own rows, inside phases 1 and 2 (FillList).
+ *
  * Semantics mirror ops/flatten.py exactly (differential-tested in
  * tests/test_native_flatten.py) -- the Python flattener remains the
  * oracle.  Reference anchor for the loop this replaces: the audit
@@ -1319,8 +1325,25 @@ typedef struct {
     Py_ssize_t *max_keyset; /* per keyset */
     Py_ssize_t *max_rk_l;   /* per rk spec */
     int32_t *remap;         /* local id -> global id */
+    size_t filled;          /* bytes of prefill this thread wrote */
     pthread_t thread;
 } ThreadCtx;
+
+/* An output array: made PyArray_EMPTY under the GIL and entered here; the
+ * workers write the prefill of their own rows (and the last one the
+ * padding rows') inside the released phase, before they write a cell of
+ * them. */
+typedef struct {
+    char *data;
+    size_t row_bytes; /* bytes of one row of axis 0 */
+    int fill;         /* 0 and -1: that byte throughout; else an int32 */
+} FillEnt;
+
+typedef struct {
+    FillEnt *ents;
+    int n, cap;
+    size_t held; /* prefill bytes written under the GIL instead */
+} FillList;
 
 typedef struct Work {
     const char **bufs;
@@ -1352,7 +1375,14 @@ typedef struct Work {
     CCanonSpec *canons;
     int n_canons;
     long bucket;
-    Row *rows;
+    /* least widths the caller asks for (0: none): per axis, per keyset,
+     * per ragged keyset's l */
+    Py_ssize_t *ax_floor, *ks_floor, *rk_floor;
+    Row *rows;          /* malloc'd: each worker zeroes and wires its own */
+    AxisItems *ax_blk;  /* rows' sub-arrays, one block a kind */
+    KeysetRow *ks_blk;
+    RKRow *rk_blk;
+    FillList fill1, fill2; /* prefills the workers owe, by phase */
     /* phase-1 outputs */
     int32_t *gid, *kid, *nsid, *nmid;
     int32_t **c_sid; /* canon columns [N], -2 = idiom yields nothing */
@@ -1840,16 +1870,86 @@ phase3_remap(ThreadCtx *t)
                     r1 * w->rk_m[s] * w->rk_l[s]);
 }
 
+static void
+fill_bytes(char *p, size_t nbytes, int fill)
+{
+    if (fill == 0 || fill == -1) {
+        /* int32 -1 is all-ones bytes: one vectorized memset instead of
+         * an element loop (the sid arrays are the bulk of the output) */
+        memset(p, fill, nbytes);
+    } else {
+        int32_t *data = (int32_t *)p;
+        size_t total = nbytes / sizeof(int32_t);
+        for (size_t i = 0; i < total; i++)
+            data[i] = fill;
+    }
+}
+
+/* rows [r0, r1) of every listed array: row-major, so one contiguous
+ * stretch an array */
+static size_t
+fill_rows(const FillList *fl, Py_ssize_t r0, Py_ssize_t r1)
+{
+    size_t total = 0;
+    if (r1 <= r0)
+        return 0;
+    for (int i = 0; i < fl->n; i++) {
+        const FillEnt *e = &fl->ents[i];
+        size_t nbytes = (size_t)(r1 - r0) * e->row_bytes;
+        fill_bytes(e->data + (size_t)r0 * e->row_bytes, nbytes, e->fill);
+        total += nbytes;
+    }
+    return total;
+}
+
+/* a worker's share of a phase's prefill: its own rows, and for the last
+ * thread (whatever its own range holds) the padding rows past n_real.
+ * The rows are partitioned by thread, so no thread waits for another. */
+static void
+worker_fill(ThreadCtx *t, const FillList *fl)
+{
+    Work *w = t->w;
+    t->filled += fill_rows(fl, t->row0, t->row1);
+    if (t->tid == w->nthreads - 1)
+        t->filled += fill_rows(fl, w->n_real, w->n_pad);
+}
+
+/* the scratch rows of a worker's own range: zeroed and pointed at their
+ * slices of the three blocks (first touched here, not under the GIL) */
+static void
+worker_rows(ThreadCtx *t)
+{
+    Work *w = t->w;
+    size_t r0 = (size_t)t->row0, n = (size_t)(t->row1 - t->row0);
+    size_t na = (size_t)(w->n_axes ? w->n_axes : 1);
+    size_t nk = (size_t)(w->n_keysets ? w->n_keysets : 1);
+    size_t nr = (size_t)(w->n_rks ? w->n_rks : 1);
+    if (n == 0)
+        return;
+    memset(w->ax_blk + r0 * na, 0, n * na * sizeof(AxisItems));
+    memset(w->ks_blk + r0 * nk, 0, n * nk * sizeof(KeysetRow));
+    memset(w->rk_blk + r0 * nr, 0, n * nr * sizeof(RKRow));
+    for (size_t i = r0; i < r0 + n; i++) {
+        w->rows[i].root = NULL;
+        w->rows[i].axes = w->ax_blk + i * na;
+        w->rows[i].keysets = w->ks_blk + i * nk;
+        w->rows[i].rks = w->rk_blk + i * nr;
+    }
+}
+
 static void *
 worker_main(void *arg)
 {
     ThreadCtx *t = (ThreadCtx *)arg;
     Work *w = t->w;
     if (w->phase == 1) {
+        worker_rows(t);
+        worker_fill(t, &w->fill1);
         for (Py_ssize_t i = t->row0; i < t->row1; i++)
             if (phase1_row(t, i) < 0)
                 break;
     } else if (w->phase == 2) {
+        worker_fill(t, &w->fill2);
         if (!t->err) {
             for (Py_ssize_t i = t->row0; i < t->row1; i++)
                 if (phase2_row(t, i) < 0)
@@ -1916,25 +2016,35 @@ run_phase_released(Work *w, int phase)
 
 /* ---------------- GIL-side glue ---------------- */
 
+/* One output array: PyArray_EMPTY, its prefill left to the workers through
+ * ``fl``.  (Not PyArray_ZEROS for the zero-filled ones: numpy lets the GIL
+ * go around every calloc of a kilobyte or more, so beside a thread that
+ * wants the lock each such array costs this one a switch interval, five
+ * milliseconds, to get it back.)  Only where the list cannot grow is the
+ * prefill written here, GIL held, and counted in ``held``. */
 static PyArrayObject *
-new_arr(int nd, npy_intp *dims, int typenum, int fill)
+new_arr(FillList *fl, int nd, npy_intp *dims, int typenum, int fill)
 {
-    PyArrayObject *a;
-    if (fill == 0)
-        return (PyArrayObject *)PyArray_ZEROS(nd, dims, typenum, 0);
-    a = (PyArrayObject *)PyArray_EMPTY(nd, dims, typenum, 0);
+    PyArrayObject *a = (PyArrayObject *)PyArray_EMPTY(nd, dims, typenum, 0);
     if (a == NULL)
         return NULL;
-    if (fill == -1) {
-        /* int32 -1 is all-ones bytes: one vectorized memset instead of
-         * an element loop (the sid arrays are the bulk of the output) */
-        memset(PyArray_DATA(a), 0xFF, (size_t)PyArray_NBYTES(a));
-    } else {
-        int32_t *data = (int32_t *)PyArray_DATA(a);
-        npy_intp total = PyArray_SIZE(a);
-        for (npy_intp i = 0; i < total; i++)
-            data[i] = fill;
+    if (fl->n == fl->cap) {
+        int cap = fl->cap ? fl->cap * 2 : 64;
+        FillEnt *ents = (FillEnt *)realloc(fl->ents,
+                                           (size_t)cap * sizeof(FillEnt));
+        if (ents == NULL) {
+            fill_bytes((char *)PyArray_DATA(a), (size_t)PyArray_NBYTES(a),
+                       fill);
+            fl->held += (size_t)PyArray_NBYTES(a);
+            return a;
+        }
+        fl->ents = ents;
+        fl->cap = cap;
     }
+    FillEnt *e = &fl->ents[fl->n++];
+    e->data = (char *)PyArray_DATA(a);
+    e->row_bytes = dims[0] ? (size_t)PyArray_NBYTES(a) / (size_t)dims[0] : 0;
+    e->fill = fill;
     return a;
 }
 
@@ -1986,6 +2096,38 @@ caxis_conv(PyObject *segments, CAxis *out, Arena *ar)
     return 0;
 }
 
+/* item ``which`` of the floors triple into ``out[n]``; a TypeError (never
+ * a ValueError, which the caller reads as a document the parser refused)
+ * where the shape is not the specs' */
+static int
+floors_conv(PyObject *floors, Py_ssize_t which, Py_ssize_t *out, int n)
+{
+    PyObject *seq = NULL;
+    if (PyTuple_Check(floors) && PyTuple_GET_SIZE(floors) == 3)
+        seq = PySequence_Fast(PyTuple_GET_ITEM(floors, which),
+                              "floors: three sequences of ints");
+    else
+        PyErr_SetString(PyExc_TypeError,
+                        "floors: None or a tuple of three sequences");
+    if (seq == NULL)
+        return -1;
+    if (PySequence_Fast_GET_SIZE(seq) != n) {
+        PyErr_SetString(PyExc_TypeError,
+                        "floors: one width a spec, in the specs' order");
+        Py_DECREF(seq);
+        return -1;
+    }
+    for (int i = 0; i < n; i++) {
+        out[i] = PyLong_AsSsize_t(PySequence_Fast_GET_ITEM(seq, i));
+        if (out[i] == -1 && PyErr_Occurred()) {
+            Py_DECREF(seq);
+            return -1;
+        }
+    }
+    Py_DECREF(seq);
+    return 0;
+}
+
 static void
 work_free(Work *w, Py_buffer *views, Py_ssize_t n_views, Arena *spec_arena)
 {
@@ -2012,16 +2154,13 @@ work_free(Work *w, Py_buffer *views, Py_ssize_t n_views, Arena *spec_arena)
         }
         free(w->tc);
     }
-    if (w->rows) {
-        free(w->rows[0].axes);    /* block-allocated */
-        free(w->rows[0].keysets);
-        free(w->rows[0].rks);
-        free(w->rows);
-    }
+    free(w->rows); free(w->ax_blk); free(w->ks_blk); free(w->rk_blk);
     free(w->scalars); free(w->scalar_review);
     free(w->axes); free(w->raggeds); free(w->keysets); free(w->mk_axes);
     free(w->parents); free(w->rks); free(w->canons); free(w->c_sid);
     free(w->sc_self);
+    free(w->ax_floor); free(w->ks_floor); free(w->rk_floor);
+    free(w->fill1.ents); free(w->fill2.ents);
     free(w->ax_trie); free(w->ax_self); free(w->ax_nself); free(w->ax_m);
     free(w->s_kind); free(w->s_num); free(w->s_sid);
     free(w->a_count);
@@ -2041,8 +2180,8 @@ work_free(Work *w, Py_buffer *views, Py_ssize_t n_views, Arena *spec_arena)
 }
 
 /* flatten_json_batch(items, scalars, axes, raggeds, keysets, map_key_axes,
- *                    parent_specs, rk_specs, to_id, to_str,
- *                    pad_n, bucket, nthreads) -> dict
+ *                    parent_specs, rk_specs, canons, to_id, to_str,
+ *                    pad_n, bucket, nthreads[, floors]) -> dict
  *
  *   items:        list of bytes-like (one JSON document per object)
  *   scalars:      list[tuple[str, ...]] (paths; __review__-rooted paths
@@ -2053,23 +2192,32 @@ work_free(Work *w, Py_buffer *views, Py_ssize_t n_views, Arena *spec_arena)
  *   map_key_axes: list[int]
  *   parent_specs: list[(child_axis_idx, parent_axis_idx)]
  *   rk_specs:     list[(axis_idx, subpath)]
+ *   floors:       None, or (per axis, per keyset, per rk spec) sequences
+ *                 of ints: the least width of each axis and the least l
+ *                 of each keyset and ragged keyset (0: none).  A width is
+ *                 bucket_up(max(the batch's own maximum, its floor)), so
+ *                 a caller that knows the corpus's widths gets its arrays
+ *                 made once, at their final shape.
  *
  * Returns the flatten_batch result dict plus "genname" (uint8 [N]),
- * "parent_idx" and "ragged_keysets" (extras computed in the same pass).
+ * "parent_idx" and "ragged_keysets" (extras computed in the same pass),
+ * and "fill_bytes": (the prefill bytes the workers wrote inside the
+ * released phases, those written with the GIL held).
  */
 static PyObject *
 py_flatten_json_batch(PyObject *self, PyObject *args)
 {
     PyObject *items, *scalars, *axes, *raggeds, *keysets, *mk_axes;
     PyObject *parent_specs, *rk_specs, *canons, *to_id, *to_str;
+    PyObject *floors = Py_None;
     Py_ssize_t pad_n;
     long bucket;
     int nthreads;
     (void)self;
-    if (!PyArg_ParseTuple(args, "OOOOOOOOOOOnli", &items, &scalars, &axes,
+    if (!PyArg_ParseTuple(args, "OOOOOOOOOOOnli|O", &items, &scalars, &axes,
                           &raggeds, &keysets, &mk_axes, &parent_specs,
                           &rk_specs, &canons, &to_id, &to_str, &pad_n,
-                          &bucket, &nthreads))
+                          &bucket, &nthreads, &floors))
         return NULL;
     if (!PyList_Check(items)) {
         PyErr_SetString(PyExc_TypeError, "items must be a list");
@@ -2093,14 +2241,12 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
     w.n_rks = (int)PyList_GET_SIZE(rk_specs);
     w.n_canons = (int)PyList_GET_SIZE(canons);
 
-    /* buffers */
-    views = (Py_buffer *)calloc((size_t)(w.n_real ? w.n_real : 1),
-                                sizeof(Py_buffer));
+    /* buffers (``views`` only once an item is no exact bytes) */
     w.bufs = (const char **)malloc((size_t)(w.n_real ? w.n_real : 1) *
                                    sizeof(char *));
     w.blens = (Py_ssize_t *)malloc((size_t)(w.n_real ? w.n_real : 1) *
                                    sizeof(Py_ssize_t));
-    if (!views || !w.bufs || !w.blens)
+    if (!w.bufs || !w.blens)
         goto oom;
     for (Py_ssize_t i = 0; i < w.n_real; i++) {
         PyObject *it = PyList_GET_ITEM(items, i);
@@ -2110,6 +2256,11 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
             w.bufs[i] = PyBytes_AS_STRING(it);
             w.blens[i] = PyBytes_GET_SIZE(it);
             continue;
+        }
+        if (views == NULL) {
+            views = (Py_buffer *)calloc((size_t)w.n_real, sizeof(Py_buffer));
+            if (views == NULL)
+                goto oom;
         }
         if (PyObject_GetBuffer(it, &views[i], PyBUF_SIMPLE) < 0)
             goto error;
@@ -2184,6 +2335,14 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
     }
     if (PyErr_Occurred())
         goto error;
+    ALLOCN(w.ax_floor, Py_ssize_t, w.n_axes);
+    ALLOCN(w.ks_floor, Py_ssize_t, w.n_keysets);
+    ALLOCN(w.rk_floor, Py_ssize_t, w.n_rks);
+    if (floors != Py_None &&
+        (floors_conv(floors, 0, w.ax_floor, w.n_axes) < 0 ||
+         floors_conv(floors, 1, w.ks_floor, w.n_keysets) < 0 ||
+         floors_conv(floors, 2, w.rk_floor, w.n_rks) < 0))
+        goto error;
 
     /* per-axis ragged extraction plan: self-column lists + subpath
      * tries (see RTrie) */
@@ -2256,27 +2415,19 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         node->col = s;
     }
 
-    /* rows (block-allocated sub-arrays) */
+    /* rows (block-allocated sub-arrays; worker_rows zeroes and wires
+     * them) */
     if (w.n_real > 0) {
-        w.rows = (Row *)calloc((size_t)w.n_real, sizeof(Row));
-        AxisItems *ax_blk = (AxisItems *)calloc(
-            (size_t)w.n_real * (size_t)(w.n_axes ? w.n_axes : 1),
-            sizeof(AxisItems));
-        KeysetRow *ks_blk = (KeysetRow *)calloc(
-            (size_t)w.n_real * (size_t)(w.n_keysets ? w.n_keysets : 1),
-            sizeof(KeysetRow));
-        RKRow *rk_blk = (RKRow *)calloc(
-            (size_t)w.n_real * (size_t)(w.n_rks ? w.n_rks : 1),
-            sizeof(RKRow));
-        if (!w.rows || !ax_blk || !ks_blk || !rk_blk) {
-            free(ax_blk); free(ks_blk); free(rk_blk);
+        size_t n = (size_t)w.n_real;
+        w.rows = (Row *)malloc(n * sizeof(Row));
+        w.ax_blk = (AxisItems *)malloc(
+            n * (size_t)(w.n_axes ? w.n_axes : 1) * sizeof(AxisItems));
+        w.ks_blk = (KeysetRow *)malloc(
+            n * (size_t)(w.n_keysets ? w.n_keysets : 1) * sizeof(KeysetRow));
+        w.rk_blk = (RKRow *)malloc(
+            n * (size_t)(w.n_rks ? w.n_rks : 1) * sizeof(RKRow));
+        if (!w.rows || !w.ax_blk || !w.ks_blk || !w.rk_blk)
             goto oom;
-        }
-        for (Py_ssize_t i = 0; i < w.n_real; i++) {
-            w.rows[i].axes = ax_blk + i * (w.n_axes ? w.n_axes : 1);
-            w.rows[i].keysets = ks_blk + i * (w.n_keysets ? w.n_keysets : 1);
-            w.rows[i].rks = rk_blk + i * (w.n_rks ? w.n_rks : 1);
-        }
     }
 
     /* threads */
@@ -2328,11 +2479,11 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         goto error;
     {
         npy_intp d1[1] = {(npy_intp)w.n_pad};
-        PyArrayObject *gid = new_arr(1, d1, NPY_INT32, -1);
-        PyArrayObject *kid = new_arr(1, d1, NPY_INT32, -1);
-        PyArrayObject *nsid = new_arr(1, d1, NPY_INT32, -1);
-        PyArrayObject *nmid = new_arr(1, d1, NPY_INT32, -1);
-        PyArrayObject *gen = new_arr(1, d1, NPY_UINT8, 0);
+        PyArrayObject *gid = new_arr(&w.fill1, 1, d1, NPY_INT32, -1);
+        PyArrayObject *kid = new_arr(&w.fill1, 1, d1, NPY_INT32, -1);
+        PyArrayObject *nsid = new_arr(&w.fill1, 1, d1, NPY_INT32, -1);
+        PyArrayObject *nmid = new_arr(&w.fill1, 1, d1, NPY_INT32, -1);
+        PyArrayObject *gen = new_arr(&w.fill1, 1, d1, NPY_UINT8, 0);
         if (!gid || !kid || !nsid || !nmid || !gen) {
             Py_XDECREF(gid); Py_XDECREF(kid); Py_XDECREF(nsid);
             Py_XDECREF(nmid); Py_XDECREF(gen);
@@ -2359,9 +2510,9 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (s_out == NULL)
             goto error;
         for (int s = 0; s < w.n_scalars; s++) {
-            PyArrayObject *a_kind = new_arr(1, d1, NPY_INT8, 0);
-            PyArrayObject *a_num = new_arr(1, d1, NPY_FLOAT32, 0);
-            PyArrayObject *a_sid = new_arr(1, d1, NPY_INT32, -1);
+            PyArrayObject *a_kind = new_arr(&w.fill1, 1, d1, NPY_INT8, 0);
+            PyArrayObject *a_num = new_arr(&w.fill1, 1, d1, NPY_FLOAT32, 0);
+            PyArrayObject *a_sid = new_arr(&w.fill1, 1, d1, NPY_INT32, -1);
             if (!a_kind || !a_num || !a_sid) {
                 Py_XDECREF(a_kind); Py_XDECREF(a_num); Py_XDECREF(a_sid);
                 Py_DECREF(s_out);
@@ -2384,7 +2535,7 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (c_out == NULL)
             goto error;
         for (int s = 0; s < w.n_canons; s++) {
-            PyArrayObject *a_sid = new_arr(1, d1, NPY_INT32, -2);
+            PyArrayObject *a_sid = new_arr(&w.fill1, 1, d1, NPY_INT32, -2);
             if (a_sid == NULL) {
                 Py_DECREF(c_out);
                 goto error;
@@ -2403,7 +2554,7 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (a_out == NULL)
             goto error;
         for (int a = 0; a < w.n_axes; a++) {
-            PyArrayObject *cnt = new_arr(1, d1, NPY_INT32, 0);
+            PyArrayObject *cnt = new_arr(&w.fill1, 1, d1, NPY_INT32, 0);
             if (cnt == NULL) {
                 Py_DECREF(a_out);
                 goto error;
@@ -2431,7 +2582,8 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         }
     }
 
-    /* widths from thread-local maxima, then phase-2 arrays */
+    /* widths from thread-local maxima and the caller's floors, then
+     * phase-2 arrays */
     {
         npy_intp d1[1] = {(npy_intp)w.n_pad};
         ALLOCN(w.r_kind, signed char *, w.n_raggeds);
@@ -2442,7 +2594,7 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (r_out == NULL)
             goto error;
         for (int r = 0; r < w.n_raggeds; r++) {
-            Py_ssize_t maxc = 0;
+            Py_ssize_t maxc = w.ax_floor[w.raggeds[r].axis];
             for (int t = 0; t < w.nthreads; t++)
                 if (w.tc[t].max_axis[w.raggeds[r].axis] > maxc)
                     maxc = w.tc[t].max_axis[w.raggeds[r].axis];
@@ -2450,9 +2602,9 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
             w.r_m[r] = m;
             w.ax_m[w.raggeds[r].axis] = m;
             npy_intp d2[2] = {(npy_intp)w.n_pad, (npy_intp)m};
-            PyArrayObject *a_kind = new_arr(2, d2, NPY_INT8, 0);
-            PyArrayObject *a_num = new_arr(2, d2, NPY_FLOAT32, 0);
-            PyArrayObject *a_sid = new_arr(2, d2, NPY_INT32, -1);
+            PyArrayObject *a_kind = new_arr(&w.fill2, 2, d2, NPY_INT8, 0);
+            PyArrayObject *a_num = new_arr(&w.fill2, 2, d2, NPY_FLOAT32, 0);
+            PyArrayObject *a_sid = new_arr(&w.fill2, 2, d2, NPY_INT32, -1);
             if (!a_kind || !a_num || !a_sid) {
                 Py_XDECREF(a_kind); Py_XDECREF(a_num); Py_XDECREF(a_sid);
                 Py_DECREF(r_out);
@@ -2477,15 +2629,15 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (k_out == NULL)
             goto error;
         for (int s = 0; s < w.n_keysets; s++) {
-            Py_ssize_t maxc = 0;
+            Py_ssize_t maxc = w.ks_floor[s];
             for (int t = 0; t < w.nthreads; t++)
                 if (w.tc[t].max_keyset[s] > maxc)
                     maxc = w.tc[t].max_keyset[s];
             Py_ssize_t l = bucket_up((long)maxc, w.bucket);
             w.k_l[s] = l;
             npy_intp d2[2] = {(npy_intp)w.n_pad, (npy_intp)l};
-            PyArrayObject *a_sid = new_arr(2, d2, NPY_INT32, -1);
-            PyArrayObject *a_cnt = new_arr(1, d1, NPY_INT32, 0);
+            PyArrayObject *a_sid = new_arr(&w.fill2, 2, d2, NPY_INT32, -1);
+            PyArrayObject *a_cnt = new_arr(&w.fill2, 1, d1, NPY_INT32, 0);
             if (!a_sid || !a_cnt) {
                 Py_XDECREF(a_sid); Py_XDECREF(a_cnt); Py_DECREF(k_out);
                 goto error;
@@ -2506,14 +2658,14 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (mk_out == NULL)
             goto error;
         for (int q = 0; q < w.n_mk; q++) {
-            Py_ssize_t maxc = 0;
+            Py_ssize_t maxc = w.ax_floor[w.mk_axes[q]];
             for (int t = 0; t < w.nthreads; t++)
                 if (w.tc[t].max_axis[w.mk_axes[q]] > maxc)
                     maxc = w.tc[t].max_axis[w.mk_axes[q]];
             Py_ssize_t m = bucket_up((long)maxc, w.bucket);
             w.mk_m[q] = m;
             npy_intp d2[2] = {(npy_intp)w.n_pad, (npy_intp)m};
-            PyArrayObject *a_sid = new_arr(2, d2, NPY_INT32, -1);
+            PyArrayObject *a_sid = new_arr(&w.fill2, 2, d2, NPY_INT32, -1);
             if (a_sid == NULL) {
                 Py_DECREF(mk_out);
                 goto error;
@@ -2533,14 +2685,14 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (p_out == NULL)
             goto error;
         for (int p = 0; p < w.n_parents; p++) {
-            Py_ssize_t maxc = 0;
+            Py_ssize_t maxc = w.ax_floor[w.parents[p].child];
             for (int t = 0; t < w.nthreads; t++)
                 if (w.tc[t].max_axis[w.parents[p].child] > maxc)
                     maxc = w.tc[t].max_axis[w.parents[p].child];
             Py_ssize_t m = bucket_up((long)maxc, w.bucket);
             w.p_m[p] = m;
             npy_intp d2[2] = {(npy_intp)w.n_pad, (npy_intp)m};
-            PyArrayObject *a_idx = new_arr(2, d2, NPY_INT32, -1);
+            PyArrayObject *a_idx = new_arr(&w.fill2, 2, d2, NPY_INT32, -1);
             if (a_idx == NULL) {
                 Py_DECREF(p_out);
                 goto error;
@@ -2562,7 +2714,8 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
         if (rk_out == NULL)
             goto error;
         for (int s = 0; s < w.n_rks; s++) {
-            Py_ssize_t maxm = 0, maxl = 0;
+            Py_ssize_t maxm = w.ax_floor[w.rks[s].axis];
+            Py_ssize_t maxl = w.rk_floor[s];
             for (int t = 0; t < w.nthreads; t++) {
                 if (w.tc[t].max_axis[w.rks[s].axis] > maxm)
                     maxm = w.tc[t].max_axis[w.rks[s].axis];
@@ -2575,8 +2728,8 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
             w.rk_l[s] = l;
             npy_intp d3[3] = {(npy_intp)w.n_pad, (npy_intp)m, (npy_intp)l};
             npy_intp d2[2] = {(npy_intp)w.n_pad, (npy_intp)m};
-            PyArrayObject *a_sid = new_arr(3, d3, NPY_INT32, -1);
-            PyArrayObject *a_cnt = new_arr(2, d2, NPY_INT32, 0);
+            PyArrayObject *a_sid = new_arr(&w.fill2, 3, d3, NPY_INT32, -1);
+            PyArrayObject *a_cnt = new_arr(&w.fill2, 2, d2, NPY_INT32, 0);
             if (!a_sid || !a_cnt) {
                 Py_XDECREF(a_sid); Py_XDECREF(a_cnt); Py_DECREF(rk_out);
                 goto error;
@@ -2666,6 +2819,20 @@ py_flatten_json_batch(PyObject *self, PyObject *args)
 
     /* phase 3: remap local sids -> global (GIL released) */
     run_phase_released(&w, 3);
+
+    {
+        size_t filled = 0;
+        for (int t = 0; t < w.nthreads; t++)
+            filled += w.tc[t].filled;
+        PyObject *fb = Py_BuildValue("(nn)", (Py_ssize_t)filled,
+                                     (Py_ssize_t)(w.fill1.held +
+                                                  w.fill2.held));
+        if (fb == NULL || PyDict_SetItemString(result, "fill_bytes", fb) < 0) {
+            Py_XDECREF(fb);
+            goto error;
+        }
+        Py_DECREF(fb);
+    }
 
     work_free(&w, views, w.n_real, &spec_arena);
     return result;

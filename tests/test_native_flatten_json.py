@@ -8,6 +8,7 @@ bit-identical.  (Same trick as the audit pipeline: one shared driver
 vocab, consistency is what matters, not order.)
 """
 
+import functools
 import json
 import os
 import random
@@ -28,6 +29,7 @@ from gatekeeper_tpu.ops.flatten import (
     ScalarCol,
     Schema,
     Vocab,
+    round_up,
 )
 from gatekeeper_tpu.utils.rawjson import RawJSON, as_raw
 
@@ -373,3 +375,173 @@ def test_rawjson_mutation_and_copy_semantics():
 
     r3 = as_raw({"a": 1})
     assert copy.deepcopy(r3)["a"] == 1  # unloaded path still works
+
+
+# --- the prefill is the workers', the widths the caller's (PR 37) ----------
+#
+# Every output array is PyArray_EMPTY: each row of it, the padding rows
+# too, carries its prefill (0, -1, -2) only because some worker wrote it
+# inside a released phase.  A row no thread filled would show as garbage
+# against the Python flattener, which np.zeros() and np.full()s.
+
+PADS = {"n": lambda n: n, "n+1": lambda n: n + 1, "2n": lambda n: 2 * n}
+
+
+def _arrays(out):
+    """Every array of a columnizer result, in the result's order."""
+    def walk(x):
+        if isinstance(x, np.ndarray):
+            yield x
+        elif isinstance(x, (list, tuple)):
+            for e in x:
+                yield from walk(e)
+    return [a for key, val in out.items() if key != "fill_bytes"
+            for a in walk(val)]
+
+
+def _columnize(f, items, pad_n, nthreads, *more):
+    schema = f.schema
+    axes = schema.axes()
+    specs = f._columnizer_specs(schema, axes,
+                                {a: i for i, a in enumerate(axes)})
+    return jmod.flatten_json_batch(items, *specs, f.vocab._to_id,
+                                   f.vocab._to_str, pad_n, f.bucket,
+                                   nthreads, *more)
+
+
+@pytest.mark.skipif(jmod is None, reason="native json build unavailable")
+@pytest.mark.parametrize("n_real", [0, 1, 127, 300])
+@pytest.mark.parametrize("pad", sorted(PADS))
+@pytest.mark.parametrize("nthreads", [1, 2, 13])
+def test_json_prefill_by_the_workers_matches_python(nthreads, pad, n_real):
+    """Threads x padding x rows: the thread count clamps to n / 128 + 1,
+    so 300 rows run on 1, 2 and 3 threads, 127 and fewer on one; with no
+    row at all the one thread's own range is empty and the padding rows
+    are all it fills (the partition leaves a last thread's range empty in
+    no other case)."""
+    schema = rich_schema()
+    objs = rich_objects(n_real, seed=11)
+    raws = [as_raw(o) for o in objs]
+    pad_n = PADS[pad](n_real)
+    vocab = Vocab()
+    f = Flattener(schema, vocab)
+    f.nthreads = nthreads
+    nat = f.flatten_raw(raws, pad_n=pad_n)
+    assert f.lane_used == "raw"
+    py = Flattener(schema, vocab, use_native=False).flatten(
+        objs, pad_n=pad_n)
+    assert nat.n == py.n == pad_n
+    assert_batches_equal(schema, py, nat)
+    # every byte of every array was prefilled once, by a worker, the lock
+    # released
+    out = _columnize(Flattener(schema, Vocab()),
+                     [r.raw for r in raws], pad_n, nthreads)
+    assert out["fill_bytes"] == (sum(a.nbytes for a in _arrays(out)), 0)
+    assert f.perf["fill_released_bytes"] == out["fill_bytes"][0]
+    assert f.perf["fill_held_bytes"] == 0
+
+
+@pytest.mark.skipif(jmod is None, reason="native json build unavailable")
+def test_json_invalid_in_the_middle_threads_range_leaves_the_vocab():
+    schema = rich_schema()
+    items = [as_raw(o).raw for o in rich_objects(300, seed=5)]
+    items[150] = b'{"kind": "Pod", "metadata": {'  # thread 1 of 3's rows
+    f = Flattener(schema, Vocab())
+    before = (dict(f.vocab._to_id), list(f.vocab._to_str))
+    with pytest.raises(ValueError, match="batch item 150"):
+        _columnize(f, items, 320, 13)
+    assert (f.vocab._to_id, f.vocab._to_str) == before
+    # and the batch lands on the dict lane, which refuses it too
+    with pytest.raises(ValueError):
+        f.flatten_raw([RawJSON(b) for b in items], pad_n=320)
+    assert (f.vocab._to_id, f.vocab._to_str) == before
+
+
+FAMILIES = {
+    "raggeds": lambda b: [a for c in b.raggeds.values()
+                          for a in (c.kind, c.num, c.sid)],
+    "keysets": lambda b: [a for c in b.keysets.values()
+                          for a in (c.sid, c.count)],
+    "map_keys": lambda b: [c.sid for c in b.map_keys.values()],
+    "parent_idx": lambda b: [c.idx for c in b.parent_idx.values()],
+    "ragged_keysets": lambda b: [a for c in b.ragged_keysets.values()
+                                 for a in (c.sid, c.count)],
+}
+# the chunk against the targets: a bucket (2) and more narrower, the
+# same, wider
+RELATIONS = {"narrower": 3, "equal": 0, "wider": -3}
+
+
+@functools.lru_cache(maxsize=None)
+def _floors_and_parent(relation):
+    """One chunk flattened twice over one vocabulary: with the targets as
+    the columnizer's floors, and as the parent did it (the chunk's own
+    widths, then ``_stabilize``'s second array and copy)."""
+    schema = rich_schema()
+    raws = [as_raw(o) for o in rich_objects(200, seed=23)]
+    vocab = Vocab()
+    own: dict = {}
+    probe = Flattener(schema, vocab, bucket=2)
+    probe.record_widths(probe.flatten_raw(raws, pad_n=256), own)
+    targets = {k: max(1, v + RELATIONS[relation]) for k, v in own.items()}
+    floors = Flattener(schema, vocab, bucket=2, width_targets=targets)
+    with_floors = floors.flatten_raw(raws, pad_n=256)
+    parent = Flattener(schema, vocab, bucket=2, width_targets=targets)
+    parent._width_floors = lambda schema, axes: None
+    as_parent = parent.flatten_raw(raws, pad_n=256)
+    return schema, with_floors, as_parent, floors.perf, parent.perf
+
+
+@pytest.mark.skipif(jmod is None, reason="native json build unavailable")
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+@pytest.mark.parametrize("relation", sorted(RELATIONS))
+def test_json_width_floors_give_stabilizes_shapes(relation, family):
+    schema, with_floors, as_parent, perf, parent_perf = \
+        _floors_and_parent(relation)
+    got, want = FAMILIES[family](with_floors), FAMILIES[family](as_parent)
+    assert got and len(got) == len(want)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert a.tobytes() == b.tobytes()
+    assert_batches_equal(schema, as_parent, with_floors)
+    # the floors leave _stabilize nothing to pad; the parent's path pads
+    # exactly where the chunk is narrower than the corpus
+    assert perf["stabilize_repads"] == 0
+    assert parent_perf["stabilize_repads"] == (relation == "narrower")
+    assert perf["fill_held_bytes"] == parent_perf["fill_held_bytes"] == 0
+    wider = perf["fill_released_bytes"] - parent_perf["fill_released_bytes"]
+    assert (wider > 0) == (relation == "narrower")
+
+
+@pytest.mark.skipif(jmod is None, reason="native json build unavailable")
+def test_json_call_without_floors_is_the_call_as_it_was():
+    """The worker pool's children pass no floors: the widths are the
+    batch's own bucketed maxima, as with None and with floors of 0."""
+    items = [as_raw(o).raw for o in rich_objects(150, seed=31)]
+    outs = []
+    for more in ((), (None,), (([0] * 3, [0] * 2, [0] * 2),)):
+        f = Flattener(rich_schema(), Vocab())
+        outs.append((_columnize(f, items, 160, 1, *more), f.vocab._to_str))
+    (first, strs), rest = outs[0], outs[1:]
+    for sid, cnt in first["keysets"] + first["ragged_keysets"]:
+        assert sid.shape[-1] == round_up(int(cnt.max()), 8)
+    for (_k, _n, sid), axis in zip(first["raggeds"], (0, 0, 0, 1, 2)):
+        assert sid.shape[1] == round_up(int(first["axes"][axis].max()), 8)
+    for out, strs2 in rest:
+        assert strs2 == strs and out["fill_bytes"] == first["fill_bytes"]
+        for a, b in zip(_arrays(out), _arrays(first)):
+            assert a.shape == b.shape and a.tobytes() == b.tobytes()
+    # items that are no exact bytes go through the buffer protocol
+    f = Flattener(rich_schema(), Vocab())
+    mixed = [bytearray(b) if i % 3 else memoryview(b)
+             for i, b in enumerate(items)]
+    mixed[0] = items[0]
+    out = _columnize(f, mixed, 160, 1)
+    assert f.vocab._to_str == strs
+    for a, b in zip(_arrays(out), _arrays(first)):
+        assert a.tobytes() == b.tobytes()
+    f = Flattener(rich_schema(), Vocab())
+    for bad in (([0] * 3, [0] * 2), ([0] * 2, [0] * 2, [0] * 2), 7,
+                (["x"] * 3, [0] * 2, [0] * 2)):
+        with pytest.raises(TypeError):
+            _columnize(f, items, 160, 1, bad)

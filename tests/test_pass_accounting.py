@@ -776,6 +776,10 @@ UNREAD = ["flatten_cpu", "flatten_released", "masks_released",
           "wire_pack_cpu", "wire_pack_released", "dispatch_cpu",
           "dispatch_released", "fl_assemble_cpu", "fl_canon_fill_cpu"]
 GONE = ["fl_c_columnize", "fl_py_assemble", "fl_canon_fill", "fl_stabilize"]
+# counts every lane of the flattener writes on every pass, a 0 too (PR 37):
+# the columnizer's prefill bytes by who wrote them, the chunks re-padded
+FILL_KEYS = ["fl_fill_released_bytes", "fl_fill_held_bytes",
+             "fl_stabilize_repads"]
 
 
 def _raw_mgr(toy, pipeline, n=40):
@@ -1018,7 +1022,7 @@ def test_the_account_keys_are_written_on_every_pass(toy, pipeline):
 
 def test_dict_objects_write_the_stage_keys_and_no_flatten_raw_table(toy):
     # the dict lane never enters flatten_raw: its table is absent, the
-    # stage's own cpu and released are there, a 0.0
+    # stage's own cpu and released are there, a 0.0; nothing was prefilled
     _client, evaluator = toy
     evaluator.perf_reset()
     mgr = _toy_mgr(toy, "on")
@@ -1028,6 +1032,38 @@ def test_dict_objects_write_the_stage_keys_and_no_flatten_raw_table(toy):
     assert isinstance(evaluator.perf["masks_cpu"], float)
     assert not [k for k in FLATTEN_RAW_KEYS + GONE + UNREAD
                 if k in evaluator.perf]
+    assert [evaluator.perf[k] for k in FILL_KEYS] == [0, 0, 0]
+
+
+@pytest.mark.parametrize("traced", [False, True])
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+def test_the_fill_counters_are_written_on_every_pass(toy, pipeline, traced):
+    """The columnizer's workers prefill its arrays with the lock let go:
+    every byte of it is counted released, none held, and the arrays
+    arrive at a width `_stabilize` has nothing to add to; the same counts
+    with a tracer and without, and on the spans of the native calls."""
+    _client, evaluator = toy
+    mgr = _raw_mgr(toy, pipeline)
+    mgr.audit()
+    mgr.perf = {}
+    evaluator.perf_reset()
+    tracer = tracing.Tracer(seed=0)
+    if traced:
+        with tracing.activate(tracer):
+            mgr.audit()
+    else:
+        mgr.audit()
+    released, held, repads = (evaluator.perf[k] for k in FILL_KEYS)
+    # three chunks of 16, 16 and 8 Namespaces: at least the four identity
+    # columns of every row, int32
+    assert released >= 40 * 4 * 4 and released == int(released)
+    assert (held, repads) == (0, 0)
+    if traced:
+        spans = _spans_by_name(tracer)[0]["ops.flatten.native"]
+        assert len(spans) == 3
+        assert sum(s["attributes"]["fill_released_bytes"]
+                   for s in spans) == released
+        assert {s["attributes"]["fill_held_bytes"] for s in spans} == {0}
 
 
 def test_with_the_native_modules_unloaded_nothing_is_released(
@@ -1042,6 +1078,10 @@ def test_with_the_native_modules_unloaded_nothing_is_released(
     with_modules = mgr.audit()
     fused = warmed.perf["wire_cols_fused"]
     assert (fused > 0) == (native.load_wirepack() is not None)
+    # the warm pass's widths went in as the columnizer's floors
+    assert (warmed.perf["fl_fill_released_bytes"] > 0) == (
+        native.load_json() is not None)
+    assert warmed.perf["fl_stabilize_repads"] == 0
     monkeypatch.setattr(native, "load_wirepack", lambda: None)
     monkeypatch.setattr(native, "load_json", lambda: None)
     mgr.perf = {}
@@ -1049,7 +1089,11 @@ def test_with_the_native_modules_unloaded_nothing_is_released(
     without = mgr.audit()
     released = {k: v for k, v in list(mgr.perf.items())
                 + list(warmed.perf.items()) if k.endswith("_released")}
-    # (without the columnizer flatten_raw is not entered: no fl_* table)
+    # (without the columnizer flatten_raw is not entered: no fl_* table,
+    # and nothing is prefilled by anyone's workers)
+    assert warmed.perf["fl_fill_released_bytes"] == 0
+    assert warmed.perf["fl_fill_held_bytes"] == 0
+    assert warmed.perf["fl_stabilize_repads"] == 0
     assert set(released) >= {"list_released"} | {
         f"pipe_{s}_released"
         for s in ("flatten", "dispatch", "collect", "fold_render")}
