@@ -13,7 +13,11 @@ them, and a median jumps from one mode to the other between runs.
 ``AuditManager.audit()`` itself, on the executables of the measured passes,
 and its totals and kept violations must be the interpreter's; and the
 (constraint, object) pairs a ``return_bits`` sweep finds on it must be the
-interpreter's.  Every measured pass is then held to the set-up pass.
+interpreter's.  Every measured pass is then held to the set-up pass.  A
+total is what the configuration's ``audit.exact_totals`` says it is: the
+number of a constraint's results (true, ``AuditConfig``'s default and what
+``python -m gatekeeper_tpu`` runs) or of its violating objects (false).
+Every number compared goes into the result line, each beside its limit.
 """
 
 from __future__ import annotations
@@ -184,32 +188,47 @@ def kept_agrees(got: list, want: list, limit: int) -> bool:
 
 
 def sample_audit_problems(got, order: list, results: dict, ident: dict,
-                          limit: int) -> list:
+                          limit: int, exact: bool = False,
+                          counts: dict | None = None) -> list:
     """What ``got``, the audit of the sample corpus, reports otherwise than
     the interpreter.  ``results``: {corpus index: {constraint key:
-    [messages]}}; ``ident``: {corpus index: (kind, namespace, name)}."""
+    [messages]}}; ``ident``: {corpus index: (kind, namespace, name)}.
+    ``exact`` is the configuration's ``audit.exact_totals``: a constraint's
+    total is then the number of its results over the listed objects, as
+    upstream's ``totalViolations`` counts, and otherwise the number of
+    listed objects that violate it.  ``counts``, if given, takes how many
+    constraints each comparison failed (the run's ``compared``)."""
     problems = []
-    if got.incomplete or got.total_objects != len(order):
+    short = got.incomplete or got.total_objects != len(order)
+    if short:
         problems.append(f"sample audit: incomplete={got.incomplete}, "
                         f"{got.total_objects} of {len(order)} objects")
     totals: dict = {}
     violators: dict = {}
     for idx in order:
         for key, msgs in results.get(idx, {}).items():
-            totals[key] = totals.get(key, 0) + 1
+            totals[key] = totals.get(key, 0) + (len(msgs) if exact else 1)
             violators.setdefault(key, []).append((ident[idx], msgs))
+    totals_differ = kept_differ = 0
     for key, total in got.total_violations.items():
         if total != totals.get(key, 0):
+            totals_differ += 1
             problems.append(f"sample audit: {key} totals {total}, the "
                             f"interpreter {totals.get(key, 0)}")
         kept = [((v.kind, v.namespace, v.name), v.message)
                 for v in got.kept[key]]
         if not kept_agrees(kept, violators.get(key, []), limit):
+            kept_differ += 1
             problems.append(f"sample audit: {key} keeps other violations "
                             f"than the interpreter's first {limit}")
     missing = set(totals) - set(got.total_violations)
     if missing:
         problems.append(f"sample audit: no totals for {sorted(missing)}")
+    if counts is not None:
+        counts.update(sample_audit_short=int(short),
+                      sample_totals_differ=totals_differ,
+                      sample_kept_differ=kept_differ,
+                      sample_totals_missing=len(missing))
     return problems
 
 
@@ -236,6 +255,45 @@ def device_pairs(program, groups: dict) -> set:
                 for oi in violation_rows(hits, ci, len(chunk)):
                     pairs.add((tuple(con.key()), members[int(oi)][0]))
     return pairs
+
+
+def trace_plan(setup_pass_s: float, window_s: float, trace_from: int,
+               trace_passes: int) -> tuple:
+    """(index of the first traced pass of the window, traced passes).  The
+    mix's own choice, unless the set-up pass says that the window holds
+    fewer whole passes than that takes: then the window's first pass alone,
+    so that a cell whose pass fills most of the window still has a traced
+    run."""
+    if setup_pass_s * (trace_from + trace_passes) > window_s:
+        return 0, 1
+    return trace_from, trace_passes
+
+
+def hit_buffers(ev) -> dict:
+    """What of the evaluator's adaptive state is part of a sweep program's
+    key: each swept shape's hit-buffer size under ``return_bits``, or None
+    where the shape is pinned to the bit grid, which has no buffer."""
+    return {shape: None if st["pinned"] else st["cap"]
+            for shape, st in ev.warm_state()["hit_state"].items()}
+
+
+def settled_pass(mgr, ev, most: int = 3) -> tuple:
+    """(the set-up pass, its seconds, passes run).  A chunk whose hits
+    overflow its buffer under ``return_bits`` is swept again through the
+    bit grid and the buffer grows, and it is the pass after that asks for
+    the program of the new size: that pass is set-up's too, so that nothing
+    compiles inside the window.  A shape the overflow pins to the bit grid
+    needs none (the overflow itself ran that program), nor does a lane that
+    sizes no buffer (the top-k lane's ladder is warmed whole)."""
+    for n in range(1, most + 1):
+        sized = hit_buffers(ev)
+        t = time.monotonic()
+        first = mgr.audit()
+        seconds = time.monotonic() - t
+        if all(now is None or now == sized[shape]
+               for shape, now in hit_buffers(ev).items() if shape in sized):
+            break
+    return first, seconds, n
 
 
 def canonical_run(audit_run) -> tuple:
@@ -287,14 +345,16 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
     lister = lister_of(paths)
     mgr = program.build_audit(lister)
     ev = program.evaluator
-    ev.warm_pass(program.client.constraints(), lister(), chunk)
+    # the lane the passes will sweep in: return_bits under exact totals
+    ev.warm_pass(program.client.constraints(), lister(), chunk,
+                 return_bits=cfg["audit"]["exact_totals"])
     run.mark("warm_pass")
     t_sample = time.monotonic()
     sampled = program.build_audit(lister_of([sample_corpus])).audit()
     run.mark("sample_audit")
     compiles_in_sample_audit = run.compiles_between(t_sample,
                                                     time.monotonic())
-    first = mgr.audit()
+    first, setup_pass_s, setup_passes = settled_pass(mgr, ev)
     run.mark("setup_pass")
     program.begin_background_compile()
     want = canonical_run(first)
@@ -313,7 +373,14 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
         meta = json.loads(raw)
         ident[idx] = (meta["kind"], meta["metadata"].get("namespace", ""),
                       meta["metadata"]["name"])
-    problems = sample_audit_problems(sampled, order, results, ident, limit)
+    # every number that decides ``correct``; each has the limit 0
+    compared: dict = {}
+    problems = sample_audit_problems(sampled, order, results, ident, limit,
+                                     cfg["audit"]["exact_totals"], compared)
+    compared["device_pairs_differ"] = len(device ^ interp)
+    compared["reference_sample_empty"] = int(not interp)
+    compared["setup_pass_short"] = int(
+        first.incomplete or first.total_objects != n_objects)
     if device != interp:
         problems.append(
             f"device sweep != interpreter on {len(sample)} objects: "
@@ -331,8 +398,11 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
     fallbacks_in_setup = int(ev.perf.get("collect_fallbacks", 0))
     ev.perf_reset()
     mgr.perf = {}
-    trace_passes = traffic["trace_passes"] if run.traced else 0
-    trace_from = traffic["trace_from_pass"]
+    trace_from, trace_passes = trace_plan(
+        setup_pass_s, run.seconds, traffic["trace_from_pass"],
+        traffic["trace_passes"])
+    if not run.traced:
+        trace_passes = 0
     traced_passes = 0
     if run.traced:
         run.watch_gc()
@@ -364,13 +434,17 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
 
     attempted = failed = 0
     chunks_per_pass = sum(-(-n // chunk) for n in sizes.values())
+    compared.update(passes_short=0, passes_differ=0,
+                    window_empty=int(not passes))
     for i, r in enumerate(runs):
         attempted += chunks_per_pass
         failed += r.failed_chunks + r.retried_chunks
         if r.incomplete or r.total_objects != n_objects:
+            compared["passes_short"] += 1
             problems.append(f"pass {i}: incomplete={r.incomplete}, "
                             f"{r.total_objects} objects")
         elif canonical_run(r) != want:
+            compared["passes_differ"] += 1
             problems.append(f"pass {i}: totals or kept violations differ "
                             "from the set-up pass")
     fallbacks = int(ev.perf.get("collect_fallbacks", 0))
@@ -399,12 +473,16 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
     }
     notes = {
         "passes": len(passes), "pass_s": passes,
+        "traced_from": trace_from, "traced_passes": traced_passes,
+        "setup_passes": setup_passes,
         "pass_s_median": stats.median(passes) if passes else None,
         "objects": n_objects, "constraints": len(first.kept),
         "inventory_synced": n_inv, "reference_sample": len(sample),
         "reference_violating_pairs": len(interp),
         "sample_audit_objects": len(order),
         "sample_audit_violations": sum(sampled.total_violations.values()),
+        "sample_audit_violating_objects": sum(
+            len(results.get(idx, ())) for idx in order),
         "sample_audit_kept": sum(len(v) for v in sampled.kept.values()),
         "compiles_in_sample_audit": compiles_in_sample_audit,
         "violations": sum(first.total_violations.values()),
@@ -420,5 +498,7 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
     e2e = {"setup_s": setup_s}
     if passes:
         e2e["audit_pass_s"] = stats.mean(passes)
-    return run.result(not problems, attempted, failed, e2e, obs, notes)
+    return run.result(not problems, attempted, failed, e2e, obs, notes,
+                      {name: {"value": n, "limit": 0}
+                       for name, n in compared.items()})
 

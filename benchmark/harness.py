@@ -168,10 +168,13 @@ class Run:
 
     # --- the result ------------------------------------------------------------
     def result(self, correct: bool, attempted: int, failed: int,
-               end_to_end: dict, obs: dict, notes: dict) -> dict:
+               end_to_end: dict, obs: dict, notes: dict,
+               compared: dict | None = None) -> dict:
         """The run's one line.  ``--trace 0`` carries the cell's end-to-end
         metrics, ``--trace 1`` its per-layer metrics, the device's busy
-        seconds and the breakdown."""
+        seconds and the breakdown.  ``compared``: {name: {"value",
+        "limit"}} of every number that decided ``correct``; it comes last
+        in the line and is the last that standard error says."""
         device = dict(self.device,
                       memory_peak_bytes=self.memory_peak_bytes())
         out = {"correct": bool(correct), "attempted": int(attempted),
@@ -206,4 +209,8 @@ class Run:
         with open(os.path.join(self.work, "notes.json"), "w") as f:
             json.dump(notes, f)
         print("benchmark: notes: " + json.dumps(notes), file=sys.stderr)
+        if compared is not None:
+            out["compared"] = compared
+            print(f"benchmark: correct={out['correct']}, compared: "
+                  + json.dumps(compared), file=sys.stderr)
         return out

@@ -284,17 +284,26 @@ def entries() -> dict:
     return manifest.read_json(manifest.MANIFEST)
 
 
-def test_the_manifest_resolves_with_four_cells_and_four_configurations():
+def listed_in_order(workloads: list) -> bool:
+    """The four cells of PR 32 are listed, in their order; later cells may
+    stand anywhere among them."""
+    return [w for w in workloads if w in CELLS] == CELLS
+
+
+def test_the_manifest_resolves_with_the_cell_and_its_configuration():
+    """Containment and order since PR 38: later PRs append their own cells
+    and configurations (until then this test held the manifest to exactly
+    four of each and ``tests/conftest.py`` deselected it under its old
+    name)."""
     assert manifest.check() == []
     m = entries()
-    assert [w["name"] for w in m["workloads"]] == CELLS
-    assert len(m["configs"]) == 4
-    entry = m["configs"][-1]
-    assert entry["name"] == "library-c500sel"
+    assert listed_in_order([w["name"] for w in m["workloads"]])
+    assert len(m["configs"]) >= 4
+    entry = next(c for c in m["configs"] if c["name"] == "library-c500sel")
     assert entry["file"] == "benchmark/configs/library-c500sel.json"
     assert entry["source"] == config()["source"]
     assert len(entry["source"]) <= 200 and entry["reduced"] == ["objects"]
-    work = m["workloads"][-1]
+    work = next(w for w in m["workloads"] if w["name"] == CELL)
     assert work == {
         "name": CELL, "config": "library-c500sel", "traffic": "audit-sweep",
         "chips": 1, "why": work["why"]}
@@ -304,14 +313,19 @@ def test_the_manifest_resolves_with_four_cells_and_four_configurations():
                                                     "setup_s"}
 
 
-def test_the_cell_reports_what_the_control_reports_and_the_two_new():
+def test_the_cell_reports_what_the_control_reports_with_the_two_new():
+    """Containment and order since PR 38 (the list grows with every metric
+    a later PR appends; the old name is the one ``tests/conftest.py``
+    deselected)."""
     m = entries()
     e2e = {e["name"]: e for e in m["end_to_end"]}
-    assert e2e["audit_pass_s"]["workloads"] == CELLS
+    assert listed_in_order(e2e["audit_pass_s"]["workloads"])
     control = [p["name"] for p in manifest.Cell(CONTROL).per_layer]
     mine = [p["name"] for p in manifest.Cell(CELL).per_layer]
-    assert mine == control and len(mine) == 28
-    assert mine[-2:] == NEW
+    assert mine == control and len(mine) >= 28
+    assert [n for n in mine if n in NEW] == NEW
+    older = mine[:mine.index(NEW[0])]
+    assert len(older) == 26  # what the control reported before PR 32
     per_layer = {p["name"]: p for p in m["per_layer"]}
     # no list: every cell reports it, the planned admission cells too
     # (test_benchmark_yardstick.py holds that they need entries only)
@@ -319,7 +333,7 @@ def test_the_cell_reports_what_the_control_reports_and_the_two_new():
     assert "entry.compiles_in_window" in mine
     for name, better, unit in zip(NEW, ["higher", "lower"], ["1", "s"]):
         p = per_layer[name]
-        assert p["workloads"] == CELLS
+        assert listed_in_order(p["workloads"])
         assert (p["layer"], p["moves"], p["source"]) == (
             "masks", "audit_pass_s", "program_counter")
         assert (p["better"], p["unit"]) == (better, unit)

@@ -105,6 +105,12 @@ def test_a_program_without_the_account_reads_nothing(name):
                              "fold_render": 1.0, "n_renders": 1400},
                     evaluator={"flatten": 2.0, "masks": 1.0},
                     spans=[{"name": "audit.sweep", "duration_s": 4.0}])
+    if name == "python_gc.full_span_s_per_pass":
+        # since PR 38 a tracer's spans with no full collection among them
+        # are a window that held none (psp.audit-sweep since PR 35), so the
+        # parent of PR 24 would read 0.0; only a run without spans is silent
+        assert read(name, parent) == 0.0
+        parent = dict(parent, spans=[])
     assert read(name, parent) is None
 
 
@@ -149,6 +155,8 @@ def test_full_span_seconds_are_the_gc_spans_over_the_passes():
                 obs_of(spans=spans)) == pytest.approx(0.3)
     assert read("python_gc.full_span_s_per_pass",
                 obs_of(spans=spans, passes=0)) is None
+    assert read("python_gc.full_span_s_per_pass",
+                obs_of(spans=spans[::2])) == 0.0  # a window without one
 
 
 def test_idle_unlabelled_share_on_the_ledgers_pr22_breakdown():
