@@ -18,6 +18,16 @@ total is what the configuration's ``audit.exact_totals`` says it is: the
 number of a constraint's results (true, ``AuditConfig``'s default and what
 ``python -m gatekeeper_tpu`` runs) or of its violating objects (false).
 Every number compared goes into the result line, each beside its limit.
+
+A mix with a ``churn`` block (``churn.py``) changes the cluster between
+every two passes.  A pass is then held to what its own cluster owes: the
+set-up pass's totals moved by every change of an object's verdict since,
+as a ``return_bits`` sweep of every changed object finds them once the
+window has closed (so that the measured passes are the first to meet each
+new name), that sweep held to the interpreter on a seeded sample of the
+changed objects, and the kept violations of three passes held to the
+interpreter's review of the very bytes those passes listed.  A mix without
+the block runs as it always has.
 """
 
 from __future__ import annotations
@@ -28,7 +38,7 @@ import os
 import sys
 import time
 
-from benchmark import cluster, reference, stats, wiring
+from benchmark import churn, cluster, reference, stats, wiring
 from benchmark.harness import Run
 
 
@@ -232,10 +242,12 @@ def sample_audit_problems(got, order: list, results: dict, ident: dict,
     return problems
 
 
-def device_pairs(program, groups: dict) -> set:
+def device_pairs(program, groups: dict, chunk: int | None = None) -> set:
     """{(constraint key, corpus index)} the device sweep finds violated on
     the sample: each kind group swept with ``return_bits``, as an audit
-    with exact totals sweeps it."""
+    with exact totals sweeps it.  With ``chunk``, a group larger than that
+    is swept in slices of ``chunk`` rows, the last filled up with the
+    group's first members so that it runs on the program of the others."""
     from gatekeeper_tpu.apis.constraints import AUDIT_EP
     from gatekeeper_tpu.parallel.sharded import violation_rows
     from gatekeeper_tpu.utils.rawjson import RawJSON
@@ -245,15 +257,20 @@ def device_pairs(program, groups: dict) -> set:
     pairs: set = set()
     for g, members in groups.items():
         cons_g = [c for c in constraints if c.kind in g]
-        chunk = [RawJSON(raw) for _, raw in members]
-        swept = program.evaluator.sweep(cons_g, chunk, return_bits=True)
-        missing = {c.kind for c in cons_g} - set(swept)
-        if missing:
-            raise RuntimeError(f"not evaluated on the device: {missing}")
-        for kcons, _idx, _valid, _counts, hits in swept.values():
-            for ci, con in enumerate(kcons):
-                for oi in violation_rows(hits, ci, len(chunk)):
-                    pairs.add((tuple(con.key()), members[int(oi)][0]))
+        step = chunk or len(members)
+        for lo in range(0, len(members), step):
+            part = members[lo:lo + step]
+            if lo:
+                part = part + members[:step - len(part)]
+            rows = [RawJSON(raw) for _, raw in part]
+            swept = program.evaluator.sweep(cons_g, rows, return_bits=True)
+            missing = {c.kind for c in cons_g} - set(swept)
+            if missing:
+                raise RuntimeError(f"not evaluated on the device: {missing}")
+            for kcons, _idx, _valid, _counts, hits in swept.values():
+                for ci, con in enumerate(kcons):
+                    for oi in violation_rows(hits, ci, len(rows)):
+                        pairs.add((tuple(con.key()), part[int(oi)][0]))
     return pairs
 
 
@@ -314,6 +331,21 @@ def run(run: Run) -> dict:
     run.mark("corpus")
     sample_path = os.path.join(run.work, "sample.tsv")
     sample = read_sample(paths, sample_path)
+    epochs = None
+    if traffic.get("churn"):
+        if cfg["audit"]["exact_totals"]:
+            raise ValueError("a churn mix holds totals of violating objects; "
+                             "under exact_totals they are result counts")
+        # the first epochs now, so that the interpreter reviews its sample
+        # of the changed objects beside set-up; the rest once set-up's
+        # passes have said how many the window can hold
+        epochs = churn.Epochs(run, cfg, traffic["churn"], paths)
+        epochs.make(churn.epochs_least(traffic["churn"]))
+        epochs.wait()
+        with open(sample_path, "ab") as f:
+            for vid, raw in epochs.sample_first():
+                f.write(b"%d\t" % vid + raw + b"\n")
+        run.mark("churn_first_epochs")
     inventory = [p + ".inv" for p in paths]
     ref = reference.Children(run.spawn, cfg, "audit", run.seed, inventory,
                              sample_path, run.work,
@@ -322,12 +354,145 @@ def run(run: Run) -> dict:
     program = wiring.Program(cfg, run.traced, run.seed, run.cell.chips)
     run.mark("program_library")
     try:
-        return _measure(run, program, paths, counts, sample, ref, inventory)
+        return _measure(run, program, paths, counts, sample, ref, inventory,
+                        epochs)
     finally:
         program.close()
 
 
-def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
+def settle(run: Run, mgr, epochs) -> list:
+    """Set-up's churned passes, [(epoch, the pass, executables it asked
+    for, its seconds)]: an epoch is brought in and the cluster audited until
+    a pass asks XLA for nothing, at most ``settle_passes`` times.  The first
+    drift a boot meets is set-up's; what recurs is the window's."""
+    out = []
+    for _ in range(int(epochs.block["settle_passes"])):
+        epochs.bring_in()
+        t = time.monotonic()
+        audited = mgr.audit()
+        seconds = time.monotonic() - t
+        asked = run.compiles_between(t, t + seconds)
+        out.append((len(epochs.rows) - 1, audited, asked, seconds))
+        if not asked:
+            break
+    return out
+
+
+def churn_problems(run: Run, program, epochs, paths: list, inventory: list,
+                   router, first, settled: list, runs: list,
+                   pass_epoch: list, reviewed: dict, compared: dict) -> list:
+    """``correct`` through the changes, once the window has closed; the
+    numbers go into ``compared``, what they found is returned as text.
+    ``reviewed``: {version number: {constraint key: [messages]}} of the
+    interpreter's sample of the first epochs' changed objects, which it
+    reviewed beside set-up; its sample of the later epochs' and the objects
+    three passes kept violations of go to children started here."""
+    cfg = run.cell.config
+    n, limit = epochs.n, cfg["audit"]["violations_limit"]
+    rows = epochs.rows
+    base = churn.read_positions(
+        paths, {pos for changes in rows for pos, _op, _prev, _raw in changes})
+    versions = dict(base)
+    for e, changes in enumerate(rows):
+        for pos, _op, _prev, raw in changes:
+            versions[churn.version_id(n, e, pos)] = raw
+    later = dict(epochs.sample_later())
+    sampled = {**dict(epochs.sample_first()), **later}
+    versions.update(sampled)
+    # every changed object, as the corpus had it and as each epoch made it,
+    # on the device alone: nothing of the memo or of a pass is in this sweep
+    pairs = device_pairs(program, by_group(router, list(versions.items())),
+                         cfg["audit"]["chunk_size"])
+    run.mark("churn_sweep")
+    problems = []
+    ledger = churn.Ledger(n, rows, base, pairs, first.total_violations)
+    compared["touch_moved_a_verdict"] = ledger.touch_moved
+    if ledger.touch_moved:
+        problems.append(f"{ledger.touch_moved} rewrites of resourceVersion "
+                        "changed a verdict of the device")
+    compared.update(kept_short=0, kept_stale=0, kept_unfounded=0)
+    passes = [(e, r, f"settle pass {i}")
+              for i, (e, r, _asked, _s) in enumerate(settled)]
+    passes += [(e, r, f"pass {i}")
+               for i, (e, r) in enumerate(zip(pass_epoch, runs))]
+    for e, r, name in passes:
+        if r.incomplete or r.total_objects != n or e < 0:
+            continue  # counted as passes_short or epochs_ran_out
+        found = ledger.pass_problems(e, r, limit)
+        compared["passes_differ"] += int(found["totals"] > 0)
+        for key in ("kept_short", "kept_stale", "kept_unfounded"):
+            compared[key] += found[key]
+        if any(found.values()):
+            problems.append(f"{name}, epoch {e}: {found}")
+    # the interpreter on the later epochs' sample and on what the first,
+    # the middle and the last pass kept
+    k = churn.RENDERED_PASSES
+    at = sorted({(len(runs) - 1) * i // max(1, k - 1) for i in range(k)}
+                if runs else ())
+    unchanged = churn.locate(paths, {
+        name for i in at for name in churn.kept_names(runs[i])
+        if name not in ledger.position})
+    kept_of = []
+    by_raw: dict = {}  # the bytes listed -> their line of the review's input
+    for raw in later.values():
+        by_raw.setdefault(raw, len(by_raw))
+    for i in at:
+        listed, missing = ledger.kept_versions(pass_epoch[i], runs[i],
+                                               unchanged)
+        compared["kept_stale"] += len(missing)
+        if missing:
+            problems.append(f"pass {i} keeps violations of objects no one "
+                            f"listed: {sorted(missing)[:3]}")
+        kept_of.append((i, listed))
+        for raw in listed.values():
+            by_raw.setdefault(raw, len(by_raw))
+    path = os.path.join(run.work, "kept.tsv")
+    with open(path, "wb") as f:
+        for raw, rid in by_raw.items():
+            f.write(b"%d\t" % rid + raw + b"\n")
+    results: dict = {}
+    if by_raw:
+        results = reference.by_index(reference.Children(
+            run.spawn, cfg, "audit", run.seed, inventory, path, run.work,
+            churn.children_for(len(by_raw), churn.REVIEWED_A_CHILD),
+            tag="kept").join())
+    run.mark("churn_kept_review")
+    by_bytes = {raw: results[rid] for raw, rid in by_raw.items()
+                if rid in results}
+    reviewed = dict(reviewed)
+    reviewed.update((vid, by_bytes[raw]) for vid, raw in later.items()
+                    if raw in by_bytes)
+    interp = {(key, vid) for vid, per in reviewed.items() for key in per}
+    device = {(key, vid) for key, vid in pairs if vid in sampled}
+    compared["churn_pairs_differ"] = len(device ^ interp)
+    if device != interp:
+        problems.append(
+            f"device sweep != interpreter on {len(sampled)} changed objects: "
+            f"{len(device - interp)} device-only, {len(interp - device)} "
+            f"interpreter-only, e.g. {sorted(device ^ interp)[:3]}")
+    # every one of the first epochs and the last one brought in
+    owed = set(range(churn.SAMPLE_FIRST_EPOCHS)) | {len(rows) - 1}
+    epochs_sampled = {vid // n - 1 for vid in sampled if vid in reviewed}
+    compared["churn_sample_short"] = int(
+        not owed <= epochs_sampled or set(sampled) != set(reviewed))
+    if compared["churn_sample_short"]:
+        problems.append(f"the interpreter reviewed {len(reviewed)} of "
+                        f"{len(sampled)} sampled changed objects, of the "
+                        f"epochs {sorted(epochs_sampled)}; owed "
+                        f"{sorted(owed)}")
+    compared["kept_messages_differ"] = 0
+    for i, listed in kept_of:
+        bad = churn.kept_message_problems(runs[i], listed, by_bytes)
+        compared["kept_messages_differ"] += bad
+        if bad:
+            problems.append(f"pass {i}: {bad} constraints keep violations "
+                            "the interpreter does not report of the bytes "
+                            "listed")
+    return problems
+
+
+def _measure(run, program, paths, counts, sample, ref, inventory,
+             epochs=None) -> dict:
     cfg, traffic = run.cell.config, run.cell.traffic
     n_objects = int(cfg["objects"])
     chunk = cfg["audit"]["chunk_size"]
@@ -342,7 +507,8 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
     groups = by_group(router, sample)
     sample_corpus = os.path.join(run.work, "sample.corpus.jsonl")
     order = write_sample_corpus(groups, sizes, chunk, sample_corpus)
-    lister = lister_of(paths)
+    lister = (lister_of(paths) if epochs is None
+              else churn.lister_of(paths, epochs.overlay))
     mgr = program.build_audit(lister)
     ev = program.evaluator
     # the lane the passes will sweep in: return_bits under exact totals
@@ -356,18 +522,33 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
                                                     time.monotonic())
     first, setup_pass_s, setup_passes = settled_pass(mgr, ev)
     run.mark("setup_pass")
+    settled: list = []
+    if epochs is not None:
+        settled = settle(run, mgr, epochs)
+        # the children make the rest beside the sample's sweep, as many as
+        # the window can hold at the pace of the fastest pass so far: a
+        # pass that compiled (the set-up pass of a checkout's first run)
+        # says nothing of the window's
+        epochs.make(churn.epochs_wanted(epochs.block, run.seconds, min(
+            [setup_pass_s] + [s for _e, _r, _asked, s in settled])))
+        run.mark("churn_settle")
     program.begin_background_compile()
     want = canonical_run(first)
     device = device_pairs(program, groups)
     run.mark("sample_sweep")
-    results: dict = {}  # corpus index -> {constraint key: [messages]}
-    for part in ref.join():
-        for idx, rows in part:
-            for kind, name, msg in rows:
-                results.setdefault(idx, {}).setdefault(
-                    (kind, name), []).append(msg)
+    # corpus index -> {constraint key: [messages]}; the changed objects'
+    # version numbers lie above every corpus index
+    results, reviewed = {}, {}
+    for idx, per in reference.by_index(ref.join()).items():
+        if idx >= n_objects:
+            reviewed[idx] = per
+        elif per:
+            results[idx] = per
     interp = {(key, idx) for idx, per in results.items() for key in per}
     run.mark("reference_join")
+    if epochs is not None:
+        epochs.wait()
+        run.mark("churn_epochs")
     ident = {}
     for idx, raw in sample:
         meta = json.loads(raw)
@@ -394,6 +575,9 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
 
     # --- the window ---------------------------------------------------------
     passes: list = []      # wall seconds of each whole pass
+    began: list = []       # time.monotonic() at each one's start
+    pass_epoch: list = []  # the newest epoch in each one's cluster
+    ran_out = 0            # passes that found no epoch left to bring in
     runs: list = []
     fallbacks_in_setup = int(ev.perf.get("collect_fallbacks", 0))
     ev.perf_reset()
@@ -406,14 +590,22 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
     traced_passes = 0
     if run.traced:
         run.watch_gc()
+    vocabulary_w0 = len(program.tpu.vocab)
+    brought_in = len(epochs.rows) if epochs is not None else 0
     w0 = time.monotonic()
     w0_wall = time.time()
     setup_s = w0 - run.t0
 
     def one_pass() -> None:
+        nonlocal ran_out
+        if epochs is not None:
+            # between two passes, outside either's clock
+            ran_out += int(not epochs.bring_in())
+            pass_epoch.append(len(epochs.rows) - 1)
         t = time.monotonic()
         runs.append(mgr.audit())
         passes.append(time.monotonic() - t)
+        began.append(t)
 
     def room() -> bool:
         # a pass counts only if it ends inside the window, so none starts
@@ -431,6 +623,14 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
         else:
             one_pass()
     w1 = time.monotonic()
+    w1_wall = time.time()
+    vocabulary_w1 = len(program.tpu.vocab)
+    # the window's own: what a churn mix sweeps after it adds to ev.perf
+    perf = {"evaluator": dict(ev.perf), "manager": dict(mgr.perf)}
+    if epochs is not None:
+        # the sweep of the changed objects below is no part of the cell
+        run.memory_peak = run.memory_peak_bytes()
+        run.mark("window")
 
     attempted = failed = 0
     chunks_per_pass = sum(-(-n // chunk) for n in sizes.values())
@@ -443,19 +643,29 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
             compared["passes_short"] += 1
             problems.append(f"pass {i}: incomplete={r.incomplete}, "
                             f"{r.total_objects} objects")
-        elif canonical_run(r) != want:
+        elif epochs is None and canonical_run(r) != want:
             compared["passes_differ"] += 1
             problems.append(f"pass {i}: totals or kept violations differ "
                             "from the set-up pass")
-    fallbacks = int(ev.perf.get("collect_fallbacks", 0))
+    fallbacks = int(perf["evaluator"].get("collect_fallbacks", 0))
     failed += fallbacks
     if not passes:
         problems.append("no whole pass fits the window")
+    changed_in_window = 0
+    if epochs is not None:
+        changed_in_window = sum(epochs.changed[brought_in:])
+        compared["epochs_ran_out"] = ran_out
+        if ran_out:
+            problems.append(f"{ran_out} passes found none of the "
+                            f"{epochs.made} epochs left")
+        problems += churn_problems(run, program, epochs, paths, inventory,
+                                   router, first, settled, runs, pass_epoch,
+                                   reviewed, compared)
     for p in problems:
         print(f"benchmark: {p}", file=sys.stderr)
 
     compiles = run.compiles_between(w0, w1)
-    spans = program.spans(w0_wall) if run.traced else []
+    spans = program.spans(w0_wall, w1_wall) if run.traced else []
     reduced = None
     if traced_passes:
         reduced = dict(run.reduce_trace(spans), passes=traced_passes)
@@ -463,11 +673,17 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
         raise RuntimeError(f"the window held {len(passes)} passes: too few "
                            "to trace")
     obs = {
-        "perf": {"evaluator": dict(ev.perf), "manager": dict(mgr.perf)},
+        "perf": perf,
         "passes": len(passes), "objects": n_objects,
         "constraints": len(first.kept),
         "spans": spans, "hist": {},
-        "counts": {"compiles_in_window": compiles},
+        "counts": {"compiles_in_window": compiles,
+                   "changed_share": (changed_in_window
+                                     / (len(passes) * n_objects)
+                                     if passes else None),
+                   "new_strings_per_pass": (
+                       (vocabulary_w1 - vocabulary_w0) / len(passes)
+                       if passes else None)},
         "full_gc_s": run.full_gc_s_between(w0, w1) if run.traced else None,
         "loadgen": None, "trace": reduced,
     }
@@ -495,6 +711,17 @@ def _measure(run, program, paths, counts, sample, ref, inventory) -> dict:
         "xla_cache_dir": program.xla_cache_dir,
         "problems": problems,
     }
+    if epochs is not None:
+        notes["churn"] = {
+            "epochs_made": epochs.made, "epochs_brought_in": len(epochs.rows),
+            "changed": epochs.changed,
+            "settle": [{"epoch": e, "executables_asked_for": asked,
+                        "seconds": seconds}
+                       for e, _r, asked, seconds in settled],
+            "executables_by_pass": [
+                run.compiles_between(t, t + s)
+                for t, s in zip(began, passes)],
+            "vocabulary": [vocabulary_w0, vocabulary_w1]}
     e2e = {"setup_s": setup_s}
     if passes:
         e2e["audit_pass_s"] = stats.mean(passes)

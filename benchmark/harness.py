@@ -46,6 +46,9 @@ class Run:
         # the interpreter's garbage collector, once watch_gc() was called
         self.full_gcs: list = []
         self.device = None
+        # the device's peak where a run reads it before its result is made
+        # (what it sweeps after its window is no part of the cell)
+        self.memory_peak = None
         self.peaks = None
         self.marks: dict = {}  # set-up phase -> seconds since the last mark
         self._marked = t0
@@ -175,8 +178,9 @@ class Run:
         seconds and the breakdown.  ``compared``: {name: {"value",
         "limit"}} of every number that decided ``correct``; it comes last
         in the line and is the last that standard error says."""
-        device = dict(self.device,
-                      memory_peak_bytes=self.memory_peak_bytes())
+        device = dict(self.device, memory_peak_bytes=(
+            self.memory_peak_bytes() if self.memory_peak is None
+            else self.memory_peak))
         out = {"correct": bool(correct), "attempted": int(attempted),
                "failed": int(failed)}
         if not self.traced:

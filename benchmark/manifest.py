@@ -51,10 +51,13 @@ def apply_rehearsal(doc: dict) -> dict:
 
 class Cell:
     """A configuration under a traffic mix, with everything it names,
-    loaded: an entry of ``workloads`` (``Cell(name)``), or a pair that is
-    no cell yet (``Cell.unlisted``)."""
+    loaded: an entry of ``workloads`` (``Cell(name)``), that entry under a
+    mix no cell lists yet (``Cell(name, traffic=...)``: the cell is the
+    mix's control, and lends it its configuration and its metrics), or a
+    pair that is no cell yet and held to no metric (``Cell.unlisted``)."""
 
-    def __init__(self, name: str, rehearse: bool = False):
+    def __init__(self, name: str, rehearse: bool = False,
+                 traffic: str | None = None):
         m = read_json(MANIFEST)
         cells = {w["name"]: w for w in m["workloads"]}
         if name not in cells:
@@ -62,8 +65,9 @@ class Cell:
                            f"has {sorted(cells)}")
         entry = cells[name]
         cfg = next(c for c in m["configs"] if c["name"] == entry["config"])
-        self._load(name, os.path.join(ROOT, cfg["file"]), entry["traffic"],
-                   int(entry["chips"]), rehearse)
+        self._load(name if traffic is None else f"{name}.under.{traffic}",
+                   os.path.join(ROOT, cfg["file"]),
+                   traffic or entry["traffic"], int(entry["chips"]), rehearse)
         # the mix says which end-to-end metrics its kind of load yields;
         # the manifest says which of them this cell is held to
         self.end_to_end = [e for e in m["end_to_end"]
@@ -150,6 +154,11 @@ def check() -> list:
             if missing:
                 faults.append(f"cell {w['name']}: traffic {w['traffic']!r} "
                               f"does not yield {sorted(missing)}")
+    for file in sorted(os.listdir(os.path.join(HERE, "traffic"))):
+        mix = read_json(os.path.join(HERE, "traffic", file))
+        if "churn" in mix and "assumed" not in mix:
+            faults.append(f"traffic {mix['name']!r} changes the cluster and "
+                          "lists nothing under 'assumed'")
     for metric in m["end_to_end"] + m["per_layer"]:
         if metric["source"] not in SOURCES:
             faults.append(f"metric {metric['name']}: source "

@@ -86,12 +86,13 @@ class Children:
     that none outlives the run."""
 
     def __init__(self, spawn, config: dict, mode: str, seed: int,
-                 inventory: list, input_path: str, work_dir: str, parts: int):
+                 inventory: list, input_path: str, work_dir: str, parts: int,
+                 tag: str = "reference"):
         self.outputs = []
         self.procs = []
         for part in range(parts):
-            job = os.path.join(work_dir, f"reference.{part}.job.json")
-            out = os.path.join(work_dir, f"reference.{part}.out.json")
+            job = os.path.join(work_dir, f"{tag}.{part}.job.json")
+            out = os.path.join(work_dir, f"{tag}.{part}.out.json")
             with open(job, "w") as f:
                 json.dump({"config": config, "mode": mode, "seed": seed,
                            "inventory": inventory, "input": input_path,
@@ -110,6 +111,18 @@ class Children:
             with open(path) as f:
                 outs.append(json.load(f))
         return outs
+
+
+def by_index(parts: list) -> dict:
+    """{index: {(constraint kind, constraint name): [messages]}} of audit
+    children's outputs, an entry for every line reviewed."""
+    out: dict = {}
+    for part in parts:
+        for idx, rows in part:
+            per = out.setdefault(idx, {})
+            for kind, name, msg in rows:
+                per.setdefault((kind, name), []).append(msg)
+    return out
 
 
 if __name__ == "__main__":

@@ -3,12 +3,16 @@
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
     python benchmark/run.py --check
     python benchmark/run.py --workload <cell> --rehearse [...]
+    python benchmark/run.py --workload <cell> --traffic <mix> [...]
 
 One process that holds the chip: it loads the cell's configuration and
 traffic mix, sets up, measures for ``--seconds``, prints one line of JSON
 last and exits.  Without a TPU (or with fewer chips than the cell asks for)
 it prints no result and exits non-zero; it never falls back.  ``--rehearse``
 is the only CPU mode: toy sizes, for the sandbox, and its line says so.
+``--traffic`` runs the cell's configuration, held to the cell's metrics,
+under a mix that no cell lists yet (``traffic/audit-churn.json``, whose
+control is ``full.audit-sweep``): a builder's reading, never the driver's.
 """
 
 from __future__ import annotations
@@ -33,6 +37,8 @@ def main(argv=None) -> int:
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--rehearse", action="store_true",
                    help="toy sizes on whatever JAX finds; never a result")
+    p.add_argument("--traffic", default=None,
+                   help="a mix of benchmark/traffic/ in place of the cell's")
     p.add_argument("--check", action="store_true",
                    help="check BENCHMARK.json against the files it names")
     args = p.parse_args(argv)
@@ -47,7 +53,8 @@ def main(argv=None) -> int:
         return 1 if faults else 0
     if not args.workload:
         p.error("--workload is required")
-    cell = manifest.Cell(args.workload, rehearse=args.rehearse)
+    cell = manifest.Cell(args.workload, rehearse=args.rehearse,
+                         traffic=args.traffic)
     seconds = args.seconds
     if seconds is None:
         seconds = manifest.read_json(manifest.MANIFEST)["run_seconds"]
