@@ -2051,22 +2051,31 @@ class AuditManager:
         ``exact``: totals count RESULTS via bit-packed hit rows; otherwise
         totals are the device's violating-object counts and only top-k
         hits render.  ``budget(con)`` -> remaining run-level kept slots for
-        a constraint (defaults to ``limit``): in the non-exact path a
-        constraint whose run budget is exhausted renders NOTHING for this
-        chunk — without it every chunk re-renders up to ``limit`` hits per
-        constraint through the exact interpreter only to drop them at the
-        run-level cap (~(n_chunks-1)x wasted render work on
-        violation-dense corpora)."""
-        for kind, (kcons, idx, valid, counts, bits) in swept.items():
+        a constraint (defaults to ``limit``): a constraint whose run budget
+        is exhausted renders NOTHING for this chunk — without it every
+        chunk re-renders up to ``limit`` hits per constraint through the
+        exact interpreter only to drop them at the run-level cap
+        (~(n_chunks-1)x wasted render work on violation-dense corpora).
+        In the exact path a row whose device count is its result total
+        (``SweptRows.results``, not -1) renders its lowest-index hits only
+        until the kept slots are filled, which keeps what rendering every
+        hit kept; any other row renders every hit for its total."""
+        for kind, entry in swept.items():
+            kcons, idx, valid, counts, bits = entry
+            counted = getattr(entry, "results", False)
             for ci, con in enumerate(kcons):
                 kept_list: list = []
                 cap = limit if budget is None else min(limit, budget(con))
                 if exact and bits is not None:
-                    # exact totals count RESULTS: every hit must render
-                    # regardless of remaining kept budget
-                    hit_idx = violation_rows(bits, ci, n_objects)
+                    # a row the device counted renders only until its kept
+                    # slots are filled; any other row renders every hit,
+                    # whatever the kept budget, for its total
+                    device_total = int(counts[ci]) \
+                        if counted and counts[ci] >= 0 else None
                     total = 0
-                    for oi in hit_idx.tolist():
+                    for oi in violation_rows(bits, ci, n_objects).tolist():
+                        if device_total is not None and len(kept_list) >= cap:
+                            break
                         results = render(con, oi)
                         total += len(results)
                         for r in results:
@@ -2074,6 +2083,8 @@ class AuditManager:
                                 kept_list.append(
                                     (oi, r.msg,
                                      (r.metadata or {}).get("details")))
+                    if device_total is not None:
+                        total = device_total
                 else:
                     total = int(counts[ci])
                     for j in range(idx.shape[1]):

@@ -45,8 +45,9 @@ from typing import Any, Optional
 
 from gatekeeper_tpu.ops.flatten import FLATTEN_SCHEMA_VERSION
 
-# bump when the on-disk payload layout changes
-CACHE_FORMAT = 1
+# bump when the on-disk payload layout changes (2: a Program carries the
+# result count its lowering proved)
+CACHE_FORMAT = 2
 
 # miss reasons for gatekeeper_generation_cache_miss_count{reason}
 MISS_COLD = "cold"          # no entry on disk

@@ -1712,4 +1712,8 @@ def lower_cel_template(compiled, template_kind: str, vocab,
         expr=expr,
         params=tuple(params),
         schema=low.schema,
+        # the evaluator returns one result per validation that is not true
+        # (false, or an error under failurePolicy Fail): the count is the
+        # number of these, with no dedupe (drivers/cel_driver.py query)
+        count=N.CountSum(tuple(N.CountIf(v) for v in violations)),
     )

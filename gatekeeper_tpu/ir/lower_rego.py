@@ -386,27 +386,37 @@ class _Lowerer:
         if rule is None or rule.kind != "set":
             raise LowerError("no violation set rule")
         clause_exprs = []
+        # per clause: (verdict, what the count needs of its lowering)
+        self.clauses: list = []
         for clause in rule.clauses:
             if clause.els is not None:
                 raise LowerError("else on violation clause")
-            clause_exprs.append(self._lower_body(clause.body, {}))
+            seen = {"head": _head_msg_var(clause)}
+            expr = self._lower_body(clause.body, {}, seen)
+            clause_exprs.append(expr)
+            self.clauses.append((expr, seen))
         if not clause_exprs:
             raise LowerError("violation rule has no clauses")
         return N.Or(tuple(clause_exprs)) if len(clause_exprs) > 1 else clause_exprs[0]
 
     # --- body lowering ----------------------------------------------------
-    def _lower_body(self, body, env: dict) -> N.Expr:
-        terms, open_groups = self._lower_body_parts(body, env, None)
+    def _lower_body(self, body, env: dict, seen: Optional[dict] = None
+                    ) -> N.Expr:
+        terms, open_groups = self._lower_body_parts(body, env, None, seen)
         assert not open_groups  # open_upto=None closes everything
         if not terms:
             raise LowerError("clause lowered to no predicates")
         return N.And(tuple(terms)) if len(terms) > 1 else terms[0]
 
-    def _lower_body_parts(self, body, env: dict, open_upto):
+    def _lower_body_parts(self, body, env: dict, open_upto,
+                          seen: Optional[dict] = None):
         """Lower a conjunction.  Groups whose every existential instance was
         created at or before ``open_upto`` (caller bindings of an inlined
         function) are returned OPEN for the caller's assembly to merge;
-        everything else closes here.  Returns (closed_terms, open_groups)."""
+        everything else closes here.  Returns (closed_terms, open_groups).
+        ``seen`` (a violation clause's own body): records the term and the
+        bindings the head variable ``seen["head"]`` is assigned from, and
+        each closed term beside the existential group it reduces."""
         env = dict(env)
         obj_preds: list[N.Expr] = []
         # group key: ("axis", Axis, inst) | ("param", name, inst)
@@ -432,6 +442,9 @@ class _Lowerer:
                 term = stmt.term if isinstance(stmt, ast.AssignStmt) else stmt.rhs
                 if not isinstance(target, ast.Var):
                     raise LowerError("destructuring assignment")
+                if seen is not None and target.name == seen["head"]:
+                    # a head assigned twice has no one shape to count
+                    seen["msg"] = None if "msg" in seen else (term, dict(env))
                 bound = self._abstract(term, env)
                 # an assignment in Rego fails when its RHS is undefined; even
                 # message-only assignments gate the clause, so emit their
@@ -633,12 +646,16 @@ class _Lowerer:
                 if group[2] <= open_upto:
                     open_groups[group] = axis_preds.pop(group)
         terms = list(obj_preds)
+        parts = [(None, t, None) for t in obj_preds]
         for group, preds in axis_preds.items():
             inner = N.And(tuple(preds)) if len(preds) > 1 else preds[0]
             if group[0] == "axis":
                 terms.append(N.AnyAxis(group[1], inner))
             else:  # param-element existential
                 terms.append(N.AnyParamList(group[1], inner))
+            parts.append((group, terms[-1], inner))
+        if seen is not None:
+            seen["parts"] = parts
         if not terms and not open_groups:
             raise LowerError("clause lowered to no predicates")
         return terms, open_groups
@@ -2118,6 +2135,252 @@ class _Lowerer:
         self.param_kinds[name] = kind
 
 
+def _head_msg_var(clause) -> Optional[str]:
+    """The variable of a ``violation[{"msg": msg}]`` head, else None."""
+    key = clause.key
+    if (isinstance(key, ast.ObjectTerm) and len(key.pairs) == 1
+            and key.pairs[0][0] == ast.Scalar("msg")
+            and isinstance(key.pairs[0][1], ast.Var)):
+        return key.pairs[0][1].name
+    return None
+
+
+def _format_tokens(fmt: str, args: list) -> list:
+    """The message ``sprintf(fmt, args)`` prints, as tokens: ``("lit",
+    text)`` and the argument values in between.  Only ``%v`` and ``%s``
+    (both print a string as it is) and ``%%``."""
+    out: list = []
+    lit: list = []
+    i = n = 0
+    while i < len(fmt):
+        ch = fmt[i]
+        if ch != "%":
+            lit.append(ch)
+            i += 1
+            continue
+        verb = fmt[i + 1: i + 2]
+        if verb == "%":
+            lit.append("%")
+        elif verb in ("v", "s") and n < len(args):
+            out.append(("lit", "".join(lit)))
+            lit = []
+            out.append(args[n])
+            n += 1
+        else:
+            raise LowerError(f"count: format verb %{verb}")
+        i += 2
+    if n != len(args):
+        raise LowerError("count: format arity")
+    out.append(("lit", "".join(lit)))
+    return out
+
+
+class _Counter:
+    """The result count of a lowered Rego template, proved from its text.
+
+    ``RegoDriver`` returns one result per distinct value of the violation
+    set.  A clause whose head is ``{"msg": msg}`` with ``msg`` a constant or
+    ``sprintf`` of a constant format gives:
+
+    - one value where it holds, if its arguments name nothing iterated
+      (the object's own fields, the review's, parameters, constants and
+      arithmetic over them): every solution prints the same message;
+    - one value per distinct tuple of the item fields it prints, among the
+      satisfying items of one existential (``c := input_containers[_]``;
+      a union axis is one), if every iterated argument is a field or the
+      map key of that one item.  Two tuples print alike only where a value
+      holds the text that follows it, so each iterated argument but the
+      last must be followed by literal text, and a tuple is counted only
+      where its values are strings free of that text's first character
+      (the device's per-pair certification; elsewhere the pair renders).
+
+    Clauses whose messages are the same tokens with no item in them are
+    one value: their conditions are OR-ed.  Any other two clauses must
+    print different messages, which :meth:`_distinct` proves from their
+    literal text; where it cannot, or a head has another shape, the
+    template has no count."""
+
+    def __init__(self, low: "_Lowerer"):
+        self.low = low
+        self.chars: set = set()  # delimiters every item key must not hold
+
+    def count(self) -> N.Expr:
+        """The number of results ``RegoDriver`` returns for a violating
+        pair: the distinct values of the violation set, over every clause;
+        LowerError where the template's text does not prove it."""
+        heads = [self._head(expr, seen) for expr, seen in self.low.clauses]
+        classes: list = []  # [tokens, [clause heads]]
+        for head in heads:
+            same = next((c for c in classes if c[0] == head["tokens"]
+                         and head["group"] is None), None)
+            if same is not None:
+                same[1].append(head)
+            else:
+                classes.append([head["tokens"], [head]])
+        for i, (ta, _ha) in enumerate(classes):
+            for tb, _hb in classes[i + 1:]:
+                if not self._distinct(ta, tb):
+                    raise LowerError("count: clause messages may coincide")
+        chars = "".join(sorted(self.chars))
+        terms = []
+        for _tokens, hs in classes:
+            head = hs[0]
+            if head["group"] is None:
+                cond = N.Or(tuple(h["expr"] for h in hs)) if len(hs) > 1 \
+                    else head["expr"]
+                terms.append(N.CountIf(cond))
+            else:
+                terms.append(N.CountDistinct(
+                    head["pre"], head["group"][1], head["inner"],
+                    head["keys"], chars))
+        return N.CountSum(tuple(terms)) if len(terms) > 1 else terms[0]
+
+    def _head(self, expr: N.Expr, seen: dict) -> dict:
+        if seen["head"] is None or seen.get("msg") is None:
+            raise LowerError("count: head is not {\"msg\": msg}")
+        term, env = seen["msg"]
+        if isinstance(term, ast.Scalar) and isinstance(term.value, str):
+            tokens = [("lit", term.value)]
+        elif (isinstance(term, ast.Call) and term.op == "sprintf"
+              and len(term.args) == 2
+              and isinstance(term.args[0], ast.Scalar)
+              and isinstance(term.args[0].value, str)
+              and isinstance(term.args[1], ast.ArrayTerm)):
+            args = [self._token(self.low._abstract(a, dict(env)))
+                    for a in term.args[1].items]
+            tokens = _format_tokens(term.args[0].value, args)
+        else:
+            raise LowerError("count: message is no sprintf of a constant")
+        tokens = _merge_literals(tokens)
+        items = [t[1] for t in tokens if t[0] == "item"]
+        head = {"tokens": tokens, "expr": expr, "group": None}
+        if not items:
+            return head
+        groups = {("axis", v.axis, v.instance) for v in items}
+        if len(groups) != 1:
+            raise LowerError("count: message names two existentials")
+        group = groups.pop()
+        part = next((p for p in seen["parts"] if p[0] == group), None)
+        if part is None:
+            raise LowerError("count: message item reduced inside another")
+        keys: list = []
+        for v in items:
+            key = self._key(v)
+            if key not in keys:
+                keys.append(key)
+        # every item but the last must be delimited by the text after it
+        for i, tok in enumerate(tokens):
+            if tok[0] != "item" or all(t[0] != "item"
+                                       for t in tokens[i + 1:]):
+                continue
+            nxt = tokens[i + 1] if i + 1 < len(tokens) else None
+            if nxt is None or nxt[0] != "lit":
+                raise LowerError("count: items with no text between them")
+            self.chars.add(nxt[1][0])
+        pre = [p[1] for p in seen["parts"] if p[0] != group]
+        head.update(
+            group=group, inner=part[2], keys=tuple(keys),
+            pre=(N.And(tuple(pre)) if len(pre) > 1
+                 else pre[0] if pre else N.ConstBool(True)))
+        return head
+
+    def _token(self, val) -> tuple:
+        """A message argument: its text where constant, ``("item", v)``
+        for a field of an iterated item, ``("fix", ...)`` for a value that
+        is one per (object, constraint)."""
+        if isinstance(val, ConstVal):
+            if isinstance(val.value, (dict, list)):
+                raise LowerError("count: composite constant in message")
+            from gatekeeper_tpu.lang.rego.value import to_opa_string
+
+            return ("lit", to_opa_string(val.value, top=True))
+        if isinstance(val, (ItemVal, MapKeyVal)):
+            return ("item", val)
+        return ("fix", self._fixed(val))
+
+    def _fixed(self, val):
+        if isinstance(val, PathVal):
+            return ("path", val.path)
+        if isinstance(val, ParamVal):
+            return ("param", val.name)
+        if isinstance(val, ConstVal) and not isinstance(val.value,
+                                                        (dict, list)):
+            return ("const", val.value)
+        if isinstance(val, ArithVal):
+            return ("arith", val.op, self._fixed(val.a), self._fixed(val.b))
+        raise LowerError(f"count: message argument {type(val).__name__}")
+
+    def _key(self, val) -> N.Expr:
+        """The sid operand of an item key, over a column the verdict
+        already reads (the count adds no column to the schema)."""
+        schema = self.low.schema
+        if isinstance(val, MapKeyVal):
+            col = MapKeyCol(axis=val.axis)
+            if col in schema.map_keys:
+                return N.MapKeySid(col)
+        else:
+            col = RaggedCol(axis=val.axis, subpath=val.subpath)
+            if col in schema.raggeds:
+                return N.FeatSid(col)
+        raise LowerError("count: message item the verdict does not read")
+
+    def _distinct(self, a: list, b: list) -> bool:
+        """Whether two clauses' messages can never be equal: read from
+        the front, or from the back, through equal literals, equal
+        one-per-pair values and items that the same character delimits,
+        until two literals differ where neither is a prefix (or suffix)
+        of the other.  Of the two readings, the one that asks the items
+        for fewer delimiters stands."""
+        proofs = [p for p in (
+            _walk(a, b), _walk([_reversed(t) for t in reversed(a)],
+                               [_reversed(t) for t in reversed(b)]))
+            if p is not None]
+        if not proofs:
+            return False
+        self.chars |= min(proofs, key=len)
+        return True
+
+
+def _walk(a: list, b: list) -> Optional[set]:
+    """The delimiters a proof that two token lists print differently asks
+    of the items it reads through, or None where it finds no proof."""
+    need: set = set()
+    for i in range(min(len(a), len(b))):
+        ta, tb = a[i], b[i]
+        if ta[0] == tb[0] == "lit":
+            if ta[1] == tb[1]:
+                continue
+            if ta[1].startswith(tb[1]) or tb[1].startswith(ta[1]):
+                return None
+            return need
+        if ta[0] == tb[0] == "fix" and ta[1] == tb[1]:
+            continue
+        if ta[0] == tb[0] == "item" and i + 1 < min(len(a), len(b)):
+            na, nb = a[i + 1], b[i + 1]
+            if na[0] == nb[0] == "lit" and na[1][:1] == nb[1][:1] != "":
+                need.add(na[1][0])
+                continue
+        return None
+    return None
+
+
+def _merge_literals(tokens: list) -> list:
+    out: list = []
+    for tok in tokens:
+        if tok[0] == "lit":
+            if not tok[1]:
+                continue
+            if out and out[-1][0] == "lit":
+                out[-1] = ("lit", out[-1][1] + tok[1])
+                continue
+        out.append(tok)
+    return out
+
+
+def _reversed(tok: tuple) -> tuple:
+    return ("lit", tok[1][::-1]) if tok[0] == "lit" else tok
+
+
 def lower_template(modules, entry_pkg: tuple, template_kind: str,
                    vocab, schema_hint: Optional[dict] = None) -> N.Program:
     """Lower a compiled template to a Program, or raise LowerError."""
@@ -2126,6 +2389,10 @@ def lower_template(modules, entry_pkg: tuple, template_kind: str,
     # the reference is abstract-evaluated; pre-bind them as union axes.
     low.entry_axis_rules = _collect_axis_rules(low)
     expr = _with_axis_rules(low)
+    try:
+        count = _Counter(low).count()
+    except LowerError:
+        count = None  # the hits render for their count, as ever
     params = tuple(
         N.ParamSpec(
             name=k, kind=v,
@@ -2138,6 +2405,7 @@ def lower_template(modules, entry_pkg: tuple, template_kind: str,
         expr=expr,
         params=params,
         schema=low.schema,
+        count=count,
     )
 
 

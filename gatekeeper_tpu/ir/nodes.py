@@ -8,6 +8,8 @@ the "constraint-program × object batch" grid of SURVEY.md §5.7.
 
 Messages and details are NOT lowered: the device detects violations, the host
 renders messages by re-running the exact interpreter only on hits (sparse).
+How MANY results a hit has is lowered where the template's own text proves
+it (``Program.count``), so an exact audit total needs no render.
 """
 
 from __future__ import annotations
@@ -390,6 +392,42 @@ class AnyParamList(Expr):
 AnyParamStrList = AnyParamList  # historical alias
 
 
+# --- result counts --------------------------------------------------------
+# Integer expressions: how many results the interpreter returns for an
+# (object, constraint) pair whose verdict is true.  Each also says where its
+# count is certified; an uncertified pair's results must be rendered.
+
+
+@dataclass(frozen=True)
+class CountIf(Expr):
+    """One result where ``cond`` holds (a clause whose head names nothing
+    iterated, or the OR of clauses with one head)."""
+
+    cond: Expr
+
+
+@dataclass(frozen=True)
+class CountDistinct(Expr):
+    """Where ``pre`` holds: the number of distinct tuples of ``keys`` (sid
+    operands of the axis's items) among the items of ``axis`` that satisfy
+    ``inner``.  Certified where every such item's keys are strings that hold
+    none of ``chars`` (the characters that delimit one key from the next in
+    the message)."""
+
+    pre: Expr
+    axis: Axis
+    inner: Expr
+    keys: tuple  # FeatSid (RaggedCol on ``axis``) / MapKeySid
+    chars: str
+
+
+@dataclass(frozen=True)
+class CountSum(Expr):
+    """The sum of counts whose results can never be equal."""
+
+    terms: tuple
+
+
 @dataclass(frozen=True)
 class ConstBool(Expr):
     value: bool
@@ -427,9 +465,12 @@ class ParamSpec:
 
 @dataclass
 class Program:
-    """A lowered template: violation ⇔ expr true for (object, constraint)."""
+    """A lowered template: violation ⇔ expr true for (object, constraint).
+    ``count``, where the lowerer could prove it, is the number of results
+    the template's own engine returns for a violating pair."""
 
     template_kind: str
     expr: Expr
     params: tuple  # tuple[ParamSpec]
     schema: "object"  # ops.flatten.Schema with the columns this expr reads
+    count: Optional[Expr] = None

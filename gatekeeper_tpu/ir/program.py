@@ -110,6 +110,17 @@ def expr_nodes(program: N.Program) -> list:
     return out
 
 
+def count_nodes(program: N.Program) -> list:
+    """The nodes of the program's result count ([] where it has none).
+    Its predicates are the verdict's own sub-expressions, so the parameter
+    and inventory tables the verdict builds cover them; what the count
+    adds is the key columns' sids and the certification tables."""
+    out: list = []
+    if program.count is not None:
+        _walk_expr(program.count, out)
+    return out
+
+
 # --- vocab-derived tables (cached on the Vocab instance, extended lazily) --
 
 _STR_FNS = {
@@ -117,8 +128,15 @@ _STR_FNS = {
     "units.parse": None,
 }
 
+# the string function of a CountDistinct's certification: "nochars:<>" is
+# valid on the strings that hold neither "<" nor ">"
+NOCHARS = "nochars:"
+
 
 def _apply_str_fn(fn: str, s: str):
+    if fn.startswith(NOCHARS):
+        # valid where the string holds none of the characters named
+        return None if any(c in s for c in fn[len(NOCHARS):]) else 0.0
     if fn == "cel.quantity":
         # k8s resource.Quantity semantics (CEL quantity()/isQuantity())
         from gatekeeper_tpu.lang.cel.cel import _parse_quantity
@@ -588,17 +606,20 @@ class _ElemListSid(N.Expr):
 _ELEM_OF = _ElemListSid
 
 
-def needed_fields(program: N.Program) -> dict:
+def needed_fields(program: N.Program, counting: bool = False) -> dict:
     """col_key -> set of array fields the program's evaluator actually
-    reads.  Drives transfer slimming: the flattener materializes kind/num/
-    sid for every column, but e.g. a Truthy-only column never needs its num
-    or sid array on device."""
+    reads (``counting``: and its result count).  Drives transfer slimming:
+    the flattener materializes kind/num/sid for every column, but e.g. a
+    Truthy-only column never needs its num or sid array on device."""
     need: dict = {}
 
     def add(spec, *fields):
         need.setdefault(col_key(spec), set()).update(fields)
 
-    for node in expr_nodes(program):
+    nodes = expr_nodes(program)
+    if counting:
+        nodes += count_nodes(program)
+    for node in nodes:
         if isinstance(node, (N.Truthy, N.Present, N.KindIs)):
             add(node.col, "kind")
         elif isinstance(node, N.FeatNum):
@@ -802,9 +823,16 @@ def extdata_key_cols(program: N.Program) -> tuple:
     return out, extractable
 
 
-def vocab_tables(program: N.Program, vocab: Vocab) -> dict:
-    """Shared (non-vmapped) vocab-derived arrays for the cols dict."""
+def vocab_tables(program: N.Program, vocab: Vocab,
+                 counting: bool = False) -> dict:
+    """Shared (non-vmapped) vocab-derived arrays for the cols dict
+    (``counting``: and those of the program's result count)."""
     out = {}
+    if counting:
+        for node in count_nodes(program):
+            if isinstance(node, N.CountDistinct) and node.chars:
+                fn = NOCHARS + node.chars
+                out[f"fn:{fn}:ok"] = fn_table(vocab, fn)[1]
     for node in expr_nodes(program):
         if isinstance(node, N.StrFnNum):
             num, valid = fn_table(vocab, node.fn)
@@ -1321,9 +1349,60 @@ def eval_expr(ctx: _Ctx, e: N.Expr):
     raise LowerError(f"cannot evaluate IR node {e}")
 
 
+def eval_count(ctx: _Ctx, e: N.Expr):
+    """(results int32, certified bool), each [N] or broadcastable to it:
+    the result count of a program's ``count`` for one constraint row."""
+    if isinstance(e, N.CountSum):
+        cnt, ok = jnp.int32(0), jnp.bool_(True)
+        for t in e.terms:
+            c, o = eval_count(ctx, t)
+            cnt, ok = cnt + c, ok & o
+        return cnt, ok
+    if isinstance(e, N.CountIf):
+        return eval_expr(ctx, e.cond).astype(jnp.int32), jnp.bool_(True)
+    if isinstance(e, N.CountDistinct):
+        pre = eval_expr(ctx, e.pre)
+        counts = ctx.cols[axis_key(e.axis)]  # [N]
+        ctx.axis = e.axis
+        try:
+            inner = eval_expr(ctx, e.inner)
+            keys = [_eval_sidlike(ctx, k) for k in e.keys]  # [N, M] each
+        finally:
+            ctx.axis = None
+        m = keys[0][0].shape[1]
+        valid = jnp.arange(m) < counts[:, None]
+        if getattr(inner, "ndim", 0) == 1:
+            inner = inner[:, None]  # item-independent: one answer per object
+        sat = inner & valid
+        # an item is counted where no earlier satisfying item has its keys
+        same = None
+        for sid, _is_str, _present in keys:
+            eq = sid[:, :, None] == sid[:, None, :]  # [N, item, earlier]
+            same = eq if same is None else same & eq
+        earlier = jnp.arange(m)[None, :] < jnp.arange(m)[:, None]
+        dup = jnp.any(same & earlier[None] & sat[:, None, :], axis=2)
+        cnt = jnp.where(pre, jnp.sum(sat & ~dup, axis=1, dtype=jnp.int32), 0)
+        good = None
+        for sid, is_str, _present in keys:
+            g = is_str & (sid >= 0)
+            if e.chars:
+                table = ctx.cols[f"fn:{NOCHARS}{e.chars}:ok"]
+                g = g & table[jnp.clip(sid, 0, table.shape[0] - 1)]
+            good = g if good is None else good & g
+        ok = jnp.logical_not(pre) | jnp.all(~sat | good, axis=1)
+        return cnt, ok
+    raise LowerError(f"cannot count IR node {e}")
+
+
 # --------------------------------------------------------------------------
 # compiled program
 # --------------------------------------------------------------------------
+
+
+def as_grid(x, shape: tuple):
+    """A vmapped count's [C] (object-independent) or [C, N] as [C, N]."""
+    x = jnp.asarray(x)
+    return jnp.broadcast_to(x.reshape(x.shape + (1,) * (2 - x.ndim)), shape)
 
 
 _PROG_UID = __import__("itertools").count(1)
@@ -1351,6 +1430,21 @@ class CompiledProgram:
 
         def batch_fn(param_table: dict, col_arrays: dict):
             return jax.vmap(lambda row: single(row, col_arrays))(param_table)
+
+        return batch_fn
+
+    def _build_count(self):
+        """The result count's twin of :meth:`_build`: (batch arrays, param
+        table) -> (results, certified), each [C] or [C, N]; None where the
+        lowerer proved no count."""
+        count = self.program.count
+        if count is None:
+            return None
+
+        def batch_fn(param_table: dict, col_arrays: dict):
+            return jax.vmap(
+                lambda row: eval_count(_Ctx(col_arrays, row), count))(
+                    param_table)
 
         return batch_fn
 

@@ -32,7 +32,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gatekeeper_tpu.ir.masks import selector_label_keys
-from gatekeeper_tpu.ir.program import (build_param_table, col_key,
+from gatekeeper_tpu.ir.program import (as_grid, build_param_table, col_key,
                                         needed_fields, pack_batch_cols,
                                         slim_cols, vocab_tables)
 from gatekeeper_tpu.ops import native
@@ -604,6 +604,60 @@ def template_grids(kinds, builders, tables, cols: dict) -> list:
     return grids
 
 
+def counted_kinds(progs, kinds, exact: bool) -> frozenset:
+    """The kinds of an exact-lane host sweep whose count column carries
+    result totals: those whose program has a proved ``count``."""
+    if not exact:
+        return frozenset()
+    return frozenset(k for k in kinds
+                     if progs[k].program.count is not None)
+
+
+def _counters(progs, kinds, exact: bool):
+    """Per kind the result-count builder (None where it has none), or None
+    for a sweep that counts nothing."""
+    counted = counted_kinds(progs, kinds, exact)
+    if not counted:
+        return None
+    return [progs[k]._build_count() if k in counted else None
+            for k in kinds]
+
+
+def result_totals(counters, tables, cols: dict, hits) -> jnp.ndarray:
+    """[C_total] int32 of an exact-lane sweep: for a row whose kind counts
+    its results (``counters[i]`` not None), the results of its hits, or -1
+    where a hit's count is not certified; for every other row its hits."""
+    out = []
+    lo = 0
+    for counter, table in zip(counters, tables):
+        ck = table["__row__"].shape[0]
+        h = hits[lo: lo + ck]
+        lo += ck
+        if counter is None:
+            out.append(jnp.sum(h, axis=1, dtype=jnp.int32))
+            continue
+        with jax.named_scope("count"):
+            cnt, ok = counter(table, cols)
+            total = jnp.sum(jnp.where(h, as_grid(cnt, h.shape), 0), axis=1,
+                            dtype=jnp.int32)
+            out.append(jnp.where(jnp.all(as_grid(ok, h.shape) | ~h, axis=1),
+                                 total, -1))
+    return jnp.concatenate(out)
+
+
+class SweptRows(tuple):
+    """One kind's rows of a collected sweep, ``(constraints, idx, valid,
+    counts, bits)``.  ``results`` is True where ``counts`` holds each row's
+    RESULT total (an exact-lane sweep of a kind whose program counts its
+    results; -1 for a row a hit of which the device could not certify), and
+    False where it holds the row's violating objects."""
+
+    def __new__(cls, entry: tuple, results: bool = False):
+        self = super().__new__(cls, entry)
+        self.results = results
+        return self
+
+
 def topk_violations(verdicts: jnp.ndarray, k: int) -> tuple:
     """Per-constraint top-k violating object indices, lowest-index-first —
     the device analog of the reference's LimitQueue (bounded max-heap,
@@ -623,10 +677,12 @@ def topk_violations(verdicts: jnp.ndarray, k: int) -> tuple:
     return top_idx, top_scores > 0
 
 
-def _masks_fold(grid, k: int, return_bits: bool, use_pallas: bool):
+def _masks_fold(grid, k: int, return_bits: bool, use_pallas: bool,
+                totals=None):
     """Masks-lane epilogue of a sweep, host and resident alike: the
     masked grid -> ONE packed int32 array [C_total, 2k+1] =
-    [idx(k) | valid(k) | count] (+ the bit-packed verdict rows)."""
+    [idx(k) | valid(k) | count] (+ the bit-packed verdict rows).
+    ``totals`` (exact lane, :func:`result_totals`) stands for ``count``."""
     with jax.named_scope("fold"):
         if use_pallas:
             from gatekeeper_tpu.ops.pallas_topk import \
@@ -636,6 +692,8 @@ def _masks_fold(grid, k: int, return_bits: bool, use_pallas: bool):
         else:
             idx, valid = topk_violations(grid, k)
             counts = jnp.sum(grid, axis=1, dtype=jnp.int32)
+        if totals is not None:
+            counts = totals
         packed = jnp.concatenate(
             [idx, valid.astype(jnp.int32), counts[:, None]], axis=1)
         if return_bits:
@@ -646,12 +704,15 @@ def _masks_fold(grid, k: int, return_bits: bool, use_pallas: bool):
 
 
 def _reduced_fold(raw, mask, budget, k: int, complete: bool, hit_cap: int,
-                  pad_n: int, use_pallas: bool):
+                  pad_n: int, use_pallas: bool, totals=None):
     """Reduced-lane epilogue of a sweep, host and resident alike: the
     raw grid and the match mask -> ONE small int32 array
     ``[counts(C) | occ(C) | nsel | hits(hit_cap)]`` (counts and occ
-    share a u16|u16 word while pad_n fits).  ``budget`` is unused
-    (may be None) when ``complete``."""
+    share a u16|u16 word while pad_n fits and ``counts`` are violating
+    objects).  ``budget`` is unused (may be None) when ``complete``;
+    ``totals`` (complete only, :func:`result_totals`) stands for
+    ``counts`` on the wire, which then travel in words of their own: a
+    result total may pass pad_n, and -1 marks an uncertified row."""
     with jax.named_scope("fold"):
         c_total = raw.shape[0]
         sentinel = c_total * pad_n
@@ -697,7 +758,9 @@ def _reduced_fold(raw, mask, budget, k: int, complete: bool, hit_cap: int,
                     sentinel).astype(jnp.int32)
             else:
                 hits = jnp.zeros((0,), jnp.int32)
-        if pad_n <= 0xFFFF:
+        if totals is not None:
+            head = [totals, occ]
+        elif pad_n <= 0xFFFF:
             # counts and occupancy are both <= pad_n: one u16|u16
             # word per constraint halves the per-chunk floor (the
             # D2H twin of the H2D wire-dtype narrowing)
@@ -768,7 +831,7 @@ class _PendingSweep:
     __slots__ = ("result", "kinds", "offsets", "by_kind", "n",
                  "return_bits", "attr_weights", "attr_rows",
                  "lane", "pad_n", "hit_cap", "flat", "ref",
-                 "dispatch_wall", "host_occ", "budget_np")
+                 "dispatch_wall", "host_occ", "budget_np", "counted")
 
     def __init__(self, result, kinds, offsets, by_kind, n, return_bits,
                  attr_weights=None, attr_rows=None, lane="masks",
@@ -803,6 +866,8 @@ class _PendingSweep:
         # budgeted reduced dispatch: the per-constraint kept budgets the
         # device selection was clipped to (None = complete variant)
         self.budget_np = None
+        # exact lane: the kinds whose count column holds result totals
+        self.counted = frozenset()
 
 
 class _FlatChunk:
@@ -1093,20 +1158,21 @@ class ShardedEvaluator:
                          label_keys=label_keys)
 
     def _needs_union(self, kinds, alias: Optional[dict] = None,
-                     programs=None) -> dict:
+                     programs=None, counting: bool = False) -> dict:
         """Union of array fields any lowered program reads — the
         transfer-slimming key shared by warm_pass (col stats) and
         sweep_submit (packing); one definition so the stats keys always
         match the packed columns.  ``alias`` (orig spec -> exec spec from
         the Flattener's prefix-axis dedup) extends each aliased key's
         needs onto its exec column so slimming keeps exactly the fields
-        some consumer reads through either name."""
+        some consumer reads through either name.  ``counting`` (the exact
+        lane's host chunks): the result counts' fields too."""
         progs = programs if programs is not None \
             else self.driver._programs
         needs: dict = {}
         for kind in sorted(kinds):
             for ck, fields in needed_fields(
-                    progs[kind].program).items():
+                    progs[kind].program, counting).items():
                 needs.setdefault(ck, set()).update(fields)
         if alias:
             for orig, new in alias.items():
@@ -1145,6 +1211,7 @@ class ShardedEvaluator:
         if fn is not None:
             return fn
         builders = [progs[kind]._build() for kind in kinds]
+        counters = _counters(progs, kinds, return_bits)
         use_pallas = self._use_pallas()
 
         def sweep_masks(tables_buf, cols_buf, table_cols: dict, mask_bits):
@@ -1156,8 +1223,10 @@ class ShardedEvaluator:
             mask = jnp.unpackbits(mask_bits, axis=1,
                                   count=pad_n).astype(jnp.bool_)
             grids = template_grids(kinds, builders, tables, cols)
-            return _masks_fold(jnp.concatenate(grids, axis=0) & mask,
-                                    k, return_bits, use_pallas)
+            grid = jnp.concatenate(grids, axis=0) & mask
+            totals = None if counters is None else \
+                result_totals(counters, tables, cols, grid)
+            return _masks_fold(grid, k, return_bits, use_pallas, totals)
 
         fn = jax.jit(sweep_masks)
         self._sweep_fns[key] = fn
@@ -1190,6 +1259,7 @@ class ShardedEvaluator:
         if fn is not None:
             return fn
         builders = [progs[kind]._build() for kind in kinds]
+        counters = _counters(progs, kinds, complete)
         use_pallas = not complete and self._use_pallas()
 
         def sweep_reduced(tables_buf, cols_buf, table_cols: dict,
@@ -1201,10 +1271,12 @@ class ShardedEvaluator:
                                         len(kinds))
             mask = jnp.unpackbits(mask_bits, axis=1,
                                   count=pad_n).astype(jnp.bool_)
-            grids = template_grids(kinds, builders, tables, cols)
-            return _reduced_fold(
-                jnp.concatenate(grids, axis=0), mask, budget, k, complete,
-                hit_cap, pad_n, use_pallas)
+            raw = jnp.concatenate(
+                template_grids(kinds, builders, tables, cols), axis=0)
+            totals = None if counters is None else \
+                result_totals(counters, tables, cols, raw & mask)
+            return _reduced_fold(raw, mask, budget, k, complete, hit_cap,
+                                 pad_n, use_pallas, totals)
 
         fn = jax.jit(sweep_reduced)
         self._sweep_fns[key] = fn
@@ -1361,7 +1433,8 @@ class ShardedEvaluator:
                            lane=self.flatten_lane,
                            workers=self.flatten_workers,
                            label_keys=selector_label_keys(cons_g))
-            st = (cons_g, fl, self._needs_union(lowered, fl.alias))
+            st = (cons_g, fl, self._needs_union(lowered, fl.alias,
+                                                counting=return_bits))
             state[g] = st
             return st
 
@@ -1521,7 +1594,8 @@ class ShardedEvaluator:
             return {}
         cols = slim_cols(pack_batch_cols(batch),
                          self._needs_union(lowered, alias or {},
-                                           programs=programs))
+                                           programs=programs,
+                                           counting=return_bits))
         n = len(objects)
         if batch.has_generate_name is not None:
             any_gen = bool(batch.has_generate_name[:n].any())
@@ -1620,7 +1694,8 @@ class ShardedEvaluator:
         cols = pack_batch_cols(batch)
         # transfer slimming: ship only the array fields some program reads
         cols = slim_cols(cols, self._needs_union(lowered, fl.alias,
-                                                 programs=programs))
+                                                 programs=programs,
+                                                 counting=return_bits))
 
         if batch.has_generate_name is not None:
             # native JSON lane: presence came back as a column — avoids
@@ -1790,13 +1865,21 @@ class ShardedEvaluator:
             for key in ("rows_vectorized", "rows_predicate"):
                 tracing.set_attribute(key, mask_counts[key])
         self._perf_add("masks_cpu", time.thread_time() - c0)
-        # constraint rows of this dispatch, and those whose program
-        # ir/lower_cel.py produced: written on every dispatch, a 0 too
+        # constraint rows of this dispatch, those whose program
+        # ir/lower_cel.py produced, and those whose total the device
+        # gives: every row of the top-k lane, the rows of the kinds whose
+        # program counts its results in the exact lane (less, at collect,
+        # a row whose count a hit left uncertified): written on every
+        # dispatch, a 0 too
         cel_kinds = self.driver._cel_kinds
+        counted = counted_kinds(progs, kinds, return_bits)
         for key, rows in (
                 ("sweep_rows", c_off),
                 ("sweep_rows_cel", sum(len(by_kind[kind]) for kind in kinds
-                                       if kind in cel_kinds))):
+                                       if kind in cel_kinds)),
+                ("sweep_rows_counted",
+                 sum(len(by_kind[kind]) for kind in counted)
+                 if return_bits else c_off)):
             self._perf_add(key, rows)
             tracing.set_attribute(key, rows)
         from gatekeeper_tpu.observability import costattr
@@ -1834,7 +1917,8 @@ class ShardedEvaluator:
             table_cols.update(ext_cols)
         for kind in kinds:
             for tk, tv in vocab_tables(
-                progs[kind].program, self.driver.vocab
+                progs[kind].program, self.driver.vocab,
+                counting=kind in counted
             ).items():
                 table_cols[tk] = tv
             for tk, tv in self.driver.inventory_cols(
@@ -1936,6 +2020,7 @@ class ShardedEvaluator:
             flat=flat if lane == "reduced" else None)
         pending.host_occ = host_occ_np
         pending.budget_np = None if complete else budget_np
+        pending.counted = counted
         return pending
 
     def _table_upload_bytes(self, table_cols: dict) -> int:
@@ -2104,10 +2189,21 @@ class ShardedEvaluator:
             valid_np = (packed_np[lo:hi, k_eff: 2 * k_eff] != 0) & (idx_np < n)
             counts_np = packed_np[lo:hi, 2 * k_eff]
             kb = bits_np[lo:hi] if bits_np is not None else None
-            out[kind] = (pending.by_kind[kind], idx_np, valid_np, counts_np,
-                         kb)
+            out[kind] = self._swept_rows(
+                pending, kind, (pending.by_kind[kind], idx_np, valid_np,
+                                counts_np, kb))
         self._perf_add("collect", time.perf_counter() - t0)
         return out
+
+    def _swept_rows(self, pending, kind: str, entry: tuple) -> SweptRows:
+        """A collected kind's rows, marked where its counts are result
+        totals; a row a hit left uncertified leaves ``sweep_rows_counted``
+        here, since its total comes from the renders."""
+        results = kind in pending.counted
+        if results:
+            self._perf_add("sweep_rows_counted",
+                           -int(np.count_nonzero(entry[3] < 0)))
+        return SweptRows(entry, results)
 
     @staticmethod
     def _kept_from_hits(sub: np.ndarray, ck: int, pad_n: int, k_eff: int,
@@ -2140,7 +2236,7 @@ class ShardedEvaluator:
         self._perf_add("d2h_bytes", arr.nbytes)
         c_total = max(hi for _lo, hi in pending.offsets.values())
         pad_n, n = pending.pad_n, pending.n
-        if pad_n <= 0xFFFF:
+        if pad_n <= 0xFFFF and not pending.counted:
             co = arr[:c_total].view(np.uint32)
             counts_all = (co & 0xFFFF).astype(np.int32)
             occ_all = (co >> 16).astype(np.int32)
@@ -2201,8 +2297,9 @@ class ShardedEvaluator:
             idx_np, valid_np = self._kept_from_hits(sub, ck, pad_n,
                                                     k_eff, n)
             kb = HitRows(sub, pad_n, n, ck) if complete else None
-            out[kind] = (pending.by_kind[kind], idx_np, valid_np,
-                         counts_all[lo:hi], kb)
+            out[kind] = self._swept_rows(
+                pending, kind, (pending.by_kind[kind], idx_np, valid_np,
+                                counts_all[lo:hi], kb))
         attr = costattr.active()
         if attr is not None and pending.dispatch_wall > 0:
             # satellite of the reduced lane: occupancy weights come from

@@ -1173,3 +1173,116 @@ def test_the_flatteners_parts_are_spans_under_columnize(toy, pipeline):
         (drain,) = by_name["pipeline.drain"]
         assert drain["parent_id"] == root["span_id"]
         _inside(drain, root)
+
+
+# --- result counts from the device in the account (ir Program.count) --------
+
+LIMITS = {
+    "apiVersion": "constraints.gatekeeper.sh/v1beta1",
+    "kind": "K8sContainerLimits",
+    "metadata": {"name": "container-must-have-limits"},
+    "spec": {"match": {"kinds": [{"apiGroups": [""], "kinds": ["Pod"]}]},
+             "parameters": {"cpu": "200m", "memory": "1Gi"}},
+}
+
+
+@pytest.fixture(scope="module")
+def counted_toy():
+    """A row the device counts (K8sContainerLimits, one result per clause
+    and distinct container name) beside one it cannot
+    (K8sRequiredLabels: details beside the message)."""
+    tpu = TpuDriver()
+    client = Client(target=K8sValidationTarget(), drivers=[tpu],
+                    enforcement_points=[AUDIT_EP])
+    for name, con in (("requiredlabels", {
+            "apiVersion": "constraints.gatekeeper.sh/v1beta1",
+            "kind": "K8sRequiredLabels",
+            "metadata": {"name": "ns-must-have-gk"},
+            "spec": {"match": {"kinds": [{"apiGroups": [""],
+                                          "kinds": ["Namespace"]}]},
+                     "parameters": {"labels": [{"key": "gatekeeper"}]}}}),
+            ("containerlimits", LIMITS)):
+        client.add_template(load_yaml_file(f"{LIB}/{name}/template.yaml")[0])
+        client.add_constraint(con)
+    assert tpu._programs["K8sContainerLimits"].program.count is not None
+    assert tpu._programs["K8sRequiredLabels"].program.count is None
+    # pods with two limitless containers: four results each
+    objects = _objects(40) + [
+        {"apiVersion": "v1", "kind": "Pod",
+         "metadata": {"name": f"p{i}", "namespace": "ns-1"},
+         "spec": {"containers": [{"name": "a", "image": "x"},
+                                 {"name": "b", "image": "y"}]}}
+        for i in range(30)]
+    return client, ShardedEvaluator(tpu, make_mesh(), violations_limit=5), \
+        objects
+
+
+def _counted_pass(counted_toy, exact, pipeline="on"):
+    client, evaluator, objects = counted_toy
+    evaluator.perf_reset()
+    mgr = AuditManager(
+        client, lister=lambda: iter(objects),
+        config=AuditConfig(chunk_size=16, exact_totals=exact,
+                           pipeline=pipeline, violations_limit=5),
+        evaluator=evaluator)
+    tracer = tracing.Tracer(seed=0)
+    with tracing.activate(tracer):
+        run = mgr.audit()
+    dispatches = [s for t in tracer.traces() for s in t["spans"]
+                  if s["name"] == "device.sweep_dispatch"]
+    return run, mgr.perf, evaluator.perf, dispatches
+
+
+@pytest.mark.parametrize("pipeline", ["on", "off"])
+def test_the_exact_lane_renders_uncounted_hits_and_the_kept_slots(
+        counted_toy, pipeline):
+    """``n_renders`` under exact totals is the uncounted row's hits plus
+    the renders that fill the counted row's kept slots (plain dicts: the
+    memo answers none), and the counted row's total is its results."""
+    run, mperf, eperf, dispatches = _counted_pass(counted_toy, True,
+                                                  pipeline)
+    unlabelled = sum(not o["metadata"].get("labels")
+                     for o in counted_toy[2] if o["kind"] == "Namespace")
+    limits = ("K8sContainerLimits", "container-must-have-limits")
+    assert run.total_violations[limits] == 30 * 4
+    assert run.total_violations[("K8sRequiredLabels", "ns-must-have-gk")] \
+        == unlabelled
+    # five kept slots at four results a pod: the first two pods render
+    assert [v.name for v in run.kept[limits]] == ["p0"] * 4 + ["p1"]
+    assert mperf["n_renders"] == unlabelled + 2
+    # written on every dispatch, a 0 too: the Pod group's rows counted
+    assert sorted(s["attributes"]["sweep_rows_counted"]
+                  for s in dispatches) == [0, 0, 0, 1, 1]
+    assert eperf["sweep_rows_counted"] == 2 and eperf["sweep_rows"] == 5
+
+
+def test_in_the_top_k_lane_every_row_is_counted(counted_toy):
+    run, _mperf, eperf, dispatches = _counted_pass(counted_toy, False)
+    assert run.total_violations[
+        ("K8sContainerLimits", "container-must-have-limits")] == 30
+    assert eperf["sweep_rows_counted"] == eperf["sweep_rows"] == 5
+    assert all(s["attributes"]["sweep_rows_counted"]
+               == s["attributes"]["sweep_rows"] for s in dispatches)
+
+
+def test_an_uncertified_hit_renders_its_row_and_leaves_the_count(
+        counted_toy):
+    """A container named with the message's delimiter could print as
+    another's message: the device does not certify that chunk's count, the
+    row renders every hit there, and the total is the interpreter's."""
+    client, evaluator, objects = counted_toy
+    tricky = [dict(o) for o in objects]
+    tricky[-1] = {"apiVersion": "v1", "kind": "Pod",
+                  "metadata": {"name": "odd", "namespace": "ns-1"},
+                  "spec": {"containers": [{"name": "a> has no cpu limit",
+                                           "image": "x"}]}}
+    evaluator.perf_reset()
+    mgr = AuditManager(
+        client, lister=lambda: iter(tricky),
+        config=AuditConfig(chunk_size=16, exact_totals=True),
+        evaluator=evaluator)
+    run = mgr.audit()
+    assert run.total_violations[
+        ("K8sContainerLimits", "container-must-have-limits")] == 29 * 4 + 2
+    # the chunk with the odd pod is a row the renders counted
+    assert evaluator.perf["sweep_rows_counted"] == 1

@@ -105,37 +105,66 @@ def test_remote_audit_sweep_parity(sidecar):
 def test_remote_exact_totals(sidecar):
     """exact_totals through the sidecar must match the local exact path
     (the CEL noprivileged template yields ONE result per violating pod —
-    its validation is size(badContainers)==0 — so totals count pods)."""
+    its validation is size(badContainers)==0 — so totals count pods), and
+    both the interpreter's, on a library where the device counts some rows'
+    results (container rules: one result per distinct container name, two
+    here a pod) and the renders count the others' (K8sDisallowedRepos,
+    K8sContainerRatios)."""
+    from gatekeeper_tpu.drivers.rego_driver import RegoDriver
+
     address, _svc = sidecar
     rc, remote = _remote_client(address)
     lc, ltpu = _local_client()
-    for c in (rc, lc):
+    pc = Client(target=K8sValidationTarget(),
+                drivers=[RegoDriver(), CELDriver()],
+                enforcement_points=[WEBHOOK_EP, AUDIT_EP])
+    for c in (rc, lc, pc):
         load_library(c, skip_kinds=("K8sUniqueIngressHost",))
     remote.wipe_data()
     pods = [{
         "apiVersion": "v1", "kind": "Pod",
         "metadata": {"name": f"p{i}"},
         "spec": {"containers": [
-            {"name": "a", "image": "x", "securityContext":
-                {"privileged": True}},
+            {"name": "a", "image": "evilcorp.io/x", "securityContext":
+                {"privileged": True},
+             "resources": {"limits": {"cpu": "4", "memory": "4Gi"},
+                           "requests": {"cpu": "1", "memory": "1Gi"}}},
             {"name": "b", "image": "y", "securityContext":
                 {"privileged": True}},
         ]},
-    } for i in range(4)]
+    } for i in range(4)] + [o for o in make_cluster_objects(80, seed=41)
+                            if o.get("kind") == "Pod"]
+    config = AuditConfig(exact_totals=True, violations_limit=5)
     r_mgr = AuditManager(
-        rc, lister=lambda: iter(pods), config=AuditConfig(),
-        evaluator=RemoteEvaluator(remote, violations_limit=20,
+        rc, lister=lambda: iter(pods), config=config,
+        evaluator=RemoteEvaluator(remote, violations_limit=5,
                                   exact_totals=True),
     )
-    l_mgr = AuditManager(
-        lc, lister=lambda: iter(pods),
-        config=AuditConfig(exact_totals=True),
-        evaluator=ShardedEvaluator(ltpu, make_mesh(), violations_limit=20),
-    )
-    r_run, l_run = r_mgr.audit(), l_mgr.audit()
-    assert r_run.total_violations == l_run.total_violations
+    ev = ShardedEvaluator(ltpu, make_mesh(), violations_limit=5)
+    l_mgr = AuditManager(lc, lister=lambda: iter(pods), config=config,
+                         evaluator=ev)
+    p_mgr = AuditManager(pc, lister=lambda: iter(pods), config=config)
+    r_run, l_run, p_run = r_mgr.audit(), l_mgr.audit(), p_mgr.audit()
+    assert r_run.total_violations == l_run.total_violations \
+        == p_run.total_violations
+    for key in p_run.kept:
+        want = [(v.name, v.message) for v in p_run.kept[key]]
+        for run in (r_run, l_run):
+            assert [(v.name, v.message)
+                    for v in run.kept[key]] == want, key
     key = ("K8sNoPrivileged", "no-privileged-containers")
-    assert r_run.total_violations[key] == 4  # one result per violating pod
+    privileged = sum(any(
+        (c.get("securityContext") or {}).get("privileged") is True
+        for c in (p["spec"].get("containers") or [])
+        + (p["spec"].get("initContainers") or [])) for p in pods)
+    assert privileged >= 4
+    assert r_run.total_violations[key] == privileged  # one result a pod
+    assert l_run.total_violations[
+        ("K8sContainerLimits", "container-must-have-limits")] > 4
+    assert l_run.total_violations[
+        ("K8sDisallowedRepos", "repo-must-not-be-k8s-gcr")] >= 4
+    # counted and uncounted rows both swept
+    assert 0 < ev.perf["sweep_rows_counted"] < ev.perf["sweep_rows"]
 
 
 def test_sidecar_process_e2e(tmp_path):
