@@ -21,13 +21,18 @@ Every number compared goes into the result line, each beside its limit.
 
 A mix with a ``churn`` block (``churn.py``) changes the cluster between
 every two passes.  A pass is then held to what its own cluster owes: the
-set-up pass's totals moved by every change of an object's verdict since,
-as a ``return_bits`` sweep of every changed object finds them once the
-window has closed (so that the measured passes are the first to meet each
-new name), that sweep held to the interpreter on a seeded sample of the
-changed objects, and the kept violations of three passes held to the
-interpreter's review of the very bytes those passes listed.  A mix without
-the block runs as it always has.
+set-up pass's totals moved by every change of an object's count since.  A
+``return_bits`` sweep of every changed object finds which versions violate
+once the window has closed (so that the measured passes are the first to
+meet each new name), held to the interpreter on a seeded sample of the
+changed objects; a version counts 1 for each constraint it violates where a
+total is the number of violating objects.  Where it is the number of
+results, the children review every changed version, violating or not: a
+version counts the interpreter's results for the constraint, and the
+sweep's pairs are held to the interpreter's on every version, so that no
+total rests on what the program found.  The kept violations of three passes
+are held to the interpreter's review of the very bytes those passes listed.
+A mix without the block runs as it always has.
 """
 
 from __future__ import annotations
@@ -35,6 +40,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import resource
 import sys
 import time
 
@@ -333,9 +339,6 @@ def run(run: Run) -> dict:
     sample = read_sample(paths, sample_path)
     epochs = None
     if traffic.get("churn"):
-        if cfg["audit"]["exact_totals"]:
-            raise ValueError("a churn mix holds totals of violating objects; "
-                             "under exact_totals they are result counts")
         # the first epochs now, so that the interpreter reviews its sample
         # of the changed objects beside set-up; the rest once set-up's
         # passes have said how many the window can hold
@@ -380,37 +383,102 @@ def settle(run: Run, mgr, epochs) -> list:
 
 def churn_problems(run: Run, program, epochs, paths: list, inventory: list,
                    router, first, settled: list, runs: list,
-                   pass_epoch: list, reviewed: dict, compared: dict) -> list:
+                   pass_epoch: list, reviewed: dict, compared: dict,
+                   told: dict) -> list:
     """``correct`` through the changes, once the window has closed; the
-    numbers go into ``compared``, what they found is returned as text.
-    ``reviewed``: {version number: {constraint key: [messages]}} of the
-    interpreter's sample of the first epochs' changed objects, which it
-    reviewed beside set-up; its sample of the later epochs' and the objects
-    three passes kept violations of go to children started here."""
+    numbers go into ``compared``, what the check itself cost into ``told``
+    (the run's notes), what they found is returned as text.  ``reviewed``:
+    {version number: {constraint key: [messages]}} of the interpreter's
+    sample of the first epochs' changed objects, which it reviewed beside
+    set-up; its sample of the later epochs', the objects three passes kept
+    violations of and, where a total is the number of results, every listed
+    version go to children started here."""
     cfg = run.cell.config
     n, limit = epochs.n, cfg["audit"]["violations_limit"]
+    exact = cfg["audit"]["exact_totals"]
     rows = epochs.rows
-    base = churn.read_positions(
-        paths, {pos for changes in rows for pos, _op, _prev, _raw in changes})
-    versions = dict(base)
-    for e, changes in enumerate(rows):
-        for pos, _op, _prev, raw in changes:
-            versions[churn.version_id(n, e, pos)] = raw
+    ledger = churn.Ledger(n, rows, churn.read_positions(
+        paths, {pos for changes in rows for pos, _op, _prev, _raw in changes}))
     later = dict(epochs.sample_later())
     sampled = {**dict(epochs.sample_first()), **later}
-    versions.update(sampled)
     # every changed object, as the corpus had it and as each epoch made it,
     # on the device alone: nothing of the memo or of a pass is in this sweep
-    pairs = device_pairs(program, by_group(router, list(versions.items())),
-                         cfg["audit"]["chunk_size"])
+    t = time.monotonic()
+    pairs = device_pairs(program, by_group(router, list(
+        {**ledger.raw_of, **sampled}.items())), cfg["audit"]["chunk_size"])
+    told["sweep_executables"] = run.compiles_between(t, time.monotonic())
     run.mark("churn_sweep")
     problems = []
-    ledger = churn.Ledger(n, rows, base, pairs, first.total_violations)
+    compared.update(touch_moved_a_verdict=0, kept_short=0, kept_stale=0,
+                    kept_unfounded=0)
+    # what the interpreter reviews here: the later epochs' sample, what the
+    # first, the middle and the last pass kept and, where a total is the
+    # number of results, every listed version that set-up's children did
+    # not review, whatever the device found of it: its results are its
+    # counts
+    counted = set(ledger.raw_of) if exact else set()
+    k = churn.RENDERED_PASSES
+    at = sorted({(len(runs) - 1) * i // max(1, k - 1) for i in range(k)}
+                if runs else ())
+    unchanged = churn.locate(paths, {
+        name for i in at for name in churn.kept_names(runs[i])
+        if name not in ledger.position})
+    kept_of = []
+    by_raw: dict = {}  # the bytes listed -> their line of the review's input
+    for raw in later.values():
+        by_raw.setdefault(raw, len(by_raw))
+    for i in at:
+        listed, unlisted = ledger.kept_versions(pass_epoch[i], runs[i],
+                                                unchanged)
+        compared["kept_stale"] += len(unlisted)
+        if unlisted:
+            problems.append(f"pass {i} keeps violations of objects no one "
+                            f"listed: {sorted(unlisted)[:3]}")
+        kept_of.append((i, listed))
+        for raw in listed.values():
+            by_raw.setdefault(raw, len(by_raw))
+    for vid in counted - set(reviewed):
+        by_raw.setdefault(ledger.raw_of[vid], len(by_raw))
+    path = os.path.join(run.work, "kept.tsv")
+    with open(path, "wb") as f:
+        for raw, rid in by_raw.items():
+            f.write(b"%d\t" % rid + raw + b"\n")
+    results: dict = {}
+    parts = churn.children_for(len(by_raw), churn.REVIEWED_A_CHILD)
+    cpu = resource.getrusage(resource.RUSAGE_CHILDREN)
+    t = time.monotonic()
+    if by_raw:
+        results = reference.by_index(reference.Children(
+            run.spawn, cfg, "audit", run.seed, inventory, path, run.work,
+            parts, tag="kept").join())
+    done = resource.getrusage(resource.RUSAGE_CHILDREN)
+    told.update(counted_versions=len(counted), review_lines=len(by_raw),
+                review_children=parts if by_raw else 0,
+                review_s=time.monotonic() - t,
+                review_child_cpu_s=(done.ru_utime + done.ru_stime
+                                    - cpu.ru_utime - cpu.ru_stime))
+    run.mark("churn_kept_review")
+    by_bytes = {raw: results[rid] for raw, rid in by_raw.items()
+                if rid in results}
+    reviewed = dict(reviewed)
+    reviewed.update((vid, by_bytes[raw]) for vid, raw in later.items()
+                    if raw in by_bytes)
+    # what each version counts: its results under exact totals, 1 for each
+    # constraint the device finds it violating otherwise
+    if exact:
+        answers = {vid: reviewed.get(vid, by_bytes.get(ledger.raw_of[vid]))
+                   for vid in counted}
+        missing = sorted(vid for vid, per in answers.items() if per is None)
+        answered = {vid: per for vid, per in answers.items()
+                    if per is not None}
+        counts = churn.result_counts(answered)
+    else:
+        missing, answered, counts = [], {}, churn.clipped(pairs)
+    ledger.owe(counts, first.total_violations)
     compared["touch_moved_a_verdict"] = ledger.touch_moved
     if ledger.touch_moved:
         problems.append(f"{ledger.touch_moved} rewrites of resourceVersion "
-                        "changed a verdict of the device")
-    compared.update(kept_short=0, kept_stale=0, kept_unfounded=0)
+                        "changed a count")
     passes = [(e, r, f"settle pass {i}")
               for i, (e, r, _asked, _s) in enumerate(settled)]
     passes += [(e, r, f"pass {i}")
@@ -424,52 +492,20 @@ def churn_problems(run: Run, program, epochs, paths: list, inventory: list,
             compared[key] += found[key]
         if any(found.values()):
             problems.append(f"{name}, epoch {e}: {found}")
-    # the interpreter on the later epochs' sample and on what the first,
-    # the middle and the last pass kept
-    k = churn.RENDERED_PASSES
-    at = sorted({(len(runs) - 1) * i // max(1, k - 1) for i in range(k)}
-                if runs else ())
-    unchanged = churn.locate(paths, {
-        name for i in at for name in churn.kept_names(runs[i])
-        if name not in ledger.position})
-    kept_of = []
-    by_raw: dict = {}  # the bytes listed -> their line of the review's input
-    for raw in later.values():
-        by_raw.setdefault(raw, len(by_raw))
-    for i in at:
-        listed, missing = ledger.kept_versions(pass_epoch[i], runs[i],
-                                               unchanged)
-        compared["kept_stale"] += len(missing)
-        if missing:
-            problems.append(f"pass {i} keeps violations of objects no one "
-                            f"listed: {sorted(missing)[:3]}")
-        kept_of.append((i, listed))
-        for raw in listed.values():
-            by_raw.setdefault(raw, len(by_raw))
-    path = os.path.join(run.work, "kept.tsv")
-    with open(path, "wb") as f:
-        for raw, rid in by_raw.items():
-            f.write(b"%d\t" % rid + raw + b"\n")
-    results: dict = {}
-    if by_raw:
-        results = reference.by_index(reference.Children(
-            run.spawn, cfg, "audit", run.seed, inventory, path, run.work,
-            churn.children_for(len(by_raw), churn.REVIEWED_A_CHILD),
-            tag="kept").join())
-    run.mark("churn_kept_review")
-    by_bytes = {raw: results[rid] for raw, rid in by_raw.items()
-                if rid in results}
-    reviewed = dict(reviewed)
-    reviewed.update((vid, by_bytes[raw]) for vid, raw in later.items()
-                    if raw in by_bytes)
-    interp = {(key, vid) for vid, per in reviewed.items() for key in per}
-    device = {(key, vid) for key, vid in pairs if vid in sampled}
+    # the sweep against the interpreter on every version it reviewed: the
+    # seeded sample and, under exact totals, every listed version, so that
+    # a violation the device misses is a disagreement and not a count of 0
+    held = {**answered, **reviewed}
+    interp = {(key, vid) for vid, per in held.items() for key in per}
+    device = {(key, vid) for key, vid in pairs
+              if vid in sampled or vid in answered}
     compared["churn_pairs_differ"] = len(device ^ interp)
     if device != interp:
         problems.append(
-            f"device sweep != interpreter on {len(sampled)} changed objects: "
-            f"{len(device - interp)} device-only, {len(interp - device)} "
-            f"interpreter-only, e.g. {sorted(device ^ interp)[:3]}")
+            f"device sweep != interpreter on {len(set(sampled) | set(held))} "
+            f"changed versions: {len(device - interp)} device-only, "
+            f"{len(interp - device)} interpreter-only, e.g. "
+            f"{sorted(device ^ interp)[:3]}")
     # every one of the first epochs and the last one brought in
     owed = set(range(churn.SAMPLE_FIRST_EPOCHS)) | {len(rows) - 1}
     epochs_sampled = {vid // n - 1 for vid in sampled if vid in reviewed}
@@ -488,6 +524,10 @@ def churn_problems(run: Run, program, epochs, paths: list, inventory: list,
             problems.append(f"pass {i}: {bad} constraints keep violations "
                             "the interpreter does not report of the bytes "
                             "listed")
+    compared["churn_counts_missing"] = len(missing)
+    if missing:
+        problems.append(f"the interpreter gave no count for {len(missing)} "
+                        f"listed versions, e.g. {missing[:3]}")
     return problems
 
 
@@ -652,6 +692,7 @@ def _measure(run, program, paths, counts, sample, ref, inventory,
     if not passes:
         problems.append("no whole pass fits the window")
     changed_in_window = 0
+    told: dict = {}  # what the check after the window cost
     if epochs is not None:
         changed_in_window = sum(epochs.changed[brought_in:])
         compared["epochs_ran_out"] = ran_out
@@ -660,7 +701,7 @@ def _measure(run, program, paths, counts, sample, ref, inventory,
                             f"{epochs.made} epochs left")
         problems += churn_problems(run, program, epochs, paths, inventory,
                                    router, first, settled, runs, pass_epoch,
-                                   reviewed, compared)
+                                   reviewed, compared, told)
     for p in problems:
         print(f"benchmark: {p}", file=sys.stderr)
 
@@ -721,7 +762,7 @@ def _measure(run, program, paths, counts, sample, ref, inventory,
             "executables_by_pass": [
                 run.compiles_between(t, t + s)
                 for t, s in zip(began, passes)],
-            "vocabulary": [vocabulary_w0, vocabulary_w1]}
+            "vocabulary": [vocabulary_w0, vocabulary_w1], **told}
     e2e = {"setup_s": setup_s}
     if passes:
         e2e["audit_pass_s"] = stats.mean(passes)

@@ -391,21 +391,34 @@ def locate(paths: list, names) -> dict:
     return found
 
 
+def clipped(pairs) -> dict:
+    """{version number: {constraint key: 1}} of the violating (constraint
+    key, version number) pairs: the counts ``Ledger.owe`` takes where a
+    total is the number of violating objects."""
+    counts: dict = {}
+    for key, vid in pairs:
+        counts.setdefault(vid, {})[key] = 1
+    return counts
+
+
+def result_counts(results: dict) -> dict:
+    """{version number: {constraint key: its results}} of the interpreter's
+    review ({version number: {constraint key: [messages]}}): the counts
+    ``Ledger.owe`` takes where a total is the number of results, as
+    upstream's ``totalViolations`` counts them."""
+    return {vid: {key: len(msgs) for key, msgs in per.items() if msgs}
+            for vid, per in results.items()}
+
+
 class Ledger:
-    """Every version the passes listed, and what each pass owes.
+    """Every version the passes listed, and, once ``owe`` has been told
+    each version's counts, what each pass owes.
 
     ``rows``: the epochs brought in, in order (``read_epoch``).  ``base``:
-    {position: the corpus's own line} of every position they change.
-    ``pairs``: the violating (constraint key, version number) pairs of all
-    those versions, the corpus's own and the epochs', as the device found
-    them.  ``setup_totals``: the set-up pass's totals, over the corpus as
-    it is."""
+    {position: the corpus's own line} of every position they change."""
 
-    def __init__(self, n: int, rows: list, base: dict, pairs, setup_totals):
-        self.n = n
-        self.violated: dict = {}   # version number -> {constraint key}
-        for key, vid in pairs:
-            self.violated.setdefault(vid, set()).add(key)
+    def __init__(self, n: int, rows: list, base: dict):
+        self.n, self.rows = n, rows
         self.raw_of = dict(base)   # version number -> bytes
         self.history: dict = {}    # position -> [epochs that changed it]
         self.position: dict = {}   # name -> position, of changed objects
@@ -413,29 +426,43 @@ class Ledger:
         # name -> the epoch it is listed from
         self.gone: dict = {}
         self.born: dict = {}
+        self.violated: dict = {}   # version number -> {constraint key}
         self.totals: list = []     # totals[e]: owed over epoch e's cluster
-        self.touch_moved = 0       # touches that changed a verdict
-        totals = dict(setup_totals)
+        self.touch_moved = 0       # touches that changed a count
         for pos, raw in base.items():
             self.position[identity(raw)] = pos
         for e, changes in enumerate(rows):
             for pos, op, prev, raw in changes:
-                old = pos if prev < 0 else version_id(n, prev, pos)
-                new = version_id(n, e, pos)
-                was = self.violated.get(old, set())
-                now = self.violated.get(new, set())
-                for key in was - now:
-                    totals[key] = totals.get(key, 0) - 1
-                for key in now - was:
-                    totals[key] = totals.get(key, 0) + 1
-                if op == TOUCHED:
-                    self.touch_moved += int(was != now)
-                else:
+                if op == REPLACED:
+                    old = pos if prev < 0 else version_id(n, prev, pos)
                     self.gone[identity(self.raw_of[old])] = e
                     self.born[identity(raw)] = e
                     self.position[identity(raw)] = pos
-                self.raw_of[new] = raw
+                self.raw_of[version_id(n, e, pos)] = raw
                 self.history.setdefault(pos, []).append(e)
+
+    def owe(self, counts: dict, setup_totals: dict) -> None:
+        """``counts``: {version number: {constraint key: count}} of every
+        listed version that violates a constraint, each count above 0 (a
+        version or a key it lacks counts 0): the number of its results for the constraint where a total
+        counts results (``result_counts``), 1 where it counts violating
+        objects (``clipped``).  ``setup_totals``: the set-up pass's totals,
+        over the corpus as it is.  Each change moves a constraint's total
+        by the change in the object's count."""
+        self.violated = {vid: set(per) for vid, per in counts.items()}
+        self.totals, self.touch_moved = [], 0
+        totals = dict(setup_totals)
+        for e, changes in enumerate(self.rows):
+            for pos, op, prev, _raw in changes:
+                old = pos if prev < 0 else version_id(self.n, prev, pos)
+                was = counts.get(old, {})
+                now = counts.get(version_id(self.n, e, pos), {})
+                for key in set(was) | set(now):
+                    moved = now.get(key, 0) - was.get(key, 0)
+                    if moved:
+                        totals[key] = totals.get(key, 0) + moved
+                if op == TOUCHED:
+                    self.touch_moved += int(was != now)
             self.totals.append(dict(totals))
 
     def version(self, e: int, pos: int) -> int:
@@ -453,8 +480,9 @@ class Ledger:
         ``min(limit, total)`` or more than ``limit`` violations
         (``kept_short``), and how many kept violations name an object that
         cluster does not hold, a replaced Pod or one not yet made
-        (``kept_stale``), or a changed object in a version the device did
-        not find violating (``kept_unfounded``)."""
+        (``kept_stale``), or a changed object in a version whose counts
+        say it does not violate that constraint (``kept_unfounded``).
+        ``owe`` first."""
         out = {"totals": 0, "kept_short": 0, "kept_stale": 0,
                "kept_unfounded": 0}
         owed = self.totals[e]
@@ -467,7 +495,7 @@ class Ledger:
             for v in kept:
                 name = (v.kind, v.namespace, v.name)
                 if not self.born.get(name, -1) <= e < self.gone.get(
-                        name, len(self.totals)):
+                        name, len(self.rows)):
                     out["kept_stale"] += 1
                 elif name in self.position:
                     vid = self.version(e, self.position[name])

@@ -51,9 +51,9 @@ def apply_rehearsal(doc: dict) -> dict:
 
 class Cell:
     """A configuration under a traffic mix, with everything it names,
-    loaded: an entry of ``workloads`` (``Cell(name)``), that entry under a
-    mix no cell lists yet (``Cell(name, traffic=...)``: the cell is the
-    mix's control, and lends it its configuration and its metrics), or a
+    loaded: an entry of ``workloads`` (``Cell(name)``), that entry under
+    another mix (``Cell(name, traffic=...)``: the cell lends the mix its
+    configuration and its metrics; a reading by hand), or a
     pair that is no cell yet and held to no metric (``Cell.unlisted``)."""
 
     def __init__(self, name: str, rehearse: bool = False,

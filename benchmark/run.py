@@ -11,8 +11,8 @@ last and exits.  Without a TPU (or with fewer chips than the cell asks for)
 it prints no result and exits non-zero; it never falls back.  ``--rehearse``
 is the only CPU mode: toy sizes, for the sandbox, and its line says so.
 ``--traffic`` runs the cell's configuration, held to the cell's metrics,
-under a mix that no cell lists yet (``traffic/audit-churn.json``, whose
-control is ``full.audit-sweep``): a builder's reading, never the driver's.
+under another mix (``full.audit-sweep`` under ``traffic/audit-churn.json``
+reads that mix in the top-k lane): a reading by hand, never a cell's run.
 """
 
 from __future__ import annotations

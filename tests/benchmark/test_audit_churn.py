@@ -1,21 +1,26 @@
 """``audit-churn`` (PR 39): a cluster that changes between every two audit
-passes; a mix that is no cell yet and runs as ``full.audit-sweep``, its
-control, under ``--traffic audit-churn``.  The epochs are a pure function
-of the configuration and the seed, change only what the mix says (kind
-counts stand, no name and no bytes recur, a replaced Pod keeps everything
-but its name and, for some, its image, a touch rewrites ``resourceVersion``
-alone), the ledger owes every pass the totals of its own cluster, the
-interpreter's sample reaches the last epoch brought in, the manifest
-resolves with the mix, and the mix runs end to end at toy size: as it
-stands (correct), with a lister that serves an epoch late and with an audit
-plane that keeps a violation of a deleted Pod (not correct, each by the
-number that guards it).  A mix without the block takes the path it always
-took.  Nothing here times the system under test."""
+passes; the cell ``exact.audit-churn`` runs it in the lane
+``python -m gatekeeper_tpu`` runs, exact totals, and ``full.audit-sweep``
+still reads it in the top-k lane under ``--traffic audit-churn``.  The
+epochs are a pure function of the configuration and the seed, change only
+what the mix says (kind counts stand, no name and no bytes recur, a replaced
+Pod keeps everything but its name and, for some, its image, a touch
+rewrites ``resourceVersion`` alone), the ledger owes every pass the totals
+of its own cluster (a version's results under exact totals, 1 for each
+constraint it violates otherwise), the interpreter's sample reaches the
+last epoch brought in, the manifest resolves with the cell, and the mix
+runs end to end at toy size in both lanes: as it stands (correct), with a
+lister that serves an epoch late, with an audit plane that keeps a
+violation of a deleted Pod and with a total altered where the program
+produces it (not correct, each by the number that guards it).  A mix
+without the block takes the path it always took.  Nothing here times the
+system under test."""
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -27,11 +32,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import audit, churn, cluster, manifest  # noqa: E402
+from benchmark import (audit, churn, cluster, manifest, reference,  # noqa: E402
+                       wiring)
 
 MIX = "audit-churn"
 CONTROL = "full.audit-sweep"
 RUN = f"{CONTROL}.under.{MIX}"  # the run's name, and its work directory's
+CELL = "exact.audit-churn"      # the mix in the exact lane, a cell
+CELL_CONTROL = "exact.audit-sweep"
 SEED = 2147483999
 EPOCHS = 6
 OLD_COMPARED = [
@@ -41,7 +49,7 @@ OLD_COMPARED = [
 NEW_COMPARED = [
     "epochs_ran_out", "touch_moved_a_verdict", "kept_short", "kept_stale",
     "kept_unfounded", "churn_pairs_differ", "churn_sample_short",
-    "kept_messages_differ"]
+    "kept_messages_differ", "churn_counts_missing"]
 
 
 def toy() -> tuple:
@@ -306,7 +314,9 @@ def small_ledger() -> churn.Ledger:
     pairs = {(K1, 3), (K2, churn.version_id(n, 0, 3)),
              (K2, churn.version_id(n, 1, 3)),
              (K1, 5), (K1, churn.version_id(n, 0, 5))}
-    return churn.Ledger(n, rows, base, pairs, {K1: 4, K2: 0})
+    ledger = churn.Ledger(n, rows, base)
+    ledger.owe(churn.clipped(pairs), {K1: 4, K2: 0})
+    return ledger
 
 
 def kept(*names: str) -> list:
@@ -347,8 +357,64 @@ def test_a_kept_violation_names_an_object_of_that_pass():
 
 def test_a_touch_that_moves_a_verdict_is_counted():
     ledger = churn.Ledger(10, [[(5, churn.TOUCHED, -1, pod("pod-5", "1"))]],
-                          {5: pod("pod-5")}, {(K1, 5)}, {K1: 1})
+                          {5: pod("pod-5")})
+    ledger.owe(churn.clipped({(K1, 5)}), {K1: 1})
     assert ledger.touch_moved == 1 and ledger.totals == [{K1: 0}]
+
+
+def test_a_touch_that_changes_a_count_is_counted():
+    """Under result totals a touch that leaves the verdict and changes the
+    number of results is counted, and moves the total by the difference;
+    the same change counted by violating objects moves nothing."""
+    ledger = churn.Ledger(10, [[(5, churn.TOUCHED, -1, pod("pod-5", "1"))]],
+                          {5: pod("pod-5")})
+    ledger.owe({5: {K1: 1}, 15: {K1: 3}}, {K1: 4})
+    assert ledger.touch_moved == 1 and ledger.totals == [{K1: 6}]
+    assert ledger.violated == {5: {K1}, 15: {K1}}
+    ledger.owe(churn.clipped({(K1, 5), (K1, 15)}), {K1: 2})
+    assert ledger.touch_moved == 0 and ledger.totals == [{K1: 2}]
+
+
+def escalating(name: str, first: bool, second: bool) -> bytes:
+    """A Pod of two containers, each allowed to escalate its privileges or
+    not."""
+    return cluster.dumps({
+        "apiVersion": "v1", "kind": "Pod",
+        "metadata": {"name": name, "namespace": "ns-0"},
+        "spec": {"containers": [
+            {"name": c, "image": "openpolicyagent/opa:0.9.2",
+             "securityContext": {"allowPrivilegeEscalation": e}}
+            for c, e in (("a", first), ("b", second))]}})
+
+
+def test_a_result_total_moves_by_the_count_and_not_by_the_verdict():
+    """At toy size, the exact cell's own interpreter: a rollout whose new
+    Pod's second container may escalate privileges as its first already
+    could.  The constraint's total moves by the change in its results (1 to
+    2: +1) where totals count results, and not at all where they count
+    violating objects; no other constraint moves in either."""
+    cfg = manifest.Cell(CELL, rehearse=True).config
+    n = 10
+    new = churn.version_id(n, 0, 3)
+    old_raw, new_raw = (escalating("pod-3", True, False),
+                        escalating("pod-10", True, True))
+    results = reference.by_index([reference.audit_results(
+        wiring.interpreter_client(cfg),
+        [b"3\t" + old_raw, b"%d\t" % new + new_raw])])
+    key = next(k for k in results[3]
+               if k[0] == "K8sPSPAllowPrivilegeEscalationContainer")
+    assert (len(results[3][key]), len(results[new][key])) == (1, 2)
+    rows = [[(3, churn.REPLACED, -1, new_raw)]]
+    # the corpus is the one Pod, so its counts are the set-up pass's totals
+    counts = churn.result_counts(results)
+    exact = churn.Ledger(n, rows, {3: old_raw})
+    exact.owe(counts, counts[3])
+    assert exact.totals == [{**counts[3], key: 2}]
+    pairs = {(k, vid) for vid, per in results.items() for k in per}
+    objects = churn.Ledger(n, rows, {3: old_raw})
+    objects.owe(churn.clipped(pairs), {k: 1 for k in results[3]})
+    assert objects.totals == [{k: 1 for k in results[3]}]
+    assert exact.touch_moved == objects.touch_moved == 0
 
 
 def test_kept_messages_are_the_interpreters_of_the_bytes_listed():
@@ -380,10 +446,10 @@ def test_the_manifest_resolves_with_the_mix_and_its_two_metrics(monkeypatch):
     assert manifest.check() == []
     m = manifest.read_json(manifest.MANIFEST)
     mix = manifest.read_json(manifest.traffic_path(MIX))
-    # no cell yet, and the file says why and how it runs meanwhile
-    assert not any(w["traffic"] == MIX for w in m["workloads"])
-    assert "not a cell of BENCHMARK.json yet" in mix["status"]
-    assert f"--workload {CONTROL} --traffic {MIX}" in mix["status"]
+    # a cell in the exact lane only; the file has no status
+    assert [w["name"] for w in m["workloads"] if w["traffic"] == MIX] == [
+        CELL]
+    assert "status" not in mix
     assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
     cell, control = manifest.Cell(CONTROL, traffic=MIX), manifest.Cell(CONTROL)
     # the control lends the mix its configuration, to the byte, and its
@@ -402,12 +468,29 @@ def test_the_manifest_resolves_with_the_mix_and_its_two_metrics(monkeypatch):
             "from": "counts", "key": name.partition(".")[2]}
     # a mix that changes the cluster says what it assumed, and of each
     # value whether a document of the repository bears it out
+    assumed = mix["assumed"]
     assert {"churn.share_per_pass", "churn.replaced", "churn.replaced_pod",
-            "churn.new_image_share", "churn.kinds"} <= set(mix["assumed"])
-    assert "IN THE REPOSITORY" in mix["assumed"]["churn.share_per_pass"]
+            "churn.new_image_share", "churn.kinds"} <= set(assumed)
+    assert "IN THE REPOSITORY" in assumed["churn.share_per_pass"]
+    # each value's public source by name, URL and parameter, or the reading
+    # that shows no finding rests on its size
+    for key, says in (("churn.share_per_pass", "CL2_LOAD_TEST_THROUGHPUT"),
+                      ("churn.share_per_pass", "kubernetes.io/docs/setup/"),
+                      ("churn.replaced_pod", "controllers/deployment")):
+        assert says in assumed[key] and "recalled, not checked here" in \
+            assumed[key]
     for key in ("churn.replaced", "churn.new_image_share"):
-        assert "No source" in mix["assumed"][key]
+        assert "No source" in assumed[key]
+    # where no source exists, what rests on the value's size is read in the
+    # cell's own lane at another value
+    for key in ("churn.share_per_pass", "churn.replaced",
+                "churn.new_image_share"):
+        assert "What rests on" in assumed[key]
+        assert "PERF.md section 5" in assumed[key]
     assert "listed in that pass" in mix["guarantees"]["kept_violations"]
+    # a total in both meanings
+    for word in ("exact_totals", "results", "violating objects"):
+        assert word in mix["guarantees"]["totals"]
     real = manifest.read_json
 
     def without_assumed(path):
@@ -420,33 +503,49 @@ def test_the_manifest_resolves_with_the_mix_and_its_two_metrics(monkeypatch):
     assert any("'assumed'" in fault for fault in manifest.check())
 
 
-def test_a_churn_mix_needs_totals_of_violating_objects(monkeypatch, capsys):
-    from benchmark import run as run_py
-
-    real = manifest.read_json
-
-    def exact(path):
-        doc = real(path)
-        if path.endswith("library-full.json"):
-            doc["audit"]["exact_totals"] = True
-        return doc
-
-    monkeypatch.setattr(manifest, "read_json", exact)
-    with pytest.raises(ValueError, match="exact_totals"):
-        run_py.main(["--workload", CONTROL, "--traffic", MIX, "--rehearse",
-                     "--seconds", "1"])
+def test_the_cell_is_its_controls_configuration_under_the_mix():
+    """``exact.audit-churn``: ``library-full-exact`` (exact totals, the lane
+    ``__main__`` runs) under the mix, on one chip, held to what its control
+    ``exact.audit-sweep`` is held to and listed after it everywhere; the
+    one metric every cell reports lists every audit cell."""
+    m = manifest.read_json(manifest.MANIFEST)
+    work = next(w for w in m["workloads"] if w["name"] == CELL)
+    assert work == {"name": CELL, "config": "library-full-exact",
+                    "traffic": MIX, "chips": 1, "why": work["why"]}
+    assert "131072 objects x 46 constraints" in work["why"]
+    assert "exact totals" in work["why"] and CELL_CONTROL in work["why"]
+    cell, control = manifest.Cell(CELL), manifest.Cell(CELL_CONTROL)
+    assert cell.config == control.config
+    assert cell.config["audit"]["exact_totals"] is True
+    assert cell.traffic == manifest.read_json(manifest.traffic_path(MIX))
+    assert cell.end_to_end == control.end_to_end
+    assert [p["name"] for p in cell.per_layer] == [
+        p["name"] for p in control.per_layer]
+    assert len(cell.per_layer) >= 42
+    for metric in m["end_to_end"] + m["per_layer"]:
+        listed = metric.get("workloads")
+        if listed and CELL_CONTROL in listed:
+            assert listed.index(CELL_CONTROL) < listed.index(CELL)
+    audit_cells = [w["name"] for w in m["workloads"]
+                   if manifest.read_json(manifest.traffic_path(
+                       w["traffic"]))["loop"] == "audit"]
+    entry = next(p for p in m["per_layer"]
+                 if p["name"] == "entry.compiles_in_window")
+    assert set(audit_cells) <= set(entry["workloads"])
+    assert len(audit_cells) >= 7
 
 
 # --- the cell, end to end at toy size ----------------------------------------------
 
-def rehearse(capsys, mix: str | None = MIX, *args: str) -> tuple:
-    """(the result line, the notes) of one rehearsal of the control's
-    configuration, under ``mix`` or under its own."""
+def rehearse(capsys, mix: str | None = MIX, *args: str,
+             workload: str = CONTROL) -> tuple:
+    """(the result line, the notes) of one rehearsal of ``workload``, the
+    control's configuration by default, under ``mix`` or under its own."""
     from benchmark import run as run_py
 
     under = ["--traffic", mix] if mix else []
-    assert run_py.main(["--workload", CONTROL, *under, "--rehearse", "--seed",
-                        str(SEED), "--seconds", "3", *args]) == 0
+    assert run_py.main(["--workload", workload, *under, "--rehearse",
+                        "--seed", str(SEED), "--seconds", "3", *args]) == 0
     captured = capsys.readouterr()
     line = json.loads(captured.out.strip().splitlines()[-1])
     assert "rehearsal" in line and list(line)[-1] == "compared"
@@ -454,8 +553,9 @@ def rehearse(capsys, mix: str | None = MIX, *args: str) -> tuple:
     assert json.loads(last.partition("compared: ")[2]) == line["compared"]
     assert line["correct"] is all(c["value"] <= c["limit"]
                                   for c in line["compared"].values())
-    with open(os.path.join(ROOT, "benchmark", ".cache",
-                           RUN if mix else CONTROL, "notes.json")) as f:
+    name = f"{workload}.under.{mix}" if mix else workload
+    with open(os.path.join(ROOT, "benchmark", ".cache", name,
+                           "notes.json")) as f:
         return line, json.load(f)
 
 
@@ -523,14 +623,15 @@ def an_epoch_listed_a_pass_late(monkeypatch) -> None:
     monkeypatch.setattr(churn, "lister_of", lister_of)
 
 
-def a_deleted_pod_kept(monkeypatch) -> None:
+def a_deleted_pod_kept(monkeypatch, run: str = RUN) -> None:
     """From its fifth pass on the audit plane keeps, for one constraint, a
-    violation of the first Pod that epoch 0 replaced."""
+    violation of the first Pod that epoch 0 replaced (``run``: the name of
+    the run, and of its work directory)."""
     from gatekeeper_tpu.audit.manager import AuditManager
 
     real = AuditManager.audit
     calls = [0]
-    work = os.path.join(ROOT, "benchmark", ".cache", RUN)
+    work = os.path.join(ROOT, "benchmark", ".cache", run)
 
     def audit_(self, *a, **kw):
         out = real(self, *a, **kw)
@@ -590,3 +691,106 @@ def test_a_planted_stale_answer_is_caught(capsys, monkeypatch, fault, fails):
     if fault is a_total_altered:
         assert line["compared"]["passes_differ"]["value"] == 1
         assert sum(c["value"] for c in line["compared"].values()) == 1
+
+
+# --- the cell exact.audit-churn: the mix in the exact lane -------------------------
+
+def test_rehearse_the_exact_cell(capsys):
+    """``exact.audit-churn`` end to end at toy size on whatever JAX finds:
+    totals are results, every listed version is counted by the interpreter
+    after the window, violating or not, every pass is held to the results
+    of its own cluster, and the traced run reads every per-layer metric the
+    cell lists.  Half a minute."""
+    line, notes = rehearse(capsys, None, "--trace", "1", workload=CELL)
+    assert line["correct"] is True and line["failed"] == 0
+    assert list(line["compared"]) == OLD_COMPARED + NEW_COMPARED
+    assert all(c == {"value": 0, "limit": 0}
+               for c in line["compared"].values())
+    assert notes["problems"] == [] and notes["workload"] == CELL
+    # totals are result counts: more results than violating objects
+    assert notes["sample_audit_violations"] > \
+        notes["sample_audit_violating_objects"] > 0
+    state = notes["churn"]
+    n, passes = notes["objects"], notes["passes"]
+    assert state["epochs_brought_in"] == len(state["settle"]) + passes
+    assert set(state["changed"]) == {round(0.01 * n)}
+    assert state["executables_by_pass"] == [0] * passes
+    # the review after the window: every version listed is counted, the
+    # corpus's own of each changed position and each epoch's, and is a
+    # line of it or was reviewed beside set-up (at most the first sample's)
+    made = sum(state["changed"])
+    assert made < state["counted_versions"] <= 2 * made
+    assert state["counted_versions"] <= \
+        state["review_lines"] + churn.SAMPLE_FIRST
+    assert state["review_children"] >= 1 and state["review_s"] > 0
+    assert state["review_child_cpu_s"] > 0
+    metrics = line["metrics"]
+    listed = {p["name"] for p in manifest.Cell(CELL).per_layer}
+    assert listed - set(metrics) <= {"sweep_device_roofline"}
+    assert metrics["list.changed_share"]["value"] == pytest.approx(
+        round(0.01 * n) / n)
+    assert 29 <= metrics["list.new_strings_per_pass"]["value"] <= 29 + 6
+    assert metrics["entry.compiles_in_window"]["value"] == 0.0
+
+
+def a_review_line_lost(monkeypatch) -> None:
+    """The children that review after the window give no answer for the
+    last line of their input, which is a listed version no sample and no
+    kept violation asked for: a count no one gave."""
+    real = reference.Children.join
+
+    def join(self, *a, **kw):
+        outs = real(self, *a, **kw)
+        if os.path.basename(self.outputs[0]).startswith("kept."):
+            last = max(idx for part in outs for idx, _rows in part)
+            outs = [[row for row in part if row[0] != last] for part in outs]
+        return outs
+
+    monkeypatch.setattr(reference.Children, "join", join)
+
+
+def a_violation_the_sweep_misses(monkeypatch) -> None:
+    """The sweep of the changed versions after the window misses one
+    violation of a corpus version of a changed object, which no sample
+    holds (set-up's sweep of the sample stays sound)."""
+    real = audit.device_pairs
+
+    def device_pairs(program, groups, chunk=None):
+        pairs = real(program, groups, chunk)
+        if chunk is not None:
+            pairs = pairs - {min(pairs, key=lambda pair: pair[1])}
+        return pairs
+
+    monkeypatch.setattr(audit, "device_pairs", device_pairs)
+
+
+@pytest.mark.parametrize("fault,fails", [
+    (an_epoch_listed_a_pass_late, "kept_stale"),
+    (functools.partial(a_deleted_pod_kept, run=CELL), "kept_stale"),
+    (a_total_altered, "passes_differ"),
+    (a_review_line_lost, "churn_counts_missing"),
+    (a_violation_the_sweep_misses, "churn_pairs_differ")],
+    ids=["the control: an epoch listed a pass late", "a deleted pod kept",
+         "a total altered", "a review line lost",
+         "a violation the sweep misses"])
+def test_a_planted_fault_is_caught_in_the_exact_lane(capsys, monkeypatch,
+                                                     fault, fails):
+    """The control (a lister that serves one pass the bytes of the pass
+    before) and the planted faults in the lane of result totals: ``correct``
+    is false, and the number that guards each says so."""
+    fault(monkeypatch)
+    line, notes = rehearse(capsys, None, workload=CELL)
+    assert line["correct"] is False
+    assert line["compared"][fails]["value"] > 0
+    assert notes["problems"]
+    assert all(line["compared"][name]["value"] == 0 for name in OLD_COMPARED
+               if name != "passes_differ")
+    if fails != "churn_counts_missing":
+        assert line["compared"]["churn_counts_missing"]["value"] == 0
+    if fails in ("passes_differ", "churn_pairs_differ"):
+        # one wrong answer, one number: a violation the sweep misses moves
+        # no total, since no total rests on the sweep
+        assert line["compared"][fails]["value"] == 1
+        assert sum(c["value"] for c in line["compared"].values()) == 1
+    if fails == "churn_counts_missing":
+        assert line["compared"][fails]["value"] == 1

@@ -722,10 +722,15 @@ PLANNED = {
 def planned_manifest(tmp_path, monkeypatch):
     """BENCHMARK.json with the admission cells PERF.md section 7 plans
     appended, as the PR that lands them will append them: entries only,
-    over files that are there.  (The bounds are placeholders.)"""
+    over files that are there, and their names appended to the list of the
+    one metric every cell reports.  (The bounds are placeholders.)"""
     m = manifest.read_json(manifest.MANIFEST)
     for section, entries in PLANNED.items():
         m[section] = m[section] + entries
+    for p in m["per_layer"]:
+        if p["name"] == "entry.compiles_in_window":
+            p["workloads"] = p["workloads"] + [
+                w["name"] for w in PLANNED["workloads"]]
     path = tmp_path / "BENCHMARK.json"
     path.write_text(json.dumps(m))
     monkeypatch.setattr(manifest, "MANIFEST", str(path))

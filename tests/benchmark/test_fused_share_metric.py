@@ -117,8 +117,9 @@ def test_the_selector_cell_still_reports_what_its_control_reports():
     assert mine == theirs and len(mine) >= 29
     assert mine.index(new[0]) < mine.index(new[1]) < mine.index(NAME)
     per_layer = {p["name"]: p for p in m["per_layer"]}
-    # no list: every cell reports it, the planned admission cells too
-    assert "workloads" not in per_layer["entry.compiles_in_window"]
+    # listed in every audit cell; a later cell appends its name
+    assert set(CELLS) <= set(
+        per_layer["entry.compiles_in_window"]["workloads"])
     assert "entry.compiles_in_window" in mine
     for name, better, unit in zip(new, ["higher", "lower"], ["1", "s"]):
         p = per_layer[name]
