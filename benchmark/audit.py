@@ -205,7 +205,8 @@ def kept_agrees(got: list, want: list, limit: int) -> bool:
 
 def sample_audit_problems(got, order: list, results: dict, ident: dict,
                           limit: int, exact: bool = False,
-                          counts: dict | None = None) -> list:
+                          counts: dict | None = None,
+                          implied: dict | None = None) -> list:
     """What ``got``, the audit of the sample corpus, reports otherwise than
     the interpreter.  ``results``: {corpus index: {constraint key:
     [messages]}}; ``ident``: {corpus index: (kind, namespace, name)}.
@@ -213,7 +214,15 @@ def sample_audit_problems(got, order: list, results: dict, ident: dict,
     total is then the number of its results over the listed objects, as
     upstream's ``totalViolations`` counts, and otherwise the number of
     listed objects that violate it.  ``counts``, if given, takes how many
-    constraints each comparison failed (the run's ``compared``)."""
+    constraints each comparison failed (the run's ``compared``).
+
+    ``implied`` (``reference.implied_by_index``), where the configuration
+    expands generator objects: what each generator line implies, resultant
+    by resultant.  Its results count in the totals as a listed object's do,
+    and are kept after every listed object's, in the order the generators
+    were listed (the program audits the resultants once the pass has
+    drained); a kept implied violation is held to its message and to its
+    enforcement action, which the template may override."""
     problems = []
     short = got.incomplete or got.total_objects != len(order)
     if short:
@@ -225,13 +234,24 @@ def sample_audit_problems(got, order: list, results: dict, ident: dict,
         for key, msgs in results.get(idx, {}).items():
             totals[key] = totals.get(key, 0) + (len(msgs) if exact else 1)
             violators.setdefault(key, []).append((ident[idx], msgs))
+    resultants = set()
+    for idx in order:
+        for rid, per in (implied or {}).get(idx, ()):
+            resultants.add(rid)
+            for key, msgs in per.items():
+                totals[key] = totals.get(key, 0) + (len(msgs) if exact
+                                                    else 1)
+                violators.setdefault(key, []).append((rid, msgs))
     totals_differ = kept_differ = 0
     for key, total in got.total_violations.items():
         if total != totals.get(key, 0):
             totals_differ += 1
             problems.append(f"sample audit: {key} totals {total}, the "
                             f"interpreter {totals.get(key, 0)}")
-        kept = [((v.kind, v.namespace, v.name), v.message)
+        kept = [((v.kind, v.namespace, v.name),
+                 (v.message, v.enforcement_action)
+                 if (v.kind, v.namespace, v.name) in resultants
+                 else v.message)
                 for v in got.kept[key]]
         if not kept_agrees(kept, violators.get(key, []), limit):
             kept_differ += 1
@@ -278,6 +298,16 @@ def device_pairs(program, groups: dict, chunk: int | None = None) -> set:
                     for oi in violation_rows(hits, ci, len(rows)):
                         pairs.add((tuple(con.key()), part[int(oi)][0]))
     return pairs
+
+
+def generator_kinds(program) -> set:
+    """The kinds some ExpansionTemplate of the program applies to (every
+    template here applies to kinds of one group and version): their objects
+    are what a pass expands where the configuration says so."""
+    if program.expansion is None:
+        return set()
+    return {k for t in program.expansion.templates() for entry in t.apply_to
+            for k in entry.get("kinds") or ()}
 
 
 def trace_plan(setup_pass_s: float, window_s: float, trace_from: int,
@@ -349,6 +379,10 @@ def run(run: Run) -> dict:
             for vid, raw in epochs.sample_first():
                 f.write(b"%d\t" % vid + raw + b"\n")
         run.mark("churn_first_epochs")
+    if traffic.get("churn") and cfg["audit"].get("expand_generated"):
+        raise ValueError(f"{run.cell.name}: the churn ledger owes no "
+                         "implied results; a mix with a churn block runs "
+                         "no configuration that expands generators")
     inventory = [p + ".inv" for p in paths]
     ref = reference.Children(run.spawn, cfg, "audit", run.seed, inventory,
                              sample_path, run.work,
@@ -579,7 +613,10 @@ def _measure(run, program, paths, counts, sample, ref, inventory,
     # corpus index -> {constraint key: [messages]}; the changed objects'
     # version numbers lie above every corpus index
     results, reviewed = {}, {}
-    for idx, per in reference.by_index(ref.join()).items():
+    parts = ref.join()
+    implied = (reference.implied_by_index(parts)
+               if cfg["audit"].get("expand_generated") else None)
+    for idx, per in reference.by_index(parts).items():
         if idx >= n_objects:
             reviewed[idx] = per
         elif per:
@@ -597,7 +634,17 @@ def _measure(run, program, paths, counts, sample, ref, inventory,
     # every number that decides ``correct``; each has the limit 0
     compared: dict = {}
     problems = sample_audit_problems(sampled, order, results, ident, limit,
-                                     cfg["audit"]["exact_totals"], compared)
+                                     cfg["audit"]["exact_totals"], compared,
+                                     implied)
+    implied_results = 0
+    if implied is not None:
+        implied_results = sum(len(msgs) for entries in implied.values()
+                              for _rid, per in entries
+                              for msgs in per.values())
+        compared["reference_implied_empty"] = int(not implied_results)
+        if not implied_results:
+            problems.append("the reference sample's generators imply no "
+                            "violation")
     compared["device_pairs_differ"] = len(device ^ interp)
     compared["reference_sample_empty"] = int(not interp)
     compared["setup_pass_short"] = int(
@@ -728,6 +775,7 @@ def _measure(run, program, paths, counts, sample, ref, inventory,
         "full_gc_s": run.full_gc_s_between(w0, w1) if run.traced else None,
         "loadgen": None, "trace": reduced,
     }
+    generators = generator_kinds(program)
     notes = {
         "passes": len(passes), "pass_s": passes,
         "traced_from": trace_from, "traced_passes": traced_passes,
@@ -741,6 +789,11 @@ def _measure(run, program, paths, counts, sample, ref, inventory,
         "sample_audit_violating_objects": sum(
             len(results.get(idx, ())) for idx in order),
         "sample_audit_kept": sum(len(v) for v in sampled.kept.values()),
+        "generators": sum(n for kind, n in counts.items()
+                          if kind in generators),
+        "sample_audit_generators": sum(ident[idx][0] in generators
+                                       for idx in order),
+        "reference_implied_results": implied_results,
         "compiles_in_sample_audit": compiles_in_sample_audit,
         "violations": sum(first.total_violations.values()),
         "kept": sum(len(v) for v in first.kept.values()),

@@ -37,6 +37,9 @@ import random
 
 SHARD = 32_768  # objects per shard; one audit chunk
 _LABELLED_KINDS = ("Pod", "Service", "Ingress", "Deployment")
+# Kubernetes' rand.SafeEncodeString alphabet, in which pod-template-hash
+# is written (apimachinery pkg/util/rand)
+_SAFE_ALPHANUMS = "bcdfghjklmnpqrstvwxz2456789"
 
 _REPO_OK = "openpolicyagent/"
 _REPOS_BAD = ("docker.io/rando/", "quay.io/other/")
@@ -114,9 +117,16 @@ class Cluster:
         self._init_share = float(pod["init_container_share"])
         self._vol_share = float(pod["volumes_share"])
         self._images = self._image_pool(random.Random("images"))
+        templates = spec.get("pod_templates")
+        if templates not in (None, "pod"):
+            raise ValueError(f"cluster.pod_templates: {templates!r}; the "
+                             "one value is \"pod\"")
+        self._pod_templates = templates == "pod"
         self._makers = {"Pod": self._pod, "Service": self._service,
                         "Ingress": self._ingress,
                         "Deployment": self._deployment,
+                        "ReplicaSet": self._replica_set,
+                        "StatefulSet": self._stateful_set,
                         "Namespace": self._namespace,
                         "RoleBinding": self._binding,
                         "ClusterRoleBinding": self._cluster_binding}
@@ -299,17 +309,34 @@ class Cluster:
             spec["volumes"] = vols
         return spec
 
-    def _pod(self, rng, i: int, ns: str) -> dict:
-        meta: dict = {"name": f"pod-{i}", "namespace": ns,
-                      "labels": {"app": f"app{rng.randrange(50)}"}}
+    def _pod_meta(self, rng, meta: dict) -> dict:
+        """A Pod's labels and annotations, drawn into ``meta`` (which
+        already names the Pod, or is a pod template's)."""
+        meta["labels"] = {"app": f"app{rng.randrange(50)}"}
         self._label(rng, "Pod", meta)
         if rng.random() < self.dev["apparmor_set"]:
             meta["annotations"] = {
                 "container.apparmor.security.beta.kubernetes.io/c0":
                 "unconfined" if rng.random() < self.dev["apparmor_bad"]
                 else "runtime/default"}
+        return meta
+
+    def _pod(self, rng, i: int, ns: str) -> dict:
+        meta = self._pod_meta(rng, {"name": f"pod-{i}", "namespace": ns})
         return {"apiVersion": "v1", "kind": "Pod", "metadata": meta,
                 "spec": self._pod_spec(rng)}
+
+    def _pod_template(self, rng, template_hash: str | None = None) -> dict:
+        """``spec.template`` of a workload.  Under ``pod_templates: "pod"``
+        a Pod's draw (its labels, annotations and spec), the labels with
+        ``pod-template-hash`` where the workload is a ReplicaSet; otherwise
+        the one container every Deployment had before the key existed."""
+        if not self._pod_templates:
+            return {"spec": {"containers": [self._container(rng, "c0")]}}
+        meta = self._pod_meta(rng, {})
+        if template_hash is not None:
+            meta["labels"]["pod-template-hash"] = template_hash
+        return {"metadata": meta, "spec": self._pod_spec(rng)}
 
     def _service(self, rng, i: int, ns: str) -> dict:
         d = self.dev
@@ -349,17 +376,53 @@ class Cluster:
         return {"apiVersion": "networking.k8s.io/v1", "kind": "Ingress",
                 "metadata": meta, "spec": spec}
 
-    def _deployment(self, rng, i: int, ns: str) -> dict:
+    def _replicas(self, rng) -> int:
         # the sample allows 3..50 replicas
-        replicas = (rng.choice((1, 60))
-                    if rng.random() < self.dev["replicas_out_of_range"]
-                    else rng.choice((3, 3, 5, 8, 12, 20)))
+        return (rng.choice((1, 60))
+                if rng.random() < self.dev["replicas_out_of_range"]
+                else rng.choice((3, 3, 5, 8, 12, 20)))
+
+    def _deployment(self, rng, i: int, ns: str) -> dict:
+        replicas = self._replicas(rng)
         meta: dict = {"name": f"dep-{i}", "namespace": ns}
         self._label(rng, "Deployment", meta)
+        template = self._pod_template(rng)
+        spec: dict = {"replicas": replicas}
+        if self._pod_templates:
+            spec["selector"] = {"matchLabels": {
+                "app": template["metadata"]["labels"]["app"]}}
+        spec["template"] = template
         return {"apiVersion": "apps/v1", "kind": "Deployment",
-                "metadata": meta,
-                "spec": {"replicas": replicas, "template": {"spec": {
-                    "containers": [self._container(rng, "c0")]}}}}
+                "metadata": meta, "spec": spec}
+
+    def _replica_set(self, rng, i: int, ns: str) -> dict:
+        """The ReplicaSet a Deployment's controller runs, at the
+        Deployment's replicas."""
+        name = f"rs-{i}"
+        replicas = self._replicas(rng)
+        template = self._pod_template(rng, template_hash(name))
+        meta: dict = {"name": name, "namespace": ns}
+        spec: dict = {"replicas": replicas}
+        if self._pod_templates:
+            meta["labels"] = dict(template["metadata"]["labels"])
+            spec["selector"] = {"matchLabels": {
+                key: meta["labels"][key]
+                for key in ("app", "pod-template-hash")}}
+        spec["template"] = template
+        return {"apiVersion": "apps/v1", "kind": "ReplicaSet",
+                "metadata": meta, "spec": spec}
+
+    def _stateful_set(self, rng, i: int, ns: str) -> dict:
+        name = f"sts-{i}"
+        replicas = self._replicas(rng)
+        template = self._pod_template(rng)
+        spec: dict = {"replicas": replicas, "serviceName": name}
+        if self._pod_templates:
+            spec["selector"] = {"matchLabels": {
+                "app": template["metadata"]["labels"]["app"]}}
+        spec["template"] = template
+        return {"apiVersion": "apps/v1", "kind": "StatefulSet",
+                "metadata": {"name": name, "namespace": ns}, "spec": spec}
 
     def _namespace(self, rng, i: int, _ns: str) -> dict:
         labels = {}
@@ -414,8 +477,12 @@ class Cluster:
         kind_rng = random.Random(f"kinds:{shard}")
         rng = random.Random(f"{self.seed}:{shard}" if shard
                             else "vocabulary")
-        # the first two Pods of the cluster are the widest there can be
-        extremes = [] if shard else [_Extreme(False), _Extreme(True)]
+        # the first two Pods of the cluster are the widest there can be, and
+        # so are the first two pod templates of each workload kind
+        extremes = {kind: [] if shard else [_Extreme(False), _Extreme(True)]
+                    for kind in (("Pod", "Deployment", "ReplicaSet",
+                                  "StatefulSet")
+                                 if self._pod_templates else ("Pod",))}
         # under namespaces.listed the shard's first Namespace objects are
         # the cluster's own; the draws after them stay the fillers ns-x<i>
         own = len(self.namespaces) if self._listed and not shard else 0
@@ -423,7 +490,8 @@ class Cluster:
         lo = shard * SHARD
         for i in range(lo, min(self.n, lo + SHARD)):
             kind = self._pick(kind_rng, self._kinds, self._kind_cum)
-            draw = extremes.pop() if extremes and kind == "Pod" else rng
+            widest = extremes.get(kind)
+            draw = widest.pop() if widest else rng
             ns = self.namespace(rng)
             if kind == "Namespace" and listed < own:
                 yield self._own_namespace(listed)
@@ -467,6 +535,17 @@ class Cluster:
         rng = random.Random(f"{self.seed}:inventory:{kind}")
         for i in range(round(self.n * share)):
             yield self._makers[kind](rng, i, self.namespace(rng))
+
+
+def template_hash(name: str) -> str:
+    """A ``pod-template-hash`` as Kubernetes writes one (the safe encoding
+    of a 32-bit FNV-1a's decimal digits), of the workload's name: the same
+    for every seed, as the names are."""
+    h = 0x811C9DC5
+    for b in name.encode():
+        h = ((h ^ b) * 0x01000193) & 0xFFFFFFFF
+    return "".join(_SAFE_ALPHANUMS[int(d) % len(_SAFE_ALPHANUMS)]
+                   for d in str(h).encode())
 
 
 def dumps(obj) -> bytes:

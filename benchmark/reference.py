@@ -19,10 +19,14 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def audit_results(client, lines) -> list:
+def audit_results(client, lines, expansion=None) -> list:
     """[corpus index, [[constraint kind, constraint name, message], ...]]
     per sample line: every result of the exact review, under the audit's
-    enforcement point."""
+    enforcement point.  With ``expansion`` (``expansion_reference.
+    Expansion``), a line that some template applies to also gets what it
+    implies, as a third item: [[[kind, namespace, name], [[constraint kind,
+    constraint name, message, enforcement action], ...]], ...], one entry a
+    resultant in upstream's order (``implied_results``)."""
     from gatekeeper_tpu.apis.constraints import AUDIT_EP
     from gatekeeper_tpu.match.match import SOURCE_ORIGINAL
     from gatekeeper_tpu.target.review import AugmentedUnstructured
@@ -30,16 +34,50 @@ def audit_results(client, lines) -> list:
     out = []
     for line in lines:
         idx, _, raw = line.partition(b"\t")
+        obj = json.loads(raw)
         resp = client.review(
-            AugmentedUnstructured(object=json.loads(raw),
-                                  source=SOURCE_ORIGINAL),
+            AugmentedUnstructured(object=obj, source=SOURCE_ORIGINAL),
             enforcement_point=AUDIT_EP)
         rows = []
         for r in resp.results():
             con = r.constraint or {}
             rows.append([con.get("kind"),
                          (con.get("metadata") or {}).get("name"), r.msg])
-        out.append([int(idx), sorted(rows)])
+        entry = [int(idx), sorted(rows)]
+        if expansion is not None and expansion.generates(obj):
+            entry.append(implied_results(client, expansion, obj))
+        out.append(entry)
+    return out
+
+
+def implied_results(client, expansion, obj: dict) -> list:
+    """What upstream's audit adds to a generator object's review
+    (``pkg/audit/manager.go``, expand + review resultants + aggregate):
+    ``expansion_reference``'s resultants, each reviewed by the interpreter
+    as Generated, each result under ``[Implied by <template>]`` and the
+    template's enforcementAction where it sets one.  Nothing of the
+    program's expansion or mutation code runs here."""
+    from benchmark.expansion_reference import IMPLIED_PREFIX
+    from gatekeeper_tpu.apis.constraints import AUDIT_EP
+    from gatekeeper_tpu.match.match import SOURCE_GENERATED
+    from gatekeeper_tpu.target.review import AugmentedUnstructured
+
+    out = []
+    for template, res in expansion.expand(obj):
+        resp = client.review(
+            AugmentedUnstructured(object=res, source=SOURCE_GENERATED),
+            enforcement_point=AUDIT_EP)
+        prefix = IMPLIED_PREFIX % template.name
+        rows = []
+        for r in resp.results():
+            con = r.constraint or {}
+            rows.append([con.get("kind"),
+                         (con.get("metadata") or {}).get("name"),
+                         f"{prefix} {r.msg}",
+                         template.action or r.enforcement_action])
+        meta = res["metadata"]
+        out.append([[res["kind"], meta.get("namespace", ""), meta["name"]],
+                    sorted(rows)])
     return out
 
 
@@ -55,7 +93,7 @@ def admit_digests(client, namespaces: dict, lines) -> list:
 
 def main(job_path: str) -> int:
     sys.path.insert(0, ROOT)
-    from benchmark import cluster, wiring
+    from benchmark import cluster, expansion_reference, wiring
 
     with open(job_path) as f:
         job = json.load(f)
@@ -68,7 +106,10 @@ def main(job_path: str) -> int:
     with open(job["input"], "rb") as f:
         lines = [ln.rstrip(b"\n") for ln in f][job["part"]::job["parts"]]
     if job["mode"] == "audit":
-        out = audit_results(client, lines)
+        expansion = None
+        if config["audit"].get("expand_generated"):
+            expansion = expansion_reference.Expansion(config["expansion"])
+        out = audit_results(client, lines, expansion)
     else:
         out = admit_digests(
             client, cluster.Cluster(config["cluster"], config["objects"],
@@ -118,10 +159,29 @@ def by_index(parts: list) -> dict:
     children's outputs, an entry for every line reviewed."""
     out: dict = {}
     for part in parts:
-        for idx, rows in part:
+        for idx, rows, *_implied in part:
             per = out.setdefault(idx, {})
             for kind, name, msg in rows:
                 per.setdefault((kind, name), []).append(msg)
+    return out
+
+
+def implied_by_index(parts: list) -> dict:
+    """{index: [((kind, namespace, name), {(constraint kind, constraint
+    name): [(message, enforcement action)]}), ...]} of the generator lines
+    among audit children's outputs: what each implies, resultant by
+    resultant, in expansion order."""
+    out: dict = {}
+    for part in parts:
+        for idx, _rows, *implied in part:
+            if not implied:
+                continue
+            entries = out.setdefault(idx, [])
+            for ident, rows in implied[0]:
+                per: dict = {}
+                for kind, name, msg, action in rows:
+                    per.setdefault((kind, name), []).append((msg, action))
+                entries.append((tuple(ident), per))
     return out
 
 

@@ -11,8 +11,8 @@ from __future__ import annotations
 
 import os
 
-LIBRARY = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "library")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LIBRARY = os.path.join(ROOT, "library")
 
 
 def template_dirs(config: dict) -> list:
@@ -45,6 +45,32 @@ def load_library(client, config: dict) -> tuple:
         raise RuntimeError(f"{config['name']}: loaded {nt} templates and "
                            f"{nc} constraints, the file says {want}")
     return nt, nc
+
+
+def expansion_system(config: dict):
+    """The configuration's ``expansion`` block installed as the
+    controllers install it: a ``MutationSystem`` and an ``ExpansionSystem``
+    over it (``controller/manager.py:98-101``), each mutator upserted as
+    ``_reconcile_mutator`` does (:389) and each template as
+    ``_reconcile_expansion`` does (:401).  Paths are the repository's.
+    None without the block: the audit then gets no expansion system, as
+    before the block existed."""
+    from gatekeeper_tpu.expansion.system import ExpansionSystem
+    from gatekeeper_tpu.mutation.system import MutationSystem
+    from gatekeeper_tpu.utils.unstructured import load_yaml_file
+
+    block = config.get("expansion")
+    if block is None:
+        return None
+    mutation = MutationSystem()
+    system = ExpansionSystem(mutation_system=mutation)
+    for path in block["mutators"]:
+        for doc in load_yaml_file(os.path.join(ROOT, path)):
+            mutation.upsert_unstructured(doc)
+    for path in block["templates"]:
+        for doc in load_yaml_file(os.path.join(ROOT, path)):
+            system.upsert_template(doc)
+    return system
 
 
 def interpreter_client(config: dict):
@@ -114,6 +140,7 @@ class Program:
                              enforcement_points=[WEBHOOK_EP, AUDIT_EP])
         self.tpu.gen_coord.constraints_fn = self.client.constraints  # :770
         load_library(self.client, config)
+        self.expansion = expansion_system(config)           # :845 Manager
         fallback = self.tpu.fallback_kinds()
         if len(fallback) != config["library"]["expect"][
                 "on_interpreter_fallback"]:
@@ -154,8 +181,10 @@ class Program:
             config=AuditConfig(
                 interval_s=60.0, violations_limit=a["violations_limit"],
                 chunk_size=a["chunk_size"],
-                exact_totals=a["exact_totals"]),
-            evaluator=self.evaluator, metrics=self.metrics)
+                exact_totals=a["exact_totals"],
+                expand_generated=a.get("expand_generated", False)),
+            evaluator=self.evaluator, metrics=self.metrics,
+            expansion_system=self.expansion)
 
     def build_serving(self, namespace_objects: dict):
         from gatekeeper_tpu.webhook.policy import Batcher, ValidationHandler

@@ -28,11 +28,13 @@ DROPPED = {
     "AuditConfig": {"pipeline": "auto", "pipeline_flatten_workers": 0,
                     "shard_chunks": 0, "audit_source": "relist"},
 }
-# what a cell's configuration defines (its ``audit`` block), and the wiring
+# what a cell's configuration defines (its ``audit`` block), and the wiring;
+# ``expand_generated`` is ``--audit-expand``, false where a
+# configuration leaves it out, as it is ``AuditConfig``'s default
 KEPT = {
     "ShardedEvaluator": {"violations_limit", "metrics"},
     "AuditConfig": {"interval_s", "violations_limit", "chunk_size",
-                    "exact_totals"},
+                    "exact_totals", "expand_generated"},
 }
 
 
@@ -42,6 +44,15 @@ def constructor(name: str):
 
     return {"ShardedEvaluator": ShardedEvaluator,
             "AuditConfig": AuditConfig}[name]
+
+
+def test_a_configuration_that_names_no_expansion_runs_the_default():
+    from gatekeeper_tpu.audit.manager import AuditConfig
+
+    params = inspect.signature(AuditConfig).parameters
+    assert params["expand_generated"].default is False
+    source = inspect.getsource(wiring.Program.build_audit)
+    assert 'a.get("expand_generated", False)' in source
 
 
 @pytest.mark.parametrize("name,keyword", [
