@@ -8,6 +8,7 @@ match/merge with key-invariance, glob fan-out, and path-test gating
 
 from __future__ import annotations
 
+import copy
 from typing import Any, Callable, Optional, Sequence
 
 from gatekeeper_tpu.mutation.path_parser import ListNode, ObjectNode
@@ -50,6 +51,51 @@ def mutate(obj: dict, path, setter: Setter,
     """Apply ``setter`` at ``path`` on ``obj`` in place; returns changed?"""
     tester = tester or PathTester()
     return _mutate(obj, path, 0, setter, tester)
+
+
+def copy_along(obj: dict, paths) -> dict:
+    """A new root that ``mutate`` along ``paths`` may write into while
+    ``obj`` stays as it was: every dict and list the walk can write into
+    is copied one level deep, and every other subtree is shared with
+    ``obj``.
+
+    The walk writes only into the container of each path node: the dicts
+    an ``ObjectNode`` prefix reaches, and at a ``ListNode`` the list and
+    its dict items (a glob or key match is decided during the walk, and a
+    last ``ListNode`` rewrites the matched item in place).  The value at
+    the end of a path is not copied: a setter returns a new value and
+    never edits the one it is given.  ``pathTests`` and ``fromMetadata``
+    only read."""
+    spine: dict = {}
+    for path in paths:
+        at = spine
+        for part in path:
+            at = at.setdefault(
+                part.name if isinstance(part, ObjectNode) else None, {})
+    return _copy_spine(obj, spine)
+
+
+def _copy_spine(node, spine: dict):
+    """``node`` copied, and below it every child ``spine`` walks on to:
+    its keys are the names of the ``ObjectNode`` parts applied at
+    ``node``, or None for its ``ListNode`` parts, each mapped to the parts
+    applied below."""
+    if isinstance(node, list):
+        below = spine.get(None)
+        if below is None:
+            return node  # an object part at a list: the walk raises
+        return [_copy_spine(item, below) if isinstance(item, dict)
+                else item for item in node]
+    # a subclass (a loaded RawJSON root) keeps its type, as a deep copy
+    # would, so json_patch still compares it as a dict
+    out = dict(node) if type(node) is dict else copy.copy(node)
+    for name, below in spine.items():
+        if name is None or not below:
+            continue  # a list part at a dict raises; a leaf is replaced
+        child = out.get(name)
+        if isinstance(child, (dict, list)):
+            out[name] = _copy_spine(child, below)
+    return out
 
 
 def _mutate(node: Any, path, depth: int, setter: Setter,

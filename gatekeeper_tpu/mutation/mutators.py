@@ -395,6 +395,13 @@ MUTATOR_KINDS = {
     "AssignImage": AssignImageMutator,
 }
 
+# the kinds whose ``mutate_obj`` writes only through ``core.mutate`` along
+# the mutator's own ``path``, with setters that return new values: a copy
+# of the object along that path (``core.copy_along``) is all a working
+# copy needs.  Matched by exact type, so a subclass that writes elsewhere
+# is not taken in.
+WRITES_ALONG_PATH = frozenset(MUTATOR_KINDS.values())
+
 
 def from_unstructured(obj: dict) -> BaseMutator:
     _, _, kind = gvk_of(obj)

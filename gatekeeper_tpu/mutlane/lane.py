@@ -51,6 +51,8 @@ import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+from gatekeeper_tpu.mutation.core import copy_along
+from gatekeeper_tpu.mutation.mutators import WRITES_ALONG_PATH
 from gatekeeper_tpu.mutation.path_parser import ListNode, ObjectNode
 from gatekeeper_tpu.webhook.mutation import json_escape_pointer, json_patch
 
@@ -65,7 +67,9 @@ class MutationOutcome:
     """Per-object result of a batched mutation pass — the same facts the
     reference handler derives per object (``changed``/``patch``/``error``
     drive the AdmissionReview response; ``obj`` is the converged tree
-    when the caller asked for it)."""
+    when the caller asked for it).  Outside the ``host`` lane ``obj``
+    shares every subtree no mutator wrote into with the input object, and
+    ``patch`` values may too: neither may be written into afterwards."""
 
     changed: bool
     obj: dict  # converged tree (the INPUT object when unchanged/error)
@@ -79,9 +83,11 @@ def _paths_may_alias(pa, pb) -> bool:
     """Conservative: may the two location paths address overlapping
     nodes?  Position-wise walk; a full match through the shorter path
     (prefix or equality) aliases — one mutator writes where the other
-    reads.  Any diverging segment proves disjointness.  Object-vs-list
-    disagreement at a position is a schema conflict the system already
-    disables, but counts as aliasing here for safety."""
+    reads.  Any diverging segment proves disjointness, except below a
+    glob and a key on one list: the keyed path may create the item the
+    glob then walks.  Object-vs-list disagreement at a position is a
+    schema conflict the system already disables, but counts as aliasing
+    here for safety."""
     for na, nb in zip(pa, pb):
         if isinstance(na, ObjectNode) and isinstance(nb, ObjectNode):
             if na.name != nb.name:
@@ -89,8 +95,9 @@ def _paths_may_alias(pa, pb) -> bool:
         elif isinstance(na, ListNode) and isinstance(nb, ListNode):
             if na.key_field != nb.key_field:
                 return False
-            if (na.key_value is not None and nb.key_value is not None
-                    and na.key_value != nb.key_value):
+            if na.glob != nb.glob:
+                return True
+            if na.key_value != nb.key_value:
                 return False
         else:
             return True  # conflicting schema: treat as aliasing
@@ -181,6 +188,9 @@ class MutationLane:
         self.ingest = ingest
         self._compiled: Optional[_Compiled] = None
         self._lock = threading.Lock()
+        # this thread's [scoped, full] working copies in the running
+        # mutate_objects call (the attributes of its mutlane.apply span)
+        self._copies = threading.local()
         self._coordinator = coordinator
         if coordinator is not None:
             coordinator.register_aux(
@@ -241,8 +251,11 @@ class MutationLane:
                           source=source) as sp:
             t0 = time.perf_counter()
             occ: dict = {}
+            copies = self._copies.counts = [0, 0]
             outcomes = self._mutate_impl(objects, namespaces, source,
                                          want_objects, occ_out=occ)
+            sp.set_attribute("copies_scoped", copies[0])
+            sp.set_attribute("copies_full", copies[1])
             attr = costattr.active()
             if attr is not None and occ:
                 # the shared lane pass splits across mutators by match
@@ -407,7 +420,7 @@ class MutationLane:
         state) and the caller's lowered outcome stands — returns None.
         Any change or error ⇒ the authoritative host path owns the
         whole outcome (returned)."""
-        work = copy.deepcopy(obj)
+        work = self._working_copy(obj, matching)
         for b in matching:
             try:
                 if b.mutate_obj(work):
@@ -417,6 +430,19 @@ class MutationLane:
         return None
 
     # --- outcome lanes ----------------------------------------------------
+    def _working_copy(self, obj, ms=None):
+        """The tree the mutators ``ms`` are applied to, leaving ``obj``
+        as it was: a copy along their location paths where each of them
+        writes only there (``mutation.core.copy_along``), else, and for
+        the host's fixed point (``ms`` None), a deep copy.  Counted into
+        the running call's ``mutlane.apply`` span."""
+        scoped = ms is not None and all(type(m) in WRITES_ALONG_PATH
+                                        for m in ms)
+        self._copies.counts[0 if scoped else 1] += 1
+        if scoped:
+            return copy_along(obj, [m.path for m in ms])
+        return copy.deepcopy(obj)
+
     def _host(self, obj, ns, source, reason: str) -> MutationOutcome:
         """The authoritative per-object reference path: full fixed-point
         convergence + RFC-6902 diff (exactly what the per-object webhook
@@ -426,7 +452,7 @@ class MutationLane:
 
             self.metrics.inc_counter(M.MUTATION_FALLBACK,
                                      {"reason": reason})
-        after = copy.deepcopy(obj)
+        after = self._working_copy(obj)
         try:
             changed = self.system.mutate(after, namespace=ns,
                                          source=source)
@@ -467,7 +493,7 @@ class MutationLane:
                     "value": sub}]
         after = obj
         if want_objects:
-            after = copy.deepcopy(obj)
+            after = self._working_copy(obj, [m])
             node = after
             for p in parts[:-1]:
                 node = node.setdefault(p, {})
@@ -479,7 +505,7 @@ class MutationLane:
         object: one application each, registry order, one diff — the
         cold-burst replacement for the full convergence loop (which
         deep-copies per application per iteration)."""
-        work = copy.deepcopy(obj)
+        work = self._working_copy(obj, ms)
         for m in ms:
             try:
                 m.mutate_obj(work)
@@ -496,7 +522,7 @@ class MutationLane:
         """Targeted single application for a solo-safe list-crossing
         mutator: by solo-safety one application IS the fixed point, so
         the M-mutator convergence loop collapses to one walk."""
-        after = copy.deepcopy(obj)
+        after = self._working_copy(obj, [m])
         try:
             changed = m.mutate_obj(after)
         except Exception:

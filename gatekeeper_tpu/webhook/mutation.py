@@ -36,13 +36,15 @@ def json_escape_pointer(seg: str) -> str:
 
 
 def json_patch(before: Any, after: Any, path: str = "") -> list:
-    """Minimal RFC-6902 diff between two JSON trees."""
+    """Minimal RFC-6902 diff between two JSON trees.  A subtree shared by
+    both (a working copy made along a mutator's path) is not walked."""
+    if before is after:
+        return []
     if type(before) is not type(after) or not isinstance(
         before, (dict, list)
     ):
-        if before is after or (before == after and
-                               isinstance(before, bool) ==
-                               isinstance(after, bool)):
+        if before == after and isinstance(before, bool) == \
+                isinstance(after, bool):
             return []
         return [{"op": "replace", "path": path or "/", "value": after}]
     if isinstance(before, dict):

@@ -18,6 +18,7 @@ the audit generator stage and the recursive `expansion/system.py`:
 """
 
 import copy
+import os
 
 import pytest
 
@@ -504,3 +505,122 @@ def test_gator_expand_differential_lane(tmp_path, capsys):
     assert got == want
     assert any(o.get("metadata", {}).get("name") == "web-pod"
                for o in got)
+
+
+# --- the scoped working copy: the documented expansion, and json_patch ---------
+
+EXPANSION_LIBRARY = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmark", "libraries", "expansion")
+
+
+def test_expand_deployments_with_the_documented_mutators_is_the_reference():
+    """``expand-deployments`` with ``always-pull-image`` (lowered, the
+    ``solo`` lane) and ``k8spspdefaultallowprivilegeescalation``
+    (host-only, pathTests MustNotExist: the probe, then the host where it
+    writes) through the stage equals the recursive reference, the bases
+    are left as they were, and no two resultants share a subtree."""
+    from gatekeeper_tpu.utils.unstructured import load_yaml_file
+
+    system = MutationSystem()
+    for f in ("always-pull-image.yaml",
+              "k8spspdefaultallowprivilegeescalation.yaml"):
+        for doc in load_yaml_file(os.path.join(EXPANSION_LIBRARY, f)):
+            system.upsert_unstructured(doc)
+    es = ExpansionSystem(mutation_system=system)
+    for doc in load_yaml_file(os.path.join(EXPANSION_LIBRARY,
+                                           "expand-deployments.yaml")):
+        es.upsert_template(doc)
+
+    def workload(kind, name, containers):
+        return {"apiVersion": "apps/v1", "kind": kind,
+                "metadata": {"name": name, "namespace": "prod"},
+                "spec": {"template": {"metadata": {"labels": {"app": name}},
+                                      "spec": {"containers": containers}}}}
+
+    closed = {"allowPrivilegeEscalation": False}
+    bases = [
+        workload("Deployment", "set", [{"name": "a", "image": "nginx",
+                                        "securityContext": dict(closed)}]),
+        workload("Deployment", "unset", [{"name": "a"}, {"name": "b"}]),
+        workload("ReplicaSet", "mixed",
+                 [{"name": "a", "securityContext": dict(closed)},
+                  {"name": "b", "securityContext": {"runAsUser": 1}}]),
+        workload("StatefulSet", "bogus",
+                 [{"name": "a"}, {"name": "b", "securityContext": "x"}]),
+        workload("Deployment", "pulled",
+                 [{"name": "a", "imagePullPolicy": "Always",
+                   "securityContext": dict(closed)}]),
+    ]
+    ns = {"apiVersion": "v1", "kind": "Namespace",
+          "metadata": {"name": "prod"}}
+    namespaces = [ns] * len(bases)
+    snapshot = copy.deepcopy(bases)
+    got = ExpansionStage(es).expand_batch(bases, namespaces)
+    assert bases == snapshot
+    want = []
+    for base in bases:
+        # the reference raises the walk's MutateError out of expand
+        try:
+            want.append(ExpandResult(es.expand(copy.deepcopy(base),
+                                               namespace=ns)))
+        except Exception as e:  # noqa: BLE001
+            want.append(ExpandResult([], error=str(e)))
+    _assert_results_identical(got, want)
+    assert got[3].error is not None and not got[0].error
+    pods = [r.obj for g in got for r in g.resultants]
+    assert {p["metadata"]["name"] for p in pods} == {
+        "set-pod", "unset-pod", "mixed-pod", "pulled-pod"}
+    assert all(c["imagePullPolicy"] == "Always"
+               and c["securityContext"]["allowPrivilegeEscalation"] is False
+               for p in pods for c in p["spec"]["containers"])
+    seen: dict = {}
+    for i, p in enumerate(pods):
+        stack = [p]
+        while stack:
+            node = stack.pop()
+            if isinstance(node, (dict, list)):
+                assert seen.setdefault(id(node), i) == i
+                stack.extend(node.values() if isinstance(node, dict)
+                             else node)
+
+
+SHARED = {"keep": {"deep": [1, {"x": True}]}}
+
+
+@pytest.mark.parametrize("before,after,ops", [
+    # an object is its own diff's identity, whatever it holds
+    (SHARED, SHARED, []),
+    ([1, float("nan")], None, []),
+    ({"a": {"b": [1, 2]}, "c": "d"}, None, []),
+    # the cases the lane's patches are made of, unchanged
+    ({"a": 1}, {"a": 2}, [{"op": "replace", "path": "/a", "value": 2}]),
+    ({"a": 1}, {"a": True},
+     [{"op": "replace", "path": "/a", "value": True}]),
+    ({"a": 1, "b": 2}, {"a": 1},
+     [{"op": "remove", "path": "/b"}]),
+    ({"spec": {}}, {"spec": {"securityContext": {"runAsNonRoot": True}}},
+     [{"op": "add", "path": "/spec/securityContext",
+       "value": {"runAsNonRoot": True}}]),
+    ({"a/b": {"c~d": 1}}, {"a/b": {"c~d": 2}},
+     [{"op": "replace", "path": "/a~1b/c~0d", "value": 2}]),
+    ({"l": [{"n": "a"}]}, {"l": [{"n": "a", "p": "Always"}]},
+     [{"op": "replace", "path": "/l",
+       "value": [{"n": "a", "p": "Always"}]}]),
+    ({"l": [1]}, {"l": [1]}, []),
+    (1, "1", [{"op": "replace", "path": "/", "value": "1"}]),
+    # a scoped copy's new spine over shared subtrees diffs as a deep
+    # copy's does
+    ({"keep": SHARED["keep"], "spec": {"a": 1}},
+     {"keep": SHARED["keep"], "spec": {"a": 1, "b": 2}},
+     [{"op": "add", "path": "/spec/b", "value": 2}]),
+])
+def test_json_patch_is_unchanged_and_skips_what_both_share(before, after,
+                                                            ops):
+    from gatekeeper_tpu.webhook.mutation import json_patch
+
+    after = before if after is None else after
+    assert json_patch(before, after) == ops
+    # the same pair with no subtree shared reads the same ops
+    if before is not after:
+        assert json_patch(copy.deepcopy(before), copy.deepcopy(after)) == ops
